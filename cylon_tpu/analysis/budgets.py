@@ -232,9 +232,8 @@ def _trace_hash_partition(ctx) -> Dict[str, Dict]:
     return out
 
 
-def _trace_shuffle_ragged(ctx) -> Optional[Dict[str, Dict]]:
-    """Trace-only (XLA:CPU cannot run RaggedAllToAll); None when the
-    installed jax lacks the primitive entirely."""
+def _trace_shuffle_ragged(ctx) -> Dict[str, Dict]:
+    """Trace-only (XLA:CPU cannot run RaggedAllToAll)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -245,8 +244,6 @@ def _trace_shuffle_ragged(ctx) -> Optional[Dict[str, Dict]]:
     from ..parallel import shuffle as shuffle_mod
     from ..utils import shard_map
 
-    if not hasattr(jax.lax, "ragged_all_to_all"):
-        return None
     world, cap = GRID["world"], GRID["shard_cap"]
     n = world * cap
     arrs = _mixed_frame(n)
@@ -514,9 +511,7 @@ def trace_budgets(entries: Optional[Sequence[str]] = None) -> Dict[str, Dict]:
     ctx = _budget_ctx()
     out: Dict[str, Dict] = {}
     for name in entries or ENTRIES:
-        counts = ENTRIES[name](ctx)
-        if counts is not None:
-            out[name] = counts
+        out[name] = ENTRIES[name](ctx)
     return out
 
 

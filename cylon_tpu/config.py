@@ -195,14 +195,14 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in [
             "for A/B."),
     _K("CYLON_TPU_SCAN", "str", "", TRACE, cache_key=True,
        accessors=("cylon_tpu.ops.segments._pallas_plain_scan_selected",),
-       help="'pallas' routes run_extents' cumsum/cummax/cummin through the "
-            "Pallas scan kernel."),
+       help="run_extents' cumsum/cummax/cummin: pallas (the two-sweep "
+            "scan kernel) | xla; unset prefers pallas on TPU."),
     _K("CYLON_TPU_SEGSUM", "str", "", TRACE, cache_key=True,
        accessors=("cylon_tpu.ops.segments.prefix_reductions_enabled",
                   "cylon_tpu.ops.segments.effective_mode",
                   "cylon_tpu.ops.segments._pallas_scan_selected"),
        help="Segment-reduction path: prefix | pallas | scatter; unset "
-            "prefers prefix on TPU-family backends."),
+            "prefers pallas on TPU, scatter elsewhere."),
     _K("CYLON_TPU_ACCUM", "enum", "auto", TRACE, cache_key=True,
        choices=("wide", "narrow", "auto"),
        accessors=("cylon_tpu.precision.accumulation_mode",
@@ -302,12 +302,11 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in [
     _K("CYLON_TPU_FAULT_PLAN", "str", "", RUNTIME,
        help="Deterministic fault-injection plan: `site[@N][+][=kind]` "
             "entries joined by `;` (resilience.FaultPlan.parse), e.g. "
-            "`pass_dispatch@2=oom;probe_spawn@1=timeout`; empty disables."),
+            "`pass_dispatch@2=oom;shuffle@1=timeout`; empty disables."),
     _K("CYLON_TPU_FP_SALT", "str", "", RUNTIME,
-       help="Opaque salt mixed into every durable run/plan fingerprint.  "
-            "`bench.py --fresh` sets a per-invocation value so headline "
-            "benches can never be served from the journal result cache "
-            "(the BENCH_r03–r05 stale cache echo); empty (default) keeps "
+       help="Opaque salt mixed into every durable run/plan fingerprint: "
+            "a measurement sets a per-run value so it can never be served "
+            "from the journal result cache; empty (default) keeps "
             "fingerprints stable across runs."),
     _K("CYLON_TPU_DURABLE_DIR", "str", "", RUNTIME,
        accessors=("cylon_tpu.durable.durable_dir",

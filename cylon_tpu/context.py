@@ -123,16 +123,8 @@ class CylonContext:
             cfg = config if isinstance(config, TPUConfig) else TPUConfig()
             if cfg.num_processes is not None and cfg.num_processes > 1:
                 # the MPI_Init moment: join the global runtime before any
-                # backend initializes, so jax.devices() spans every host.
-                # jax <= 0.4.x has no jax.distributed.is_initialized; fall
-                # back to the client handle initialize() populates
-                if hasattr(jax.distributed, "is_initialized"):
-                    _initialized = jax.distributed.is_initialized()
-                else:
-                    from jax._src import distributed as _dist
-
-                    _initialized = _dist.global_state.client is not None
-                if not _initialized:
+                # backend initializes, so jax.devices() spans every host
+                if not jax.distributed.is_initialized():
                     jax.distributed.initialize(
                         coordinator_address=cfg.coordinator_address,
                         num_processes=cfg.num_processes,

@@ -27,7 +27,7 @@ using TensorFlow and CUDA-Aware MPI" (PAPERS.md).  Three primitives:
 - **pass deadlines** (:func:`pass_deadline`) — a watchdog thread armed
   per pass fires ``deadline.fired`` (obs instant + metric) the moment
   ``CYLON_TPU_PASS_DEADLINE_S`` elapses, and the pass is classified
-  `Code.Timeout` through the existing `Status` taxonomy when control
+  `Code.Timeout` through the existing `Status` codes when control
   returns, which the streaming loop retries like any transient.  The
   watchdog cannot preempt a wedged native call (nothing host-side can);
   it guarantees the hang is *visible* in the trace in real time and
@@ -237,9 +237,8 @@ def run_fingerprint(op: str, spec, frames: Sequence[Tuple[Sequence[str],
     this agrees."""
     h = hashlib.sha256()
     h.update(f"cylon_tpu.durable.v1|{op}".encode())
-    # opaque salt (CYLON_TPU_FP_SALT): `bench.py --fresh` sets a
-    # per-invocation value so a headline bench can never be served from
-    # the journal result cache (the BENCH_r03–r05 stale cache echo);
+    # opaque salt (CYLON_TPU_FP_SALT): a measurement sets a per-run
+    # value so it can never be served from the journal result cache;
     # empty keeps fingerprints stable across runs
     salt = config.knob("CYLON_TPU_FP_SALT")
     if salt:

@@ -70,7 +70,18 @@ def _roll_right(v: jax.Array, d: int, interpret: bool) -> jax.Array:
     lowering for it, so tests take jnp.roll."""
     if interpret:
         return jnp.roll(v, d, axis=1)
-    return pltpu.roll(v, d, axis=1)
+    # typed shift: under jax_enable_x64 a bare Python int traces as i64
+    # and Mosaic's dynamic_rotate takes an i32 amount
+    return pltpu.roll(v, jnp.int32(d), axis=1)
+
+
+def _block_specs(bm: int):
+    """(128, bm) data block walking the lane axis, and the (128, 1) side
+    column.  The zeros are typed: under jax_enable_x64 a bare Python 0
+    traces as i64 and Mosaic rejects the mixed index-map signature."""
+    return (pl.BlockSpec((_SUBLANES, bm), lambda i: (jnp.int32(0), i)),
+            pl.BlockSpec((_SUBLANES, 1),
+                         lambda i: (jnp.int32(0), jnp.int32(0))))
 
 
 def _block_segscan(v: jax.Array, f: jax.Array, op: str, bm: int,
@@ -145,8 +156,7 @@ def _segmented_scan_padded(x2: jax.Array, r2: jax.Array, op: str, bm: int,
     """x2, r2: (128, m) with m a multiple of bm."""
     m = x2.shape[1]
     grid = (m // bm,)
-    blk = pl.BlockSpec((_SUBLANES, bm), lambda i: (0, i))
-    col = pl.BlockSpec((_SUBLANES, 1), lambda i: (0, 0))
+    blk, col = _block_specs(bm)
     partial_scan, totals, anyreset = pl.pallas_call(
         functools.partial(_sweep1_kernel, op, bm, interpret),
         grid=grid,
@@ -217,8 +227,7 @@ def _sweep1_plain_kernel(op: str, bm: int, interpret: bool, x_ref, out_ref,
 def _scan_padded(x2: jax.Array, op: str, bm: int, interpret: bool):
     m = x2.shape[1]
     grid = (m // bm,)
-    blk = pl.BlockSpec((_SUBLANES, bm), lambda i: (0, i))
-    col = pl.BlockSpec((_SUBLANES, 1), lambda i: (0, 0))
+    blk, col = _block_specs(bm)
     partial_scan, totals = pl.pallas_call(
         functools.partial(_sweep1_plain_kernel, op, bm, interpret),
         grid=grid,

@@ -178,12 +178,14 @@ def _targets(tt, key_idx, world, mode, opts: SortOptions | None):
 
 def _probe_ragged(ctx) -> bool:
     """One tiny RaggedAllToAll program on the context's mesh: each rank
-    sends one element to every rank.  Compile+run success means the
-    backend implements the collective (XLA:CPU currently does not); any
-    failure here is a capability miss, so real shuffle errors are never
-    misclassified as fallback triggers."""
+    sends one element to every rank.  XLA:CPU does not implement the
+    collective, so there the shuffle is bucketed and nothing is probed.
+    Every other backend must compile and run it: a failure raises with the
+    compiler's message instead of quietly hiding the device path."""
     from jax.sharding import PartitionSpec as P
 
+    if jax.default_backend() == "cpu":
+        return False
     world = ctx.GetWorldSize()
 
     def fn(x):
@@ -197,18 +199,31 @@ def _probe_ragged(ctx) -> bool:
 
     from ..utils import shard_map
 
-    try:
-        f = jax.jit(shard_map(fn, mesh=ctx.mesh, in_specs=P(PARTITION_AXIS),
-                              out_specs=P(PARTITION_AXIS), check_vma=False))
-        jax.block_until_ready(f(jnp.zeros((world * world,), jnp.int32)))
-        return True
-    except Exception as e:
-        import logging
+    f = jax.jit(shard_map(fn, mesh=ctx.mesh, in_specs=P(PARTITION_AXIS),
+                          out_specs=P(PARTITION_AXIS), check_vma=False))
+    jax.block_until_ready(f(jnp.zeros((world * world,), jnp.int32)))
+    return True
 
-        logging.getLogger(__name__).info(
-            "ragged all_to_all unavailable on this backend (%s); "
-            "using bucketed shuffle", type(e).__name__)
-        return False
+
+#: XLA:TPU lays every row of a RaggedAllToAll operand out as one 128-lane
+#: u32 vector, whatever the plane's width, and a send operand of 2^31 bytes
+#: in that layout halts the core with a DMA bounds check (v5e, libtpu
+#: 0.0.34: 4,190,000 rows per shard pass, 2^22 halt — PERF.md, PR 22)
+_RAGGED_ROW_BYTES = 128 * 4
+_RAGGED_OPERAND_LIMIT = 1 << 31
+
+
+def _check_ragged_operand(t) -> None:
+    """Refuse, classified, the exchange the chip would halt on."""
+    if precision.on_tpu() \
+            and t.shard_capacity * _RAGGED_ROW_BYTES >= _RAGGED_OPERAND_LIMIT:
+        raise CylonError(
+            Code.CapacityError,
+            f"shuffle of {t.shard_capacity} rows per shard: the TPU's "
+            f"RaggedAllToAll takes fewer than "
+            f"{_RAGGED_OPERAND_LIMIT // _RAGGED_ROW_BYTES} — use more "
+            f"shards, or the out-of-core engine (cylon_tpu.exec) to "
+            f"stream the table in passes")
 
 
 def _ragged_enabled(ctx) -> bool:
@@ -409,6 +424,7 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
         # decodes under a stale layout.
         compress = pack and plane_mod.compress_enabled()
         if _ragged_enabled(ctx):
+            _check_ragged_operand(t)
             with obs_spans.span("shuffle.plan", mode=mode, world=world,
                       family="ragged"):
                 # sized here, inside the retried exchange — the task-graph

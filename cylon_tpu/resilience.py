@@ -11,7 +11,7 @@ This module supplies the three primitives the engine, the table-level
 one-shot ops, and the bench harness share:
 
 - **classification** — `Status.from_exception` (status.py) maps
-  ``XlaRuntimeError``/PJRT failure text into the `Code` taxonomy
+  ``XlaRuntimeError``/PJRT failure text into the `Code` table
   (``RESOURCE_EXHAUSTED`` → `Code.OutOfMemory`, transient comm/deadline
   failures → `Code.ExecutionError`); `RETRYABLE_CODES` names which of
   those a plain retry may heal (OOM is NOT among them — it is healed by
@@ -20,7 +20,7 @@ one-shot ops, and the bench harness share:
   ``CYLON_TPU_RETRY_MAX`` / ``CYLON_TPU_RETRY_BASE_S`` /
   ``CYLON_TPU_RETRY_MAX_S``;
 - **fault injection** — named `fault_point(site)` probes (pass_dispatch,
-  host_fetch, shuffle, probe_spawn, oneshot_join, oneshot_groupby, ...)
+  host_fetch, shuffle, shuffle_plan, oneshot_join, oneshot_groupby, ...)
   driven by a ``CYLON_TPU_FAULT_PLAN`` spec, so every recovery path is
   exercised deterministically on CPU in tier-1 tests — no real TPU OOM
   needed.  Injected faults carry the same message shapes PJRT emits, so
@@ -33,7 +33,7 @@ Fault-plan spec grammar (';'- or ','-separated entries)::
     site@N=kind     kind in {oom, timeout, comm, unknown}
     site@N+=kind    fire on EVERY hit >= N (persistent fault)
 
-e.g. ``CYLON_TPU_FAULT_PLAN="pass_dispatch@2=oom;probe_spawn@1=timeout"``.
+e.g. ``CYLON_TPU_FAULT_PLAN="pass_dispatch@2=oom;shuffle@1=timeout"``.
 """
 from __future__ import annotations
 

@@ -55,7 +55,7 @@ class Code(enum.IntEnum):
 # one exception type (XlaRuntimeError) whose message carries the absl
 # status code, so classification is textual by necessity; the patterns
 # cover the RESOURCE_EXHAUSTED / allocator shapes TPU OOMs actually emit
-# and the deadline/comm shapes a flaky tunnel emits.  resilience.py's
+# and the deadline/comm shapes a flaky link emits.  resilience.py's
 # injected faults reuse these exact message shapes.
 _OOM_PATTERNS = (
     "resource_exhausted", "resource exhausted", "out of memory",
@@ -86,7 +86,7 @@ class Status:
 
     @staticmethod
     def from_exception(exc: BaseException) -> "Status":
-        """Classify an exception into the `Code` taxonomy.
+        """Classify an exception into the `Code` table.
 
         `CylonError` keeps its own code; `MemoryError` and PJRT
         ``RESOURCE_EXHAUSTED``/allocator text map to `Code.OutOfMemory`;

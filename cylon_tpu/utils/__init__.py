@@ -19,21 +19,12 @@ def generate_uuid_v4() -> str:
 
 
 def shard_map(fn, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``jax.shard_map``: newer jax exposes it at the top
-    level with a ``check_vma`` kwarg; jax <= 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` whose equivalent kwarg is
-    ``check_rep``.  Every shard_map construction in the tree goes through
-    here so a jax upgrade/downgrade can't silently kill the whole
-    distributed test surface again."""
+    """``jax.shard_map`` — every shard_map construction in the tree goes
+    through here (cylint resolves the builders by this name)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pow2ceil(n: int, min_size: int = 8) -> int:

@@ -856,7 +856,7 @@ def peerless():
 
 def test_corruption_matrix_classification(tmp_path, no_live_journal,
                                           peerless):
-    """The full damage taxonomy, peer-less (so nothing is repairable):
+    """The full damage matrix, peer-less (so nothing is repairable):
     spill body/header bitrot quarantine, manifest mid-line corruption
     quarantines, a torn manifest TAIL is clean by contract, and a
     damaged PINNED run is never evicted (its bad pass re-executes)."""
@@ -1393,3 +1393,17 @@ def test_wire_blob_digest_contract():
     with pytest.raises(CylonError) as ei:
         wire.blob_b64("not bytes")
     assert ei.value.code == Code.SerializationError
+
+
+def test_fp_salt_changes_durable_fingerprint():
+    """CYLON_TPU_FP_SALT must perturb run_fingerprint — the journal
+    result cache keys on it, so a salted measurement can never be served
+    a prior run's spill."""
+    frames = [(("k",), {"k": np.arange(8)})]
+    with config.knob_env(CYLON_TPU_FP_SALT=None):
+        base = durable.run_fingerprint("join", ("on", "k"), frames)
+        again = durable.run_fingerprint("join", ("on", "k"), frames)
+    with config.knob_env(CYLON_TPU_FP_SALT="fresh-123"):
+        salted = durable.run_fingerprint("join", ("on", "k"), frames)
+    assert base == again
+    assert salted != base

@@ -1,0 +1,189 @@
+"""Ask the chip's compiler, without the chip: the main path's kernels and
+step programs must compile for a TPU v5e at real sizes.
+
+The TPU compiler is installed wherever JAX's TPU support is, and compiles
+for a chip that is described and not attached.  It refuses what interpret
+mode and XLA:CPU accept — 64-bit index maps and rotate amounts under
+``jax_enable_x64``, slices off the tiling, more VMEM than a kernel may use,
+programs past 16 GB — so each case here guards the next PR at no chip time.
+A compile that passes is not a chip run; ``chip_smoke.py`` is.
+
+This is the only file that describes the chip, and it does so inside a
+fixture: only one process may load the TPU's library, every xdist worker
+imports every test file, and a second file would land on another worker.
+Code under test asks ``jax.default_backend()`` and sees the CPU, so the
+tests steer it with what exists — ``precision.on_tpu`` patched true resolves
+every "auto" default the way the chip does — and with no option of the
+program.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from cylon_tpu import dtypes, precision
+from cylon_tpu.column import Column
+from cylon_tpu.context import PARTITION_AXIS
+from cylon_tpu.ops import compact, pallas_kernels, pallas_scan, segments
+
+ROWS = 1 << 24
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def as_on_chip(monkeypatch):
+    """Resolve every backend-aware default as the chip does: narrow
+    accumulation, sort permutes, packed + compressed plane, native Pallas
+    scans and hash-partition.  The modes are read at trace time, so the jit
+    caches are dropped on the way in and out."""
+    monkeypatch.setattr(precision, "on_tpu", lambda: True)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _col(n, dt, logical, sharding, width=None):
+    shape = (n,) if width is None else (n, width)
+    lengths = None if width is None else jax.ShapeDtypeStruct(
+        (n,), jnp.int32, sharding=sharding)
+    return Column(jax.ShapeDtypeStruct(shape, dt, sharding=sharding),
+                  jax.ShapeDtypeStruct((n,), jnp.bool_, sharding=sharding),
+                  lengths, logical)
+
+
+def _kv(n, sharding):
+    return (_col(n, jnp.int32, dtypes.int32, sharding),
+            _col(n, jnp.float32, dtypes.float_, sharding))
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+
+
+@pytest.mark.parametrize("world", [4, 8])
+@pytest.mark.parametrize("nwords", [(1,), (2,)], ids=["1word", "2words"])
+def test_hash_partition_kernel_compiles(one_chip, nwords, world):
+    flat = tuple(jax.ShapeDtypeStruct((ROWS,), jnp.uint32, sharding=one_chip)
+                 for _ in range(sum(nwords)))
+    compiled = pallas_kernels._hash_partition_padded.lower(
+        flat, nwords, world, False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("op", ["sum", "max", "min"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.int32],
+                         ids=["f32", "i32"])
+@pytest.mark.parametrize("segmented", [False, True],
+                         ids=["plain", "segmented"])
+def test_scan_kernels_compile(one_chip, segmented, dtype, op):
+    lanes = ROWS // pallas_scan._SUBLANES
+    x2 = jax.ShapeDtypeStruct((pallas_scan._SUBLANES, lanes), dtype,
+                              sharding=one_chip)
+    if segmented:
+        r2 = jax.ShapeDtypeStruct(x2.shape, jnp.uint32, sharding=one_chip)
+        lowered = pallas_scan._segmented_scan_padded.lower(
+            x2, r2, op, pallas_scan._BLOCK_LANES, False)
+    else:
+        lowered = pallas_scan._scan_padded.lower(
+            x2, op, pallas_scan._BLOCK_LANES, False)
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("log2_rows", [22, 24])
+def test_entry_step_compiles(one_chip, as_on_chip, log2_rows):
+    """join_gather + hash_groupby, the step every distributed op reduces to
+    per shard, at a ~1:1 join's output capacity."""
+    import __graft_entry__
+
+    rows = 1 << log2_rows
+    step, _ = __graft_entry__.entry(rows=8, out_capacity=rows + rows // 8)
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(step).lower(_kv(rows, one_chip), count,
+                                   _kv(rows, one_chip), count).compile()
+    assert precision.narrow() and segments.effective_mode() == "pallas"
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["sort_rows", "unique", "sort_permute"])
+def test_local_kernels_compile(one_chip, as_on_chip, program):
+    from cylon_tpu.ops import sort as sort_mod
+    from cylon_tpu.ops import unique as unique_mod
+
+    count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    kv = _kv(ROWS, one_chip)
+    assert compact.permute_mode() == "sort"
+    if program == "sort_rows":
+        lowered = jax.jit(lambda c, n: sort_mod.sort_rows(
+            c, n, (0,), (True,), True)).lower(kv, count)
+    elif program == "unique":
+        lowered = unique_mod.unique.lower(kv, count, (0,), "first")
+    else:
+        mask = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip)
+        lowered = jax.jit(compact.partition_indices).lower(mask)
+    assert _device_bytes(lowered.compile()) < HBM_BYTES
+
+
+@pytest.mark.parametrize("with_string", [False, True],
+                         ids=["i32_f32", "i32_f32_str"])
+def test_ragged_shuffle_compiles_on_four_chips(topo, as_on_chip, with_string):
+    """shuffle_shard_ragged under shard_map on the four described devices,
+    over the packed and compressed plane."""
+    from cylon_tpu.parallel import plane, shuffle
+    from cylon_tpu.utils import shard_map
+
+    world, shard = 4, 1 << 22
+    mesh = Mesh(np.array(topo.devices), (PARTITION_AXIS,))
+    sharded = NamedSharding(mesh, P(PARTITION_AXIS))
+    cols = _kv(world * shard, sharded)
+    stats = [0, ROWS - 1]                      # observed int32 key range
+    if with_string:
+        cols += (_col(world * shard, jnp.uint8, dtypes.string, sharded,
+                      width=32),)
+        stats += [12, 12, shard]               # extent, max length, distinct
+    assert plane.pack_enabled() and plane.compress_enabled()
+    spec = plane.build_spec(cols, stats, world, shard)
+    assert spec is not None and spec[0][0] == "narrow"
+    targets = jax.ShapeDtypeStruct((world * shard,), jnp.int32,
+                                   sharding=sharded)
+
+    def body(cc, tgt):
+        out, total = shuffle.shuffle_shard_ragged(cc, tgt, world, 2 * shard,
+                                                  spec=spec)
+        return out, jnp.reshape(total, (1,))
+
+    compiled = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=P(PARTITION_AXIS),
+        out_specs=P(PARTITION_AXIS), check_vma=False)).lower(
+            cols, targets).compile()
+    assert "ragged-all-to-all" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES

@@ -1,7 +1,7 @@
-"""HBM budget model for the bench join+groupby pipeline.
+"""HBM budget model for the fused join+groupby pipeline.
 
-Lowers the EXACT bench program (join_gather key_grouped + pipeline
-groupby) at a ladder of sizes and prints XLA's own memory analysis
+Lowers the fused program (join_gather key_grouped + pipeline groupby)
+at a ladder of sizes and prints XLA's own memory analysis
 (argument/output/temp bytes), then bytes-per-input-row — the model that
 predicts where one static program stops fitting a 16 GB v5e chip and the
 out-of-core chunked driver (cylon_tpu/exec.py) must take over.
@@ -17,6 +17,32 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+def make_fused_pipeline(out_cap: int, algo: str = "sort"):
+    """The fused program this model and tools/profile_pipeline.py lower:
+    key_grouped inner join + boundary-scan pipeline group-by, with
+    projection pushdown skipping the unused right-key output column's
+    out_cap-sized gather.  Reference driver shape:
+    cpp/src/examples/bench/table_join_dist_test.cpp:28-137."""
+    import jax
+
+    from cylon_tpu.config import JoinType
+    from cylon_tpu.ops import groupby as groupby_mod
+    from cylon_tpu.ops import join as join_mod
+    from cylon_tpu.ops.groupby import AggOp
+
+    @jax.jit
+    def pipeline(cl, cnt_l, cr, cnt_r):
+        joined, jm = join_mod.join_gather(cl, cnt_l, cr, cnt_r,
+                                          (0,), (0,), JoinType.INNER,
+                                          out_cap, algo, key_grouped=True,
+                                          project=(0, 1, 3))
+        gcols, g = groupby_mod.pipeline_groupby(
+            joined, jm, (0,), ((1, AggOp.SUM), (2, AggOp.MEAN)), 0)
+        return gcols[1].data, gcols[2].data, g, jm
+
+    return pipeline
 
 
 def analyze(rows: int, algo: str = "sort") -> dict:
@@ -37,15 +63,12 @@ def analyze(rows: int, algo: str = "sort") -> dict:
     cols_r = (colmod.from_numpy(rng.integers(0, rows, rows).astype(np.int32)),
               colmod.from_numpy(rng.random(rows).astype(np.float32)))
     count = jnp.asarray(rows, jnp.int32)
-    # the ~1:1 key distribution yields ~1.0x join expansion; capacity
-    # rounding mirrors bench.py
+    # the ~1:1 key distribution yields ~1.0x join expansion
     m = int(join_mod.join_row_count(cols_l, count, cols_r, count,
                                     (0,), (0,), JoinType.INNER, algo))
     out_cap = _cap_round(m)
 
-    from bench import make_bench_pipeline  # THE bench program, shared
-
-    compiled = (make_bench_pipeline(out_cap, algo)
+    compiled = (make_fused_pipeline(out_cap, algo)
                 .lower(cols_l, count, cols_r, count).compile())
     ma = compiled.memory_analysis()
     arg = int(ma.argument_size_in_bytes)
