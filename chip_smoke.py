@@ -214,20 +214,20 @@ def phase_kernels(rows: int, seed: int, interpret: bool) -> dict:
         assert np.array_equal(got, exp_join), f"{algo} join rows differ"
 
     # group-by: keys in order, SUM/MEAN/VAR/NUNIQUE per key
-    g = df.groupby("k")["v"]
     g64 = df.astype({"v": np.float64}).groupby("k")["v"]
+    exp_g = g64.agg(["sum", "mean"])
     cols, ng = outs["groupby"]
     ng = int(ng)
     got_g = [np.asarray(c.data)[:ng] for c in cols]
-    assert ng == g.ngroups, (ng, g.ngroups)
-    assert np.array_equal(got_g[0], g64.sum().index.values)
-    np.testing.assert_allclose(got_g[1], g64.sum().values, rtol=RTOL)
-    np.testing.assert_allclose(got_g[2], g64.mean().values, rtol=RTOL)
+    assert ng == len(exp_g), (ng, len(exp_g))
+    assert np.array_equal(got_g[0], exp_g.index.values)
+    np.testing.assert_allclose(got_g[1], exp_g["sum"].values, rtol=RTOL)
+    np.testing.assert_allclose(got_g[2], exp_g["mean"].values, rtol=RTOL)
     # f32 sum of squares minus squared mean: an absolute error, not a
     # relative one
     np.testing.assert_allclose(got_g[3], g64.var(ddof=0).values, rtol=1e-2,
                                atol=1e-4)
-    assert np.array_equal(got_g[4], g.nunique().values)
+    assert np.array_equal(got_g[4], df.groupby("k")["v"].nunique().values)
 
     # sort: keys in order, rows kept as a multiset
     cols, _ = outs["sort"]
@@ -286,11 +286,9 @@ def phase_kernels(rows: int, seed: int, interpret: bool) -> dict:
 def pandas_pipeline(left: pd.DataFrame, right: pd.DataFrame):
     """The oracle: (join row count, group-by frame, sorted left frame)."""
     merged = left.merge(right, on="k")
-    g = merged.groupby("k")["a"]
-    gb = pd.DataFrame({"k": g.sum().index.values,
-                       "sum_a": g.sum().values.astype(np.float64),
-                       "mean_a": g.mean().values.astype(np.float64),
-                       "count_a": g.count().values})
+    gb = (merged.groupby("k")["a"].agg(["sum", "mean", "count"])
+          .astype({"sum": np.float64, "mean": np.float64}).reset_index()
+          .set_axis(["k", "sum_a", "mean_a", "count_a"], axis=1))
     return len(merged), gb, left.sort_values(["k", "a"], kind="stable")
 
 
@@ -303,16 +301,23 @@ def table_pipeline(left, right):
     return joined.row_count, gb, srt
 
 
+def compare_groupby(keys, sums, means, counts, exp_gb) -> None:
+    """Keys and counts exact, f32-accumulated sums and means to RTOL."""
+    assert len(keys) == len(exp_gb), (len(keys), len(exp_gb))
+    order = np.argsort(keys, kind="stable")
+    assert np.array_equal(np.asarray(keys)[order], exp_gb["k"].values)
+    assert np.array_equal(np.asarray(counts)[order], exp_gb["count_a"].values)
+    np.testing.assert_allclose(np.asarray(sums, np.float64)[order],
+                               exp_gb["sum_a"].values, rtol=RTOL)
+    np.testing.assert_allclose(np.asarray(means, np.float64)[order],
+                               exp_gb["mean_a"].values, rtol=RTOL)
+
+
 def compare_pipeline(got, exp) -> dict:
     (n_join, gb, srt), (exp_join, exp_gb, exp_srt) = got, exp
     assert n_join == exp_join, (n_join, exp_join)
-    gb = gb.sort_values("l_k")
-    assert len(gb) == len(exp_gb), (len(gb), len(exp_gb))
-    assert np.array_equal(gb["l_k"].values, exp_gb["k"].values)
-    assert np.array_equal(gb["count_a"].values, exp_gb["count_a"].values)
-    for c in ("sum_a", "mean_a"):
-        np.testing.assert_allclose(gb[c].values.astype(np.float64),
-                                   exp_gb[c].values, rtol=RTOL, err_msg=c)
+    compare_groupby(gb["l_k"], gb["sum_a"], gb["mean_a"], gb["count_a"],
+                    exp_gb)
     assert np.array_equal(srt["k"].values, exp_srt["k"].values)
     assert np.array_equal(srt.sort_values(["k", "a"]).to_numpy(),
                           exp_srt.to_numpy())
@@ -320,38 +325,49 @@ def compare_pipeline(got, exp) -> dict:
             "sorted_rows": len(srt)}
 
 
+def checked_pipeline(left, right, ldf, rdf) -> tuple:
+    """Run the Table pipeline twice and hold the first result to the pandas
+    oracle on the same frames: (record, the oracle's group-by frame)."""
+    got, first, second = twice(lambda: table_pipeline(left, right))
+    exp = pandas_pipeline(ldf, rdf)
+    rec = compare_pipeline(got, exp)
+    rec.update(rows_per_side=len(ldf), first_s=first, second_s=second)
+    return rec, exp[1]
+
+
+def exchange_family(ctx, expect_ragged: bool) -> str:
+    from cylon_tpu.context import ctx_cache
+
+    ragged = ctx_cache(ctx, "_ragged_probe").get("ragged")
+    if expect_ragged:
+        assert ragged is True, "the ragged exchange did not run"
+    return "ragged" if ragged else "bucketed"
+
+
 def forced_shuffle(ctx, data, expect_ragged: bool) -> dict:
     """A world-1 distributed_join takes the local fast path, so drive one
     hash exchange explicitly; rows must survive as a multiset."""
     from cylon_tpu import Table
-    from cylon_tpu.context import ctx_cache
     from cylon_tpu.parallel import ops as par_ops
 
     table = Table.from_numpy(["k", "a"], [c[:SHUFFLE_ROWS] for c in data[:2]],
                              ctx=ctx)
     out = par_ops._shuffled(table, (0,), "hash")
-    ragged = ctx_cache(table.ctx, "_ragged_probe").get("ragged")
-    if expect_ragged:
-        assert ragged is True, "the ragged exchange did not run"
+    family = exchange_family(ctx, expect_ragged)
     a = table.to_pandas().sort_values(["k", "a"]).to_numpy()
     b = out.to_pandas().sort_values(["k", "a"]).to_numpy()
     assert np.array_equal(a, b), "shuffle changed the rows"
-    return {"family": "ragged" if ragged else "bucketed",
-            "rows": int(out.row_count)}
+    return {"family": family, "rows": int(out.row_count)}
 
 
 def phase_main(ctx, data) -> tuple:
     from cylon_tpu import Table
 
     lk, lv, rk, rv = data
-    left = Table.from_numpy(["k", "a"], [lk, lv], ctx=ctx)
-    right = Table.from_numpy(["k", "b"], [rk, rv], ctx=ctx)
-    got, first, second = twice(lambda: table_pipeline(left, right))
-    exp = pandas_pipeline(pd.DataFrame({"k": lk, "a": lv}),
-                          pd.DataFrame({"k": rk, "b": rv}))
-    rec = compare_pipeline(got, exp)
-    rec.update(rows_per_side=len(lk), first_s=first, second_s=second)
-    return rec, exp[1]
+    return checked_pipeline(
+        Table.from_numpy(["k", "a"], [lk, lv], ctx=ctx),
+        Table.from_numpy(["k", "b"], [rk, rv], ctx=ctx),
+        pd.DataFrame({"k": lk, "a": lv}), pd.DataFrame({"k": rk, "b": rv}))
 
 
 def phase_wide(ctx, rows: int, seed: int) -> dict:
@@ -362,28 +378,14 @@ def phase_wide(ctx, rows: int, seed: int) -> dict:
     lk, lv, rk, rv = make_data(rows, seed + 1)
     ldf = pd.DataFrame({"k": lk.astype(np.int64), "a": lv.astype(np.float64)})
     rdf = pd.DataFrame({"k": rk.astype(np.int64), "b": rv.astype(np.float64)})
-    left = Table.from_pandas(ldf, ctx=ctx)
-    right = Table.from_pandas(rdf, ctx=ctx)
-    got, first, second = twice(lambda: table_pipeline(left, right))
-    rec = compare_pipeline(got, pandas_pipeline(ldf, rdf))
-    rec.update(rows_per_side=rows, dtypes="int64/float64", first_s=first,
-               second_s=second)
-    return rec
+    rec, _ = checked_pipeline(Table.from_pandas(ldf, ctx=ctx),
+                              Table.from_pandas(rdf, ctx=ctx), ldf, rdf)
+    return dict(rec, dtypes="int64/float64")
 
 
 # ---------------------------------------------------------------------------
 # P4/P5: out of core and served
 # ---------------------------------------------------------------------------
-
-def compare_groupby(keys, sums, means, counts, exp_gb) -> None:
-    order = np.argsort(keys, kind="stable")
-    assert np.array_equal(np.asarray(keys)[order], exp_gb["k"].values)
-    assert np.array_equal(np.asarray(counts)[order], exp_gb["count_a"].values)
-    np.testing.assert_allclose(np.asarray(sums, np.float64)[order],
-                               exp_gb["sum_a"].values, rtol=RTOL)
-    np.testing.assert_allclose(np.asarray(means, np.float64)[order],
-                               exp_gb["mean_a"].values, rtol=RTOL)
-
 
 def phase_out_of_core(data, exp_gb, scratch: str) -> dict:
     from cylon_tpu import config
@@ -391,16 +393,13 @@ def phase_out_of_core(data, exp_gb, scratch: str) -> dict:
     from cylon_tpu.ops.groupby import AggOp
 
     aggs = ((1, AggOp.SUM), (1, AggOp.MEAN), (1, AggOp.COUNT))
-    runs = []
 
     def run():
         # a fresh journal each time: both calls stream every pass through
         # host -> H2D -> kernel -> D2H (P5 shows the journal answering)
         with config.knob_env(CYLON_TPU_DURABLE_DIR=tempfile.mkdtemp(
                 prefix="journal_", dir=scratch)):
-            runs.append(exec_mod.chunked_join_groupby(*data, PASSES,
-                                                      aggs=aggs))
-        return runs[-1]
+            return exec_mod.chunked_join_groupby(*data, PASSES, aggs=aggs)
 
     (out, stats), first, second = twice(run)
     assert stats["passes"] == PASSES and stats.get("parts_run") == PASSES, stats
@@ -408,8 +407,7 @@ def phase_out_of_core(data, exp_gb, scratch: str) -> dict:
     return {"rows_per_side": len(data[0]), "passes": stats["passes"],
             "mode": stats["mode"], "chunk_cap": stats["chunk_cap"],
             "groups": int(stats["groups"]), "first_s": first,
-            "second_s": second,
-            "steady_run_s": round(runs[-1][1]["run_seconds"], 3)}
+            "second_s": second}
 
 
 def phase_served(ctx, data, exp_join_rows: int, exp_gb, scratch: str) -> dict:
@@ -471,7 +469,6 @@ def assert_sharded(table, world: int) -> None:
 
 def phase_sharded(ctx, data, expect_ragged: bool) -> dict:
     from cylon_tpu import Table
-    from cylon_tpu.context import ctx_cache
     from cylon_tpu.obs import metrics
 
     world = ctx.GetWorldSize()
@@ -482,21 +479,14 @@ def phase_sharded(ctx, data, expect_ragged: bool) -> dict:
     assert_sharded(left, world)
     assert_sharded(right, world)
     sent0 = metrics.counter_value("shuffle.bytes_sent")
-    got, first, second = twice(lambda: table_pipeline(left, right))
-    sent = metrics.counter_value("shuffle.bytes_sent") - sent0
-    ragged = ctx_cache(ctx, "_ragged_probe").get("ragged")
-    family = "ragged" if ragged else "bucketed"
-    emit("exchange", family=family, bytes_sent=int(sent),
+    rec, _ = checked_pipeline(left, right, pd.DataFrame({"k": lk, "a": lv}),
+                              pd.DataFrame({"k": rk, "b": rv}))
+    sent = int(metrics.counter_value("shuffle.bytes_sent") - sent0)
+    family = exchange_family(ctx, expect_ragged)
+    emit("exchange", family=family, bytes_sent=sent,
          exchanges=int(metrics.counter_value("shuffle.exchanges")))
     assert sent > 0, "nothing was exchanged"
-    if expect_ragged:
-        assert ragged is True, "the ragged exchange did not run"
-    exp = pandas_pipeline(pd.DataFrame({"k": lk, "a": lv}),
-                          pd.DataFrame({"k": rk, "b": rv}))
-    rec = compare_pipeline(got, exp)
-    rec.update(world=world, rows_total_per_side=len(lk), family=family,
-               bytes_sent=int(sent), first_s=first, second_s=second)
-    return rec
+    return dict(rec, world=world, family=family, bytes_sent=sent)
 
 
 # ---------------------------------------------------------------------------

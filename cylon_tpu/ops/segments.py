@@ -171,54 +171,43 @@ def set_segsum(mode: "str | None") -> None:
     _SEGSUM_MODE = mode
 
 
-def prefix_reductions_enabled() -> bool:
-    """Whether narrow-mode float/min/max segment reductions use the
-    segmented scan below instead of scatter-adds.  CYLON_TPU_SEGSUM
-    (or set_segsum) forces "prefix"/"scatter"; the default is
-    backend-aware like compact.permute_mode — the scan on TPU (round-4
-    hardware: XLA:TPU serializes scatters; a same-size scan is
-    bandwidth-bound), scatter elsewhere (XLA:CPU scatter-adds are cheap
-    and its associative_scan is not).  The
-    64-bit carve-outs in groupby._segment_aggregate are mode-independent:
-    integer sums and wide accumulators keep the scatter in every mode
-    (64-bit prefix fusions have crashed this TPU backend).  Read at trace
-    time: set it before the first jitted compute or use set_segsum,
-    which clears the jit caches."""
+def effective_mode() -> str:
+    """The segment-reduction path narrow-mode float/min/max reductions
+    take: ``"pallas"`` | ``"prefix"`` | ``"scatter"``.  CYLON_TPU_SEGSUM
+    (or set_segsum) forces one; unset is backend-aware like
+    compact.permute_mode — scatter off the TPU (XLA:CPU scatter-adds are
+    cheap and its associative_scan is not), a segmented scan on it
+    (round-4 hardware: XLA:TPU serializes scatters), realized by the
+    two-sweep Pallas kernel rather than ``prefix``'s
+    lax.associative_scan: the chip's compiler takes 72 s for the latter
+    at 2^20 rows and over 400 s at 2^22, about a second for the kernel
+    at any size, and the two agree on the chip (PERF.md, PR 22).  Which
+    is faster to RUN is not measured.  The 64-bit carve-outs in
+    groupby._segment_aggregate are mode-independent: integer sums and
+    wide accumulators keep the scatter in every mode (64-bit prefix
+    fusions have crashed this TPU backend).  Read at trace time: set it
+    before the first jitted compute or use set_segsum, which clears the
+    jit caches."""
     if _SEGSUM_MODE is not None:
-        return _SEGSUM_MODE in ("prefix", "pallas")
+        return _SEGSUM_MODE
     from .. import config
 
     mode = config.knob("CYLON_TPU_SEGSUM")
     if mode in ("prefix", "pallas", "scatter"):
-        return mode != "scatter"
-    return precision.on_tpu()
+        return mode
+    return "pallas" if precision.on_tpu() else "scatter"
 
 
-def effective_mode() -> str:
-    """The segment-reduction path trace-time state selects:
-    ``"pallas"`` | ``"prefix"`` | ``"scatter"`` (public accessor — bench
-    reporting keys on it)."""
-    if not prefix_reductions_enabled():
-        return "scatter"
-    return "pallas" if _pallas_scan_selected() else "prefix"
+def prefix_reductions_enabled() -> bool:
+    """Whether segment reductions use a segmented scan (either
+    realization) instead of scatter-adds."""
+    return effective_mode() != "scatter"
 
 
 def _pallas_scan_selected() -> bool:
-    """Whether the two-sweep Pallas kernel backs segmented_reduce_sorted
-    instead of lax.associative_scan.  CYLON_TPU_SEGSUM (or set_segsum)
-    forces either; unset picks the kernel on TPU: the chip's compiler
-    takes 72 s for the associative scan at 2^20 rows and over 400 s at
-    2^22, about a second for the kernel at any size, and the two agree
-    on the chip (PERF.md, PR 22).  Which is faster to RUN is not
-    measured."""
-    if _SEGSUM_MODE is not None:
-        return _SEGSUM_MODE == "pallas"
-    from .. import config
-
-    mode = config.knob("CYLON_TPU_SEGSUM")
-    if mode in ("prefix", "pallas", "scatter"):
-        return mode == "pallas"
-    return precision.on_tpu()
+    """Whether the Pallas kernel, not lax.associative_scan, backs
+    segmented_reduce_sorted."""
+    return effective_mode() == "pallas"
 
 
 def segmented_reduce_sorted(x: jax.Array, new_group: jax.Array,

@@ -7,8 +7,8 @@ HBM pressure a *recoverable* condition: when a one-shot program exceeds
 memory, decompose it into more, smaller passes and retry only the parts
 that have not completed (the shape of "Memory-efficient array
 redistribution through portable collective communication", PAPERS.md).
-This module supplies the three primitives the engine, the table-level
-one-shot ops, and the bench harness share:
+This module supplies the three primitives the engine and the table-level
+one-shot ops share:
 
 - **classification** — `Status.from_exception` (status.py) maps
   ``XlaRuntimeError``/PJRT failure text into the `Code` table
