@@ -9,30 +9,9 @@ import socket
 import subprocess
 import sys
 
-import jax
 import pytest
 
 pytestmark = pytest.mark.slow
-
-# Upstream gap, re-checked against the 0.4.37/0.4.36 pin (PR 6): on
-# jax 0.4.x the CPU PJRT client has no multi-process computations.
-# jax.distributed.initialize() itself SUCCEEDS and jax.process_count()
-# reports 2, but the first cross-process op — device_put of globally
-# replicated data, which routes through multihost_utils.assert_equal ->
-# broadcast_one_to_all -> a jitted psum over both processes — raises
-# `XlaRuntimeError: INVALID_ARGUMENT: Multiprocess computations aren't
-# implemented on the CPU backend.` (jax/_src/dispatch.py
-# _device_put_sharding_impl).  Newer jaxlibs grow a cross-host CPU
-# collective transport, so this gate is PIN-KEYED: bumping the pin in
-# tools/full_tree_cold.sh should re-run this test, not trust this skip.
-_CPU_MULTIPROCESS_BROKEN = jax.__version__.startswith("0.4.")
-_SKIP_REASON = (
-    "jax 0.4.x CPU backend: 'Multiprocess computations aren't implemented "
-    "on the CPU backend' — initialize() succeeds but the first "
-    "cross-process device_put/psum raises XlaRuntimeError INVALID_ARGUMENT "
-    "(re-check on any jax pin bump; cylon_tpu/elastic.py is the "
-    "multi-process path that DOES run on this pin: one local mesh per "
-    "process + the shared durable journal)")
 
 # worker exit code for a coordinator-port bind race (EX_TEMPFAIL): the
 # parent retries the whole gang on a fresh port
@@ -51,7 +30,6 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-@pytest.mark.skipif(_CPU_MULTIPROCESS_BROKEN, reason=_SKIP_REASON)
 def test_two_process_distributed_join():
     worker = os.path.join(os.path.dirname(__file__), "multihost_worker.py")
     env = {k: v for k, v in os.environ.items()
