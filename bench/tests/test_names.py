@@ -1,0 +1,135 @@
+"""BENCHMARK.json against the contract's rules of names, units and files,
+and against the files under bench/ that its names have to find."""
+import glob
+import json
+import os
+import re
+
+import pytest
+
+from bench.tests.conftest import ROOT
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+WIDTHS = re.compile(r"(_dim|_rank)$")
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+    BENCH = json.load(f)
+METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+
+
+def test_top_level_keys():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["paths"] == ["bench"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    # a full check with 24 cells has to fit into 43200 s
+    runs = 2 + 14 * 24
+    assert runs * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
+
+
+@pytest.mark.parametrize("metric", METRICS, ids=lambda m: m["name"])
+def test_metric_entry(metric):
+    assert NAME.match(metric["name"])
+    assert UNIT.match(metric["unit"])
+    assert metric["better"] in ("lower", "higher")
+    assert metric["source"] in SOURCES
+    assert os.path.exists(os.path.join(ROOT, "bench", "metrics",
+                                       metric["name"] + ".py"))
+    cells = {w["name"] for w in BENCH["workloads"]}
+    assert set(metric.get("workloads", [])) <= cells
+
+
+def test_end_to_end_entries():
+    names = [m["name"] for m in BENCH["end_to_end"]]
+    assert "setup_s" in names and len(set(names)) == len(names)
+    for m in BENCH["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+
+
+def test_per_layer_entries_move_a_metric_their_cells_report():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    cells = [w["name"] for w in BENCH["workloads"]]
+    for m in BENCH["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        moved = e2e[m["moves"]]
+        assert set(m.get("workloads", cells)) <= set(
+            moved.get("workloads", cells))
+        assert "\n" not in m["layer"] and 1 <= len(m["layer"]) <= 200
+
+
+def test_every_cell_reports_enough():
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+        mine = [m for m in BENCH["end_to_end"]
+                if w["name"] in m.get("workloads", [w["name"]])]
+        assert len(mine) >= 2
+        assert any(w["name"] in m.get("workloads", [w["name"]])
+                   for m in BENCH["per_layer"])
+        assert os.path.exists(os.path.join(
+            ROOT, "bench", "traffic", w["traffic"] + ".json"))
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+    four = sum(1 for w in BENCH["workloads"] if w["chips"] == 4)
+    assert four <= max(1, len(BENCH["workloads"]) // 2)
+
+
+def test_configs():
+    used = {w["config"] for w in BENCH["workloads"]}
+    files = set()
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and c["name"] in used
+        assert len(c["source"]) <= 200 and len(c["why"]) <= 200
+        assert c["file"].startswith("bench/") and c["file"] not in files
+        files.add(c["file"])
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["source"] == c["source"]
+        for key in c["reduced"]:
+            assert NAME.match(key) and not WIDTHS.search(key)
+            assert key in cfg
+            assert any(r == key or r.startswith(key + ".")
+                       for r in cfg["reduced"])
+        for side in ("drivers", "references"):
+            assert os.path.exists(os.path.join(
+                ROOT, "bench", side, cfg["driver"] + ".py"))
+
+
+def test_file_names_under_bench():
+    ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
+    for path in glob.glob(os.path.join(ROOT, "bench", "**"), recursive=True):
+        if "__pycache__" in path:
+            continue
+        assert ok.match(os.path.relpath(path, ROOT)), path
+
+
+def test_candidates_keep_the_same_rules():
+    """bench/candidates.json: cells that wait to be registered carry
+    entries that BENCHMARK.json could take as they are."""
+    with open(os.path.join(ROOT, "bench", "candidates.json")) as f:
+        cand = json.load(f)
+    registered = {w["name"] for w in BENCH["workloads"]}
+    for w in cand["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and w["name"] not in registered
+        assert len(w["why"]) <= 200
+    for c in cand["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        with open(os.path.join(ROOT, c["file"])) as f:
+            cfg = json.load(f)
+        assert cfg["source"] == c["source"] and len(c["source"]) <= 200
+        assert all(key in cfg for key in c["reduced"])
+    for m in cand["end_to_end"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in cand["end_to_end"] + cand["per_layer"]:
+        assert os.path.exists(os.path.join(ROOT, "bench", "metrics",
+                                           m["name"] + ".py"))
