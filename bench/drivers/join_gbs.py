@@ -8,9 +8,12 @@ import numpy as np
 
 
 def build(ctx, cfg: dict, data: dict) -> dict:
+    """The tables in buffers of the configuration's ``table_capacity``
+    rows (a table's own row count where it names none)."""
     from cylon_tpu import Table
 
-    state = {side: Table.from_numpy(list(cols), list(cols.values()), ctx=ctx)
+    state = {side: Table.from_numpy(list(cols), list(cols.values()), ctx=ctx,
+                                    capacity=cfg.get("table_capacity"))
              for side, cols in data.items()}
     state["ctx"] = ctx
     return state

@@ -1,7 +1,9 @@
-"""Share of the device's busy time spent in XLA sort operations."""
+"""Share of the device's busy time spent in XLA sort operations; 0 where
+the trace holds none."""
 
 
 def read(run):
     t = run.trace
-    secs = t.get("categories_s", {}).get("sort") if t else None
-    return 100.0 * secs / t["busy_s"] if secs else None
+    if not t or not t["busy_s"]:
+        return None
+    return 100.0 * t["categories_s"].get("sort", 0.0) / t["busy_s"]

@@ -1,8 +1,9 @@
 """Device time in Pallas (Mosaic custom-call) kernels, mean over the
-chips, per traced query."""
+chips, per traced query; 0 where the trace holds none."""
 
 
 def read(run):
     t = run.trace
-    secs = t.get("categories_s", {}).get("pallas") if t else None
-    return secs / t["queries"] * 1e3 if secs else None
+    if not t:
+        return None
+    return t["categories_s"].get("pallas", 0.0) / t["queries"] * 1e3

@@ -9,11 +9,6 @@ import pandas as pd
 
 from bench.references.common import max_rel_err, round_bf16, wrong_count
 
-# bytes of one input row, either side: (k int32, a f32) or (k int32, b f32)
-_ROW_BYTES = 8
-# result bytes it has to write once: l_k int32, sum_a f32, mean_a f32,
-# count_a int32 -- the narrowest types that hold the answer
-_GROUP_BYTES = 16
 
 
 def rows_per_side(cfg: dict, chips: int) -> int:
@@ -21,22 +16,19 @@ def rows_per_side(cfg: dict, chips: int) -> int:
 
 
 def make_data(cfg: dict, chips: int, seed: int) -> dict:
-    """Keys are one uniform sample of [0, rows), drawn from the
-    configuration's ``shape_seed`` with chip_smoke.make_data's recipe: the
-    multiset of keys, so every join, group and shard size, is the same in
-    every run.  ``seed`` draws the order of the rows and all the values, so
-    the same seed gives the same inputs and two seeds the same work in
-    another order."""
+    """Two tables of the configuration's column types, every key and every
+    value drawn from ``seed`` (chip_smoke.make_data's recipe and draw
+    order: left keys, left values, right keys, right values).  Keys are
+    uniform in [0, rows), so an inner join matches about 1:1."""
     rows = rows_per_side(cfg, chips)
-    shape = np.random.default_rng(int(cfg["shape_seed"]))
-    lk = shape.integers(0, rows, rows).astype(np.int32)
-    shape.random(rows)  # the recipe's draw order: lk, lv, rk, rv
-    rk = shape.integers(0, rows, rows).astype(np.int32)
     rng = np.random.default_rng(seed)
-    return {"left": {"k": lk[rng.permutation(rows)],
-                     "a": rng.random(rows, np.float32)},
-            "right": {"k": rk[rng.permutation(rows)],
-                      "b": rng.random(rows, np.float32)}}
+    out = {}
+    for side, value in (("left", "a"), ("right", "b")):
+        types = cfg["tables"][side]
+        out[side] = {
+            "k": rng.integers(0, rows, rows).astype(types["k"], copy=False),
+            value: rng.random(rows).astype(types[value], copy=False)}
+    return out
 
 
 def queries(cfg: dict, seed: int) -> list:
@@ -52,9 +44,8 @@ def answer(data: dict, query: dict, precision: str = "f64") -> dict:
     """merge on k, group by k with sum/mean/count of a, order by
     (count desc, k asc).  ``precision="bf16"`` is the control: every
     value and every result of arithmetic rounded to bfloat16."""
-    left = pd.DataFrame(data["left"])
+    left = pd.DataFrame(data["left"]).astype({"a": np.float64})
     right = pd.DataFrame(data["right"])
-    left["a"] = left["a"].astype(np.float64)
     if precision == "bf16":
         left["a"] = round_bf16(left["a"].to_numpy())
     elif precision != "f64":
@@ -86,7 +77,9 @@ def compare(got: dict, exp: dict) -> dict:
 
 
 def work_bytes(data: dict, query: dict, exp: dict) -> int:
-    """Bytes the query cannot avoid: each input column read once and the
-    result written once, whatever implements it."""
-    return (input_rows(data, query) * _ROW_BYTES
-            + len(exp["l_k"]) * _GROUP_BYTES)
+    """Bytes the query cannot avoid, whatever implements it: each input
+    column read once, and the result written once as a key and a count of
+    the key's type and a sum and a mean of the value's."""
+    read = sum(col.nbytes for side in data.values() for col in side.values())
+    key, value = data["left"]["k"].itemsize, data["left"]["a"].itemsize
+    return read + len(exp["l_k"]) * 2 * (key + value)

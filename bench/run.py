@@ -4,8 +4,7 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything that belongs to one configuration, one traffic mix or one
-per-layer metric is found by its name in BENCHMARK.json (or in
-bench/candidates.json, the cells that are built but not registered):
+per-layer metric is found by its name in BENCHMARK.json:
 
     bench/configs/<config>.json       sizes, guarantees, limits, "driver"
                                       and, where it differs, "reference"
@@ -30,7 +29,6 @@ import importlib  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
-import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
 import traceback  # noqa: E402
@@ -49,6 +47,9 @@ from bench import trace_reduce  # noqa: E402
 # the run's 360 seconds
 TRACE_SECONDS = 20.0
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# set-up runs the cell's cycle at least twice, and then until a pass
+# compiles nothing
+WARM_PASSES_MIN, WARM_PASSES_MAX = 2, 6
 
 
 def log(*parts) -> None:
@@ -61,21 +62,7 @@ def load_json(*path) -> dict:
 
 
 def load_benchmark() -> dict:
-    """BENCHMARK.json, with the cells of bench/candidates.json (built and
-    proven, not registered) laid under it: an entry of BENCHMARK.json wins,
-    and a metric's ``workloads`` lists are joined."""
-    bench = load_json(ROOT, "BENCHMARK.json")
-    extra = load_json(BENCH, "candidates.json")
-    for key in ("configs", "workloads", "end_to_end", "per_layer"):
-        have = {e["name"]: e for e in bench[key]}
-        for entry in extra.get(key, []):
-            mine = have.get(entry["name"])
-            if mine is None:
-                bench[key].append(entry)
-            elif "workloads" in mine and "workloads" in entry:
-                mine["workloads"] = sorted(
-                    set(mine["workloads"]) | set(entry["workloads"]))
-    return bench
+    return load_json(ROOT, "BENCHMARK.json")
 
 
 def load_cell(workload: str) -> SimpleNamespace:
@@ -170,8 +157,7 @@ def warm_up(cell, state, cycle, compiles) -> int:
     a new program.  Returns the passes made."""
     import jax
 
-    mix = cell.mix
-    for n in range(1, mix["warm_passes_max"] + 1):
+    for n in range(1, WARM_PASSES_MAX + 1):
         before = compiles.count
         for query in cycle:
             table = cell.driver.run(state, query)
@@ -179,10 +165,10 @@ def warm_up(cell, state, cycle, compiles) -> int:
             cell.driver.fetch(table)
         log(f"warm-up pass {n}: {compiles.count - before} program(s) "
             f"compiled or loaded")
-        if n >= mix["warm_passes_min"] and compiles.count == before:
+        if n >= WARM_PASSES_MIN and compiles.count == before:
             return n
     raise RuntimeError(
-        f"still compiling after {mix['warm_passes_max']} warm-up passes")
+        f"still compiling after {WARM_PASSES_MAX} warm-up passes")
 
 
 def min_median_max(values: list) -> str:
@@ -272,7 +258,7 @@ def decide_correct(cell, data, cycle, records, answers, structure) -> tuple:
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
-             devices, t_process: float, keep_trace=None) -> dict:
+             devices, t_process: float) -> dict:
     """Everything past the look for a chip: set-up, the window, the
     memory reading, the reference and the comparison.  Returns the
     result line as a dict."""
@@ -309,11 +295,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         peak = memory_peak_bytes(devices)
         reduced = {}
         if trace:
-            xplane = trace_reduce.find_xplane(trace_dir)
-            if keep_trace:
-                os.makedirs(keep_trace, exist_ok=True)
-                shutil.copy(xplane, keep_trace)
-            reduced = trace_reduce.reduce(trace_reduce.load_events(xplane))
+            reduced = trace_reduce.reduce(trace_reduce.load_events(
+                trace_reduce.find_xplane(trace_dir)))
             if not reduced:
                 raise RuntimeError("the trace holds no device operation "
                                    "or no bench.query annotation")
@@ -390,13 +373,11 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    ap.add_argument("--keep-trace", metavar="DIR", default=None,
-                    help="with --trace 1, copy the .xplane.pb into DIR")
     args = ap.parse_args(argv)
     cell = load_cell(args.workload)
     devices = check_devices(cell.chips)
     report(run_cell(args.workload, args.seed, args.seconds, bool(args.trace),
-                    devices, _T_PROCESS, args.keep_trace))
+                    devices, _T_PROCESS))
     return 0
 
 

@@ -109,27 +109,3 @@ def test_file_names_under_bench():
         if "__pycache__" in path:
             continue
         assert ok.match(os.path.relpath(path, ROOT)), path
-
-
-def test_candidates_keep_the_same_rules():
-    """bench/candidates.json: cells that wait to be registered carry
-    entries that BENCHMARK.json could take as they are."""
-    with open(os.path.join(ROOT, "bench", "candidates.json")) as f:
-        cand = json.load(f)
-    registered = {w["name"] for w in BENCH["workloads"]}
-    for w in cand["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert NAME.match(w["name"]) and w["name"] not in registered
-        assert len(w["why"]) <= 200
-    for c in cand["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        with open(os.path.join(ROOT, c["file"])) as f:
-            cfg = json.load(f)
-        assert cfg["source"] == c["source"] and len(c["source"]) <= 200
-        assert all(key in cfg for key in c["reduced"])
-    for m in cand["end_to_end"]:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert 0.01 <= m["bound"] <= 0.25
-    for m in cand["end_to_end"] + cand["per_layer"]:
-        assert os.path.exists(os.path.join(ROOT, "bench", "metrics",
-                                           m["name"] + ".py"))
