@@ -33,6 +33,8 @@ import numpy as np
 
 from . import dtypes
 from .dtypes import DataType, Type
+from .obs import metrics as obs_metrics
+from .obs import span as obs_span
 from .status import Code, CylonError
 
 DEFAULT_STRING_WIDTH = 32
@@ -392,16 +394,29 @@ def _decode_rows(rows: np.ndarray, valid: np.ndarray,
     return out
 
 
+def fetch_d2h(buffers, n: Optional[int] = None, get=jax.device_get):
+    """One blocking device->host copy of a fetch, as NumPy: a pytree of
+    buffers, or the first ``n`` rows of one buffer (the device slice is
+    part of the copy).  Span ``table.fetch.d2h``; the bytes that arrived
+    add to counter ``table.fetch.bytes``."""
+    with obs_span("table.fetch.d2h"):
+        out = get(buffers if n is None else buffers[:n])
+        obs_metrics.counter_add(
+            "table.fetch.bytes",
+            sum(a.nbytes for a in jax.tree_util.tree_leaves(out)))
+    return out
+
+
 def to_numpy(col: Column, row_count: int):
     """Export valid rows to host. Strings come back as an object array of
     ``bytes`` decoded to str when valid utf-8."""
     n = int(row_count)
-    valid = np.asarray(col.validity[:n])
+    valid = fetch_d2h(col.validity, n)
     if col.is_string:
-        mat = np.asarray(col.data[:n])
-        lens = np.asarray(col.lengths[:n])
+        mat = fetch_d2h(col.data, n)
+        lens = fetch_d2h(col.lengths, n)
         return _decode_rows(_bytes_rows(mat, lens), valid)
-    vals = np.asarray(col.data[:n])
+    vals = fetch_d2h(col.data, n)
     ndt = col.dtype.numpy_dtype()
     if vals.dtype != ndt and vals.dtype.kind in "iu" and np.dtype(ndt).kind in "iu":
         vals = vals.astype(ndt)  # narrow-mode count buffers widen at export
@@ -418,12 +433,12 @@ def to_arrow(col: Column, row_count: int):
     import pyarrow as pa
 
     n = int(row_count)
-    valid = np.asarray(col.validity[:n])
+    valid = fetch_d2h(col.validity, n)
     mask = None if valid.all() else ~valid
     at = dtypes.to_arrow_type(col.dtype)
     if col.is_string:
-        mat = np.asarray(col.data[:n])
-        lens = np.asarray(col.lengths[:n])
+        mat = fetch_d2h(col.data, n)
+        lens = fetch_d2h(col.lengths, n)
         rows = _bytes_rows(mat, lens)
         if col.dtype.type == Type.STRING:
             # errors='replace' never raises, so every valid row decodes
@@ -432,5 +447,5 @@ def to_arrow(col: Column, row_count: int):
         else:
             vals = rows
         return pa.array(vals, type=at, mask=mask)
-    vals = np.asarray(col.data[:n])
+    vals = fetch_d2h(col.data, n)
     return pa.array(vals, type=at, mask=mask)

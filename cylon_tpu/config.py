@@ -545,16 +545,11 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in [
        help="Observability tracing mode: auto keeps only the always-on "
             "aggregate stopwatch; 1/on also buffers structured events for "
             "Perfetto export (obs.export); 0/off disables spans entirely "
-            "(alloc-free no-op).  Spans inside traced bodies consult it "
+            "(alloc-free no-op).  In every mode but off a span is also a "
+            "jax.profiler.TraceAnnotation.  Spans inside traced bodies consult it "
             "while tracing but never alter the traced computation, so no "
             "cache-key participation — the trace-time child spans appear "
             "on plan BUILDS, not on cached re-runs."),
-    _K("CYLON_TPU_TRACE_SYNC", "bool", False, RUNTIME,
-       accessors=("cylon_tpu.obs.spans.sync_enabled",),
-       help="Fence device work (block_until_ready on a trivial dispatch) "
-            "at span boundaries so device time attributes to the span "
-            "that launched it instead of the span doing the blocking "
-            "fetch.  Off by default: the fence serializes the pipeline."),
     _K("CYLON_TPU_TRACE_DIR", "str", "traces", RUNTIME,
        accessors=("cylon_tpu.obs.export.trace_dir",),
        help="Directory for exported trace/metrics artifacts "

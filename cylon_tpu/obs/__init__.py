@@ -11,8 +11,10 @@ consulted through ``sys.modules`` only), host-side by construction.
 
 Knobs (all runtime scope, registered in ``config.KNOBS``):
 ``CYLON_TPU_TRACE`` (auto: aggregate stopwatch only; 1: event buffer for
-export; 0: alloc-free no-op), ``CYLON_TPU_TRACE_SYNC`` (device fence at
-span boundaries), ``CYLON_TPU_TRACE_DIR``, ``CYLON_TPU_TRACE_BUFFER_CAP``.
+export; 0: alloc-free no-op), ``CYLON_TPU_TRACE_DIR``,
+``CYLON_TPU_TRACE_BUFFER_CAP``.  In every mode but off a span is also a
+``jax.profiler.TraceAnnotation``; ``stage`` names the kernel stages
+(``STAGES``) inside the programs.
 """
 from __future__ import annotations
 
@@ -21,4 +23,4 @@ from . import fleet  # noqa: F401
 from . import metrics  # noqa: F401
 from . import spans  # noqa: F401
 from . import tracectx  # noqa: F401
-from .spans import instant, span  # noqa: F401
+from .spans import STAGES, instant, span, stage  # noqa: F401

@@ -6,8 +6,9 @@ work actually ran" — collective launches and bytes moved per shuffle
 exchange (``shuffle.collective_launches`` / ``shuffle.bytes_sent``),
 out-of-core refinements (``oom.refinements``), transient retries
 (``retry.attempts``), jit-plan cache traffic (``plan_cache.hit`` /
-``plan_cache.miss``) and the host-visible HBM watermark
-(``hbm.live_bytes`` via ``jax.live_arrays``).
+``plan_cache.miss``) and the HBM watermarks (``hbm.live_bytes`` via
+``jax.live_arrays``; ``hbm.bytes_in_use`` / ``hbm.peak_bytes`` from
+``memory_stats()`` of the fullest local device, where reported).
 
 Everything is plain dict arithmetic on the host — no jax dependency, no
 locks on the hot counters (CPython's GIL makes the single add/assign
@@ -118,12 +119,21 @@ def hist_observe(name: str, value: float) -> None:
 
 
 def record_hbm_watermark() -> int:
-    """Sum live device-array bytes (``jax.live_arrays``) into the
-    ``hbm.live_bytes`` watermark gauge; returns the sampled total.
-    Host-side and jax-optional: 0 when jax was never imported."""
+    """Sample device memory; returns the live device-array bytes
+    (``jax.live_arrays``), which is also the ``hbm.live_bytes`` watermark
+    gauge.  Where the backend reports ``memory_stats()``, the fullest
+    local device also sets ``hbm.bytes_in_use`` and the
+    ``hbm.peak_bytes`` watermark.  Host-side and jax-optional: 0 when jax
+    was never imported."""
     jax = sys.modules.get("jax")
     if jax is None or not hasattr(jax, "live_arrays"):
         return 0
+    stats = [s for s in (d.memory_stats() for d in jax.local_devices()) if s]
+    if stats:
+        gauge_set("hbm.bytes_in_use",
+                  max(s.get("bytes_in_use", 0) for s in stats))
+        gauge_max("hbm.peak_bytes",
+                  max(s.get("peak_bytes_in_use", 0) for s in stats))
     total = 0
     for a in jax.live_arrays():
         total += getattr(a, "nbytes", 0) or 0

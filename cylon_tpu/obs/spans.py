@@ -25,11 +25,15 @@ Host-side only, by construction: a span measures host wall-clock between
 are legal inside jit/shard_map bodies (they then measure TRACE time and
 appear as children of the enclosing plan-build span — cylint CY101 stays
 green because no tracer is read).  Device execution is asynchronous, so
-by default device time lands in whichever span performed the blocking
-fetch; ``CYLON_TPU_TRACE_SYNC=1`` fences (``block_until_ready`` on a
-trivial dispatch, which on in-order backends drains prior launches) at
-span boundaries to attribute device time to the span that launched it —
-off by default because the fence serializes the pipeline.
+device time lands in whichever span performed the blocking fetch.
+
+The profiler's clock: in every mode but off a span is also a
+``jax.profiler.TraceAnnotation`` of the same name (attributes as its
+keyword arguments), so inside a ``jax.profiler`` session the program's
+spans lie beside the device's operations, and ``stage(name)`` is the
+``jax.named_scope`` that puts one of ``STAGES`` into the metadata of the
+operations traced under it (``tools/trace_report.py --device`` reads
+both).  With no session open an annotation costs about a microsecond.
 """
 from __future__ import annotations
 
@@ -51,6 +55,17 @@ EVENTS = "events"
 
 _MODE_OF = {"0": OFF, "off": OFF, "auto": AGGREGATE,
             "1": EVENTS, "on": EVENTS}
+
+#: aggregate of every span closed at depth 0: host time inside the program
+ROOT = "obs.root"
+
+#: the kernel stages a device operation can belong to — the one list of
+#: their names (``stage`` refuses any other); at most 16
+STAGES = ("join.ranges", "join.emit", "join.expand", "join.gather_left",
+          "join.gather_right", "groupby.sort", "groupby.boundaries",
+          "groupby.keys", "groupby.gather", "groupby.reduce", "sort.keys",
+          "sort.permute", "compact.partition", "compact.permute",
+          "plane.pack", "plane.unpack")
 
 
 class Event(NamedTuple):
@@ -107,10 +122,6 @@ def events_enabled() -> bool:
     return mode() == EVENTS
 
 
-def sync_enabled() -> bool:
-    return bool(config.knob("CYLON_TPU_TRACE_SYNC"))
-
-
 def buffer_cap() -> int:
     return max(1, int(config.knob("CYLON_TPU_TRACE_BUFFER_CAP")))
 
@@ -149,21 +160,6 @@ def _depth() -> int:
     return getattr(_tls, "depth", 0)
 
 
-def _fence() -> None:
-    """Drain prior device launches: block on a trivial dispatch (in-order
-    execution on TPU/CPU backends means it completes after everything
-    launched before it).  No-op when jax was never imported — obs itself
-    stays importable without jax."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return
-    try:
-        jax.block_until_ready(jax.numpy.add(jax.numpy.int32(0),
-                                            jax.numpy.int32(0)))
-    except Exception as e:  # a failed fence must never kill the op it wraps
-        log.debug("trace sync fence failed: %s: %s", type(e).__name__, e)
-
-
 def _record(ev: Event) -> None:
     global _dropped
     with _buf_lock:
@@ -194,17 +190,21 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("name", "attrs", "_t0", "_d", "_buffer", "_sync", "_ring",
-                 "_trace")
+    __slots__ = ("name", "attrs", "_t0", "_d", "_buffer", "_ring", "_trace",
+                 "_ann")
 
     def __init__(self, name: str, attrs: Optional[Dict[str, object]],
-                 buffer: bool, sync: bool, ring: bool):
+                 buffer: bool, ring: bool):
         self.name = name
         self.attrs = attrs
         self._buffer = buffer
-        self._sync = sync
         self._ring = ring
         self._trace = None
+        # the same span on the profiler's clock; jax through sys.modules,
+        # so obs stays importable without it
+        jax = sys.modules.get("jax")
+        self._ann = (None if jax is None else
+                     jax.profiler.TraceAnnotation(name, **(attrs or {})))
 
     def set(self, **attrs) -> "_Span":
         """Attach/refresh attributes after entry (e.g. a row count known
@@ -212,11 +212,11 @@ class _Span:
         if self.attrs is None:
             self.attrs = {}
         self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self) -> "_Span":
-        if self._sync:
-            _fence()
         if self._buffer or self._ring:
             # causal identity: become a child span of the active request
             # context (None — the common case — costs one contextvar read)
@@ -224,16 +224,19 @@ class _Span:
         self._d = _depth()
         _tls.depth = self._d + 1
         self._t0 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
-        if self._sync:
-            _fence()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         t1 = time.perf_counter_ns()
         _tls.depth = self._d
         dur = t1 - self._t0
-        _totals[self.name] = _totals.get(self.name, 0.0) + dur * 1e-9
-        _counts[self.name] = _counts.get(self.name, 0) + 1
+        for name in (self.name, ROOT) if self._d == 0 else (self.name,):
+            _totals[name] = _totals.get(name, 0.0) + dur * 1e-9
+            _counts[name] = _counts.get(name, 0) + 1
         if self._buffer or self._ring:
             tr = None
             if self._trace is not None:
@@ -261,10 +264,21 @@ def span(name: str, **attrs):
     m = mode()
     if m == OFF:
         return _NULL
-    # the sync/ring knobs resolve ONCE per span, not per boundary, so
+    # the ring knob resolves ONCE per span, not per boundary, so
     # enter/exit stay at two perf_counter reads and two dict updates
-    return _Span(name, attrs or None, m == EVENTS, sync_enabled(),
-                 ring_cap() > 0)
+    return _Span(name, attrs or None, m == EVENTS, ring_cap() > 0)
+
+
+def stage(name: str):
+    """``jax.named_scope(name)`` for one of ``STAGES``: the operations
+    traced under it carry the stage in their metadata (``op_name``), and
+    with it into a device trace.  Metadata only — the program, its cache
+    key and its jaxpr are what they were.  In every tracing mode, off
+    included: the name is part of the compiled program, not of a run."""
+    if name not in STAGES:
+        raise ValueError(f"{name!r} is not one of obs.STAGES")
+    jax = sys.modules.get("jax")
+    return _NULL if jax is None else jax.named_scope(name)
 
 
 def instant(name: str, **attrs) -> None:

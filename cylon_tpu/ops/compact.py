@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import config, precision
+from ..obs import stage
 
 
 def permute_mode() -> str:
@@ -79,6 +80,7 @@ def _idx_dtype(cap: int):
     return jnp.int64 if cap > (1 << 31) - 1 else jnp.int32
 
 
+@stage("compact.partition")
 def compact_indices(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(idx, new_count): the first ``new_count`` entries of ``idx`` are the
     row indices where ``mask`` is True, in order; entries past new_count
@@ -96,6 +98,7 @@ def compact_indices(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     return idx, new_count
 
 
+@stage("compact.partition")
 def partition_indices(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(perm, true_count): a full stable partition permutation — mask-True
     row indices first (in order), then every mask-False index (in order).
@@ -147,6 +150,7 @@ def invperm_mode() -> str:
     return config.knob("CYLON_TPU_INVPERM")
 
 
+@stage("compact.permute")
 def inverse_permute(perm: jax.Array, *fields: jax.Array) -> Tuple[jax.Array, ...]:
     """``out[perm[i]] = fields[..][i]`` for each field — the inverse-
     permutation apply (``perm`` must be a permutation of [0, n)).

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from .. import dtypes, precision
 from ..column import Column
+from ..obs import stage
 from . import compact, keys, segments
 
 
@@ -218,27 +219,42 @@ def hash_groupby(cols: Tuple[Column, ...], count,
     """
     cap = cols[0].data.shape[0]
     key_cols = [cols[i] for i in key_idx]
-    operands = keys.build_operands(key_cols, count, cap)
-    perm, sorted_ops = keys.lexsort_indices(operands, cap)
-    new_group = ~keys.rows_equal_adjacent(sorted_ops)
-    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-    start, end = segments.segment_spans(new_group)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    live = iota < count  # padding sorted last -> first `count` sorted rows live
-    num_groups = jnp.where(
-        count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
+    with stage("groupby.sort"):
+        operands = keys.build_operands(key_cols, count, cap)
+        perm, sorted_ops = keys.lexsort_indices(operands, cap)
+    with stage("groupby.boundaries"):
+        new_group = ~keys.rows_equal_adjacent(sorted_ops)
+        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        start, end = segments.segment_spans(new_group)
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        # padding sorted last -> first `count` sorted rows live
+        live = iota < count
+        num_groups = jnp.where(
+            count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
 
-    # group leader positions (first sorted row of each group)
-    leader = jnp.clip(start, 0, cap - 1)
-    group_live = iota[:cap] < num_groups
+        # group leader positions (first sorted row of each group)
+        leader = jnp.clip(start, 0, cap - 1)
+        group_live = iota[:cap] < num_groups
 
     out_cols = []
-    leader_src = jnp.take(perm, leader)  # compose index gathers: one
-    for kc in key_cols:                  # column gather instead of two
-        out_cols.append(kc.take(leader_src, valid_mask=group_live))
+    with stage("groupby.keys"):
+        leader_src = jnp.take(perm, leader)  # compose index gathers: one
+        for kc in key_cols:                  # column gather instead of two
+            out_cols.append(kc.take(leader_src, valid_mask=group_live))
 
     for col_idx, op in aggs:
-        vcol = cols[col_idx].take(perm)
+        with stage("groupby.gather"):
+            vcol = cols[col_idx].take(perm)
+        out_cols.append(_aggregate(op, vcol, live, gid, cap, ddof, start, end,
+                                   new_group, group_live,
+                                   cols[col_idx].dtype))
+    return tuple(out_cols), num_groups
+
+
+def _aggregate(op: AggOp, vcol: Column, live, gid, cap: int, ddof: int,
+               start, end, new_group, group_live, in_dtype) -> Column:
+    """One aggregate of one value column that lies in group order."""
+    with stage("groupby.reduce"):
         vvalid = vcol.validity & live
         if op == AggOp.NUNIQUE:
             vals, cnts = _nunique(vcol, vvalid, gid, cap)
@@ -253,9 +269,7 @@ def hash_groupby(cols: Tuple[Column, ...], count,
         else:
             validity = group_live & (cnts > 0)
         vals = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
-        out_cols.append(Column(vals, validity, None,
-                               _agg_out_dtype(op, cols[col_idx].dtype)))
-    return tuple(out_cols), num_groups
+    return Column(vals, validity, None, _agg_out_dtype(op, in_dtype))
 
 
 def _nunique(vcol: Column, vvalid, gid, cap: int):
@@ -293,38 +307,26 @@ def pipeline_groupby(cols: Tuple[Column, ...], count,
     boundaries come from adjacent comparison in row order — no sort."""
     cap = cols[0].data.shape[0]
     key_cols = [cols[i] for i in key_idx]
-    operands = [keys.padding_operand(cap, count)]
-    for kc in key_cols:
-        operands.extend(keys.column_operands(kc))
-    new_group = ~keys.rows_equal_adjacent(keys.pack_operands(operands))
-    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-    start, end = segments.segment_spans(new_group)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    live = iota < count
-    num_groups = jnp.where(
-        count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
-    leader = jnp.clip(start, 0, cap - 1)
-    group_live = iota < num_groups
+    with stage("groupby.boundaries"):
+        operands = [keys.padding_operand(cap, count)]
+        for kc in key_cols:
+            operands.extend(keys.column_operands(kc))
+        new_group = ~keys.rows_equal_adjacent(keys.pack_operands(operands))
+        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        start, end = segments.segment_spans(new_group)
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        live = iota < count
+        num_groups = jnp.where(
+            count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
+        leader = jnp.clip(start, 0, cap - 1)
+        group_live = iota < num_groups
 
     out_cols = []
-    for kc in key_cols:
-        out_cols.append(kc.take(leader, valid_mask=group_live))
+    with stage("groupby.keys"):
+        for kc in key_cols:
+            out_cols.append(kc.take(leader, valid_mask=group_live))
     for col_idx, op in aggs:
-        vcol = cols[col_idx]
-        vvalid = vcol.validity & live
-        if op == AggOp.NUNIQUE:
-            vals, cnts = _nunique(vcol, vvalid, gid, cap)
-        else:
-            if vcol.is_string:
-                raise TypeError(f"aggregation {op.name} unsupported on strings")
-            vals, cnts = _segment_aggregate(op, vcol.data, vvalid, gid,
-                                            cap, ddof, spans=(start, end),
-                                            boundaries=new_group)
-        if op in (AggOp.COUNT, AggOp.COUNTSUM, AggOp.NUNIQUE):
-            validity = group_live  # a count of zero values is a valid 0
-        else:
-            validity = group_live & (cnts > 0)
-        vals = jnp.where(validity, vals, jnp.zeros((), vals.dtype))
-        out_cols.append(Column(vals, validity, None,
-                               _agg_out_dtype(op, cols[col_idx].dtype)))
+        out_cols.append(_aggregate(op, cols[col_idx], live, gid, cap, ddof,
+                                   start, end, new_group, group_live,
+                                   cols[col_idx].dtype))
     return tuple(out_cols), num_groups

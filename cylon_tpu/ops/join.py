@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..column import Column
 from ..config import JoinType
+from ..obs import stage
 from . import common, compact, segments
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
@@ -104,6 +105,7 @@ def _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
     return lo, matches, perm_r, live_l, unmatched_r, left_key_order
 
 
+@stage("join.emit")
 def _emission(matches, live_l, join_type: JoinType):
     outer_left = join_type in (JoinType.LEFT, JoinType.FULL_OUTER)
     emit = jnp.where(live_l & (matches == 0), jnp.int32(1 if outer_left else 0), matches)
@@ -112,6 +114,7 @@ def _emission(matches, live_l, join_type: JoinType):
     return emit, csum, total
 
 
+@stage("join.ranges")
 def _ranges(cols_l, count_l, cols_r, count_r, left_on, right_on, join_type,
             algorithm: str):
     if algorithm == "hash":
@@ -122,6 +125,75 @@ def _ranges(cols_l, count_l, cols_r, count_r, left_on, right_on, join_type,
             join_type) + (None,)
     return _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
                          join_type)
+
+
+@stage("join.expand")
+def _key_grouped_order(lo, matches, live_l, left_key_order):
+    """Left rows in key order with the matched ones front-packed: (lo,
+    matches, live_l) reordered, and the permutation that did it."""
+    cap_l = lo.shape[0]
+    if left_key_order is None:  # hash path: order by match-range offset
+        order_key = jnp.where(live_l & (matches > 0), lo, _I32_MAX)
+        iota_l = jnp.arange(cap_l, dtype=jnp.int32)
+        _, perm_l = jax.lax.sort((order_key, iota_l), num_keys=1,
+                                 is_stable=True)
+    else:  # sort path: key order is known; partition matched to front
+        lm = jnp.take(live_l & (matches > 0), left_key_order)
+        part, _ = compact.partition_indices(lm)
+        perm_l = jnp.take(left_key_order, part)
+    lo = jnp.take(lo, perm_l)
+    matches = jnp.take(matches, perm_l)
+    live_l = jnp.take(live_l, perm_l)
+    return lo, matches, live_l, perm_l
+
+
+@stage("join.expand")
+def _expansion(lo, matches, perm_r, unmatched_r, perm_l, emit, csum, total,
+               join_type: JoinType, out_capacity: int):
+    """Output slot -> (left row, right row): (lidx, ridx, lvalid, rvalid,
+    out_count) over ``out_capacity`` slots."""
+    k = jnp.arange(out_capacity, dtype=jnp.int32)
+    cap_l = emit.shape[0]
+    base_l = csum - emit
+    if compact.permute_mode() == "sort":
+        # slot -> left row is searchsorted(csum, k, 'right') — csum is
+        # monotone, so slot k's emitter is the count of rows with
+        # csum <= k.  Realized as a sort-merge (sorts beat scatters on
+        # TPU; see compact.count_leq_dense).
+        li = compact.count_leq_dense(csum, out_capacity)
+    else:
+        # scatter + cummax forward fill: each emitting row drops its index
+        # at its first output slot (bases are distinct and ascending),
+        # cummax fills the run — one scan, one scatter
+        iota_l = jnp.arange(cap_l, dtype=jnp.int32)
+        marker = jnp.full((out_capacity,), -1, jnp.int32)
+        marker = marker.at[jnp.where(emit > 0, base_l, out_capacity)].max(
+            iota_l, mode="drop")
+        li = jax.lax.cummax(marker)
+    li = jnp.clip(li, 0, cap_l - 1)
+    base = jnp.take(base_l, li)
+    within = k - base
+    matched = jnp.take(matches, li) > 0
+    r_sorted_pos = jnp.take(lo, li) + within
+    ridx_inner = jnp.take(perm_r, jnp.clip(r_sorted_pos, 0, perm_r.shape[0] - 1))
+
+    in_main = k < total
+    lvalid = in_main
+    rvalid = in_main & matched
+    lidx = li if perm_l is None else jnp.take(perm_l, li)
+    ridx = jnp.where(rvalid, ridx_inner, 0)
+
+    out_count = total
+    if join_type in (JoinType.RIGHT, JoinType.FULL_OUTER):
+        perm_u, m = compact.compact_indices(unmatched_r)
+        tail = k - total
+        in_tail = (k >= total) & (tail < m)
+        ridx_tail = jnp.take(perm_u, jnp.clip(tail, 0, perm_u.shape[0] - 1))
+        ridx = jnp.where(in_tail, ridx_tail, ridx)
+        rvalid = rvalid | in_tail
+        lvalid = lvalid & ~in_tail
+        out_count = total + m
+    return lidx, ridx, lvalid, rvalid, out_count
 
 
 @partial(jax.jit, static_argnames=("left_on", "right_on", "join_type",
@@ -169,62 +241,12 @@ def join_gather(cols_l: Tuple[Column, ...], count_l,
     if key_grouped:
         if join_type != JoinType.INNER:
             raise ValueError("key_grouped join output requires INNER")
-        cap_l = lo.shape[0]
-        if left_key_order is None:  # hash path: order by match-range offset
-            order_key = jnp.where(live_l & (matches > 0), lo, _I32_MAX)
-            iota_l = jnp.arange(cap_l, dtype=jnp.int32)
-            _, perm_l = jax.lax.sort((order_key, iota_l), num_keys=1,
-                                     is_stable=True)
-        else:  # sort path: key order is known; partition matched to front
-            lm = jnp.take(live_l & (matches > 0), left_key_order)
-            part, _ = compact.partition_indices(lm)
-            perm_l = jnp.take(left_key_order, part)
-        lo = jnp.take(lo, perm_l)
-        matches = jnp.take(matches, perm_l)
-        live_l = jnp.take(live_l, perm_l)
+        lo, matches, live_l, perm_l = _key_grouped_order(
+            lo, matches, live_l, left_key_order)
     emit, csum, total = _emission(matches, live_l, join_type)
-
-    k = jnp.arange(out_capacity, dtype=jnp.int32)
-    cap_l = emit.shape[0]
-    base_l = csum - emit
-    if compact.permute_mode() == "sort":
-        # slot -> left row is searchsorted(csum, k, 'right') — csum is
-        # monotone, so slot k's emitter is the count of rows with
-        # csum <= k.  Realized as a sort-merge (sorts beat scatters on
-        # TPU; see compact.count_leq_dense).
-        li = compact.count_leq_dense(csum, out_capacity)
-    else:
-        # scatter + cummax forward fill: each emitting row drops its index
-        # at its first output slot (bases are distinct and ascending),
-        # cummax fills the run — one scan, one scatter
-        iota_l = jnp.arange(cap_l, dtype=jnp.int32)
-        marker = jnp.full((out_capacity,), -1, jnp.int32)
-        marker = marker.at[jnp.where(emit > 0, base_l, out_capacity)].max(
-            iota_l, mode="drop")
-        li = jax.lax.cummax(marker)
-    li = jnp.clip(li, 0, cap_l - 1)
-    base = jnp.take(base_l, li)
-    within = k - base
-    matched = jnp.take(matches, li) > 0
-    r_sorted_pos = jnp.take(lo, li) + within
-    ridx_inner = jnp.take(perm_r, jnp.clip(r_sorted_pos, 0, perm_r.shape[0] - 1))
-
-    in_main = k < total
-    lvalid = in_main
-    rvalid = in_main & matched
-    lidx = li if perm_l is None else jnp.take(perm_l, li)
-    ridx = jnp.where(rvalid, ridx_inner, 0)
-
-    out_count = total
-    if join_type in (JoinType.RIGHT, JoinType.FULL_OUTER):
-        perm_u, m = compact.compact_indices(unmatched_r)
-        tail = k - total
-        in_tail = (k >= total) & (tail < m)
-        ridx_tail = jnp.take(perm_u, jnp.clip(tail, 0, perm_u.shape[0] - 1))
-        ridx = jnp.where(in_tail, ridx_tail, ridx)
-        rvalid = rvalid | in_tail
-        lvalid = lvalid & ~in_tail
-        out_count = total + m
+    lidx, ridx, lvalid, rvalid, out_count = _expansion(
+        lo, matches, perm_r, unmatched_r, perm_l, emit, csum, total,
+        join_type, out_capacity)
 
     # projection pushdown: materialize ONLY the requested output columns
     # (indices into left ++ right), in the requested order — a pruned
@@ -243,7 +265,9 @@ def join_gather(cols_l: Tuple[Column, ...], count_l,
     out = []
     for j in project:
         if j < n_l:
-            out.append(cols_l[j].take(lidx, valid_mask=lvalid))
+            with stage("join.gather_left"):
+                out.append(cols_l[j].take(lidx, valid_mask=lvalid))
         else:
-            out.append(cols_r[j - n_l].take(ridx, valid_mask=rvalid))
+            with stage("join.gather_right"):
+                out.append(cols_r[j - n_l].take(ridx, valid_mask=rvalid))
     return tuple(out), out_count

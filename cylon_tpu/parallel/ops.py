@@ -399,7 +399,7 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
     the ragged collective the bucketed path is used and remembered.
     """
     from .. import resilience
-    from ..table import Table
+    from ..table import Table, host_sync
 
     world = t.num_shards
     ctx = t.ctx
@@ -436,12 +436,14 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
                     targets, counts, stats = _targets_counts_stats(
                         t, key_idx, mode, opts)
                     spec = plane_mod.build_spec(
-                        t.columns, [np.asarray(s) for s in stats], world,
+                        t.columns, [np.asarray(s) for s in
+                                    host_sync(stats, "shuffle.stats")], world,
                         t.shard_capacity)
                 else:
                     targets, counts = _targets_and_counts(t, key_idx, mode,
                                                           opts)
-                cm = np.asarray(counts).reshape(world, world)
+                cm = np.asarray(host_sync(counts, "shuffle.plan")).reshape(
+                    world, world)
                 _, out_cap = shuffle_mod.plan_shuffle(cm)
 
             def rfn(tt, tgt):
@@ -466,12 +468,14 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
             if compress:
                 counts, stats = _counts_stats_for(t, key_idx, mode, opts)
                 spec = plane_mod.build_spec(
-                    t.columns, [np.asarray(s) for s in stats], world,
+                    t.columns, [np.asarray(s) for s in
+                                host_sync(stats, "shuffle.stats")], world,
                     t.shard_capacity)
             else:
                 counts = _counts_for(t, key_idx, mode, opts)
             bucket, out_cap = shuffle_mod.plan_shuffle(
-                np.asarray(counts).reshape(world, world))
+                np.asarray(host_sync(counts, "shuffle.plan")).reshape(
+                    world, world))
 
         # unique closure name: cylint resolves closures module-wide by
         # bare name, and CY109 must see THIS body's spec use, not some

@@ -34,7 +34,7 @@ densely into the remainder — a narrow 10-column i32 table is 11 words
 
 Gated by ``CYLON_TPU_SHUFFLE_PACK`` (auto = on for TPU backends, the
 ``ops/compact.py::permute_mode`` precedent); A/B arms live in
-tools/microbench.py and tools/profile_pipeline.py.
+tools/microbench.py.
 
 Compression (PR 10, ``CYLON_TPU_SHUFFLE_COMPRESS``): an optional stage
 between pack and exchange that shrinks each field to the bits its
@@ -72,6 +72,7 @@ import jax.numpy as jnp
 
 from .. import config, precision
 from ..column import Column
+from ..obs import stage
 
 _UINT_OF = {1: jnp.uint8, 2: jnp.uint16, 4: jnp.uint32, 8: jnp.uint64}
 
@@ -279,6 +280,7 @@ def _field_values(cols: Sequence[Column], spec=None,
     return vals
 
 
+@stage("plane.pack")
 def pack_plane(cols: Sequence[Column], spec=None,
                codes: Optional[Dict[int, jax.Array]] = None) -> jax.Array:
     """Bit-pack the columns' buffers into one uint32[rows, words] plane.
@@ -300,6 +302,7 @@ def pack_plane(cols: Sequence[Column], spec=None,
     return jnp.stack([w for w in words], axis=1)
 
 
+@stage("plane.unpack")
 def unpack_plane(plane: jax.Array, like: Sequence[Column],
                  valid_mask: Optional[jax.Array] = None, spec=None,
                  dicts: Optional[Dict[int, Tuple[jax.Array, ...]]] = None,

@@ -79,7 +79,7 @@ class Table:
 
     @property
     def row_count(self) -> int:
-        return int(jnp.sum(self.row_counts))
+        return int(host_sync(jnp.sum(self.row_counts), "row_count"))
 
     @property
     def column_count(self) -> int:
@@ -216,19 +216,15 @@ class Table:
     def _gathered_columns(self) -> Tuple[List[Column], int]:
         """Collect live rows of every shard into one local column set."""
         if self.num_shards == 1:
-            return list(self.columns), int(self.row_counts[0])
+            return (list(self.columns),
+                    int(column_mod.fetch_d2h(self.row_counts)[0]))
 
         # ONE host transfer for the whole table (a pytree gather); on
         # multi-host the shards live on remote processes, so the gather is
         # a cross-process all-gather (the reference's analog is a
         # gather-to-rank pattern over MPI)
-        if jax.process_count() > 1:
-            from jax.experimental import multihost_utils
-
-            counts_h, cols_h = multihost_utils.process_allgather(
-                (self.row_counts, self.columns), tiled=True)
-        else:
-            counts_h, cols_h = jax.device_get((self.row_counts, self.columns))
+        counts_h, cols_h = column_mod.fetch_d2h(
+            (self.row_counts, self.columns), get=_get_everywhere)
 
         counts = np.asarray(counts_h)
         cap = self.shard_capacity
@@ -248,8 +244,13 @@ class Table:
             d = np.concatenate(parts_d) if parts_d else data[:0]
             v = np.concatenate(parts_v) if parts_v else validity[:0]
             l = np.concatenate(parts_l) if lengths is not None else None
-            out_cols.append(Column(jnp.asarray(d), jnp.asarray(v),
-                                   None if l is None else jnp.asarray(l), col.dtype))
+            with obs_span("table.fetch.h2d"):
+                out_cols.append(Column(
+                    jnp.asarray(d), jnp.asarray(v),
+                    None if l is None else jnp.asarray(l), col.dtype))
+                obs_metrics.counter_add(
+                    "table.fetch.h2d_bytes",
+                    d.nbytes + v.nbytes + (0 if l is None else l.nbytes))
         return out_cols, total
 
     def _addressable_host_shards(self) -> List[Tuple[int, List[Column], int]]:
@@ -263,35 +264,38 @@ class Table:
         # columns here hold HOST (numpy) buffers: the writers only slice and
         # np.asarray them, so wrapping back into device arrays would buy a
         # pointless H2D+D2H round-trip per shard
-        counts = _host_row_counts(self)
-        if self.num_shards == 1:
-            cols_h = jax.device_get(self.columns)
-            cols = [Column(np.asarray(c.data), np.asarray(c.validity),
-                           None if co.lengths is None
-                           else np.asarray(c.lengths), co.dtype)
-                    for co, c in zip(self.columns, cols_h)]
-            return [(0, cols, int(counts[0]))]
-        cap = self.shard_capacity
-        piece_maps = []
-        for col in self.columns:
-            dm = _host_shard_pieces(col.data, cap)
-            vm = _host_shard_pieces(col.validity, cap)
-            lm = (None if col.lengths is None
-                  else _host_shard_pieces(col.lengths, cap))
-            piece_maps.append((dm, vm, lm))
-        out: List[Tuple[int, List[Column], int]] = []
-        for sid in sorted(piece_maps[0][0]):
-            cols = [Column(dm[sid], vm[sid],
-                           None if lm is None else lm[sid], col.dtype)
-                    for col, (dm, vm, lm) in zip(self.columns, piece_maps)]
-            out.append((sid, cols, int(counts[sid])))
-        return out
+        with obs_span("table.fetch", shards=self.num_shards):
+            counts = np.asarray(column_mod.fetch_d2h(self.row_counts,
+                                                     get=_get_everywhere))
+            if self.num_shards == 1:
+                cols_h = column_mod.fetch_d2h(self.columns)
+                cols = [Column(np.asarray(c.data), np.asarray(c.validity),
+                               None if co.lengths is None
+                               else np.asarray(c.lengths), co.dtype)
+                        for co, c in zip(self.columns, cols_h)]
+                return [(0, cols, int(counts[0]))]
+            cap = self.shard_capacity
+            piece_maps = []
+            for col in self.columns:
+                dm = _host_shard_pieces(col.data, cap)
+                vm = _host_shard_pieces(col.validity, cap)
+                lm = (None if col.lengths is None
+                      else _host_shard_pieces(col.lengths, cap))
+                piece_maps.append((dm, vm, lm))
+            out: List[Tuple[int, List[Column], int]] = []
+            for sid in sorted(piece_maps[0][0]):
+                cols = [Column(dm[sid], vm[sid],
+                               None if lm is None else lm[sid], col.dtype)
+                        for col, (dm, vm, lm) in zip(self.columns, piece_maps)]
+                out.append((sid, cols, int(counts[sid])))
+            return out
 
     def to_arrow(self):
         import pyarrow as pa
 
-        cols, total = self._gathered_columns()
-        arrays = [column_mod.to_arrow(c, total) for c in cols]
+        with obs_span("table.fetch", shards=self.num_shards):
+            cols, total = self._gathered_columns()
+            arrays = [column_mod.to_arrow(c, total) for c in cols]
         return pa.table(arrays, names=list(self.names))
 
     def to_pandas(self):
@@ -301,8 +305,10 @@ class Table:
         return self.to_arrow().to_pydict()
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
-        cols, total = self._gathered_columns()
-        return {n: column_mod.to_numpy(c, total) for n, c in zip(self.names, cols)}
+        with obs_span("table.fetch", shards=self.num_shards):
+            cols, total = self._gathered_columns()
+            return {n: column_mod.to_numpy(c, total)
+                    for n, c in zip(self.names, cols)}
 
     def print(self, limit: int = 20) -> None:
         """CSV-ish row dump (reference: table.cpp Print/PrintToOStream)."""
@@ -1070,7 +1076,7 @@ def _host_shard_pieces(arr: jax.Array, cap: int) -> Dict[int, np.ndarray]:
     for sh in arr.addressable_shards:
         idx = sh.index[0] if sh.index else slice(None)
         start = 0 if idx.start is None else int(idx.start)
-        rows = np.asarray(sh.data)
+        rows = column_mod.fetch_d2h(sh.data)
         for k in range(rows.shape[0] // cap):
             sid = (start + k * cap) // cap
             if sid not in out:
@@ -1078,14 +1084,30 @@ def _host_shard_pieces(arr: jax.Array, cap: int) -> Dict[int, np.ndarray]:
     return out
 
 
-def _host_row_counts(t: Table) -> np.ndarray:
-    """Per-shard row counts as a host array, valid on every process."""
+def _get_everywhere(tree):
+    """``tree`` on the host, valid on every process: a cross-process
+    all-gather where the shards live on several, else a device_get."""
     if jax.process_count() > 1:
         from jax.experimental import multihost_utils
 
-        return np.asarray(multihost_utils.process_allgather(
-            t.row_counts, tiled=True))
-    return np.asarray(jax.device_get(t.row_counts))
+        return multihost_utils.process_allgather(tree, tiled=True)
+    return jax.device_get(tree)
+
+
+def host_sync(value, why: str, get=jax.device_get):
+    """The one door through which the main path reads a device value to
+    choose its next program (a row count, a capacity, an exchange plan):
+    span ``host.sync`` and counter ``host.syncs``; returns the value on
+    the host.  The reads of a fetch are its own (``table.fetch.d2h``),
+    not these."""
+    with obs_span("host.sync", why=why):
+        obs_metrics.counter_add("host.syncs")
+        return get(value)
+
+
+def _host_row_counts(t: Table, why: str = "row_counts") -> np.ndarray:
+    """Per-shard row counts as a host array, valid on every process."""
+    return np.asarray(host_sync(t.row_counts, why, _get_everywhere))
 
 
 class _TableIndexer:
@@ -1332,7 +1354,7 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
     cached = cap_cache.get(site)
     if cached is not None:
         out = gather_at(cached)
-        hi = int(np.max(_host_row_counts(out)))
+        hi = int(np.max(_host_row_counts(out, "join.capacity")))
         if hi <= cached:
             # shrink with hysteresis: one skewed join must not inflate
             # this site (and everything sized off its result) forever
@@ -1356,7 +1378,8 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
         counts = _shard_wise(ctx, count_fn, left, right,
                              key=("join_count", cfg.left_on, cfg.right_on, jt,
                                   algo))
-        out_cap = _cap_round(max(1, int(jnp.max(counts))))
+        out_cap = _cap_round(max(1, int(host_sync(jnp.max(counts),
+                                                  "join.count"))))
     cap_cache[site] = out_cap
     return gather_at(out_cap)
 

@@ -105,14 +105,6 @@ def test_timing_shim_is_the_same_substrate(clean_obs):
     assert obs_spans.aggregate_report()["shimmed"][1] == 1
 
 
-def test_trace_sync_knob_fences_without_error(clean_obs):
-    # jax is imported by the harness, so the fence really dispatches
-    with config.knob_env(CYLON_TPU_TRACE="1", CYLON_TPU_TRACE_SYNC="1"):
-        with span("synced"):
-            pass
-    assert obs_spans.events()[0].name == "synced"
-
-
 # ---------------------------------------------------------------------------
 # export round trip
 # ---------------------------------------------------------------------------

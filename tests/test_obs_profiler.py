@@ -1,0 +1,278 @@
+"""The program's spans and stage names on the profiler's clock.
+
+Pinned here: an ``obs.span`` is a host event of a ``jax.profiler`` trace
+(and none at all with tracing off); every name of ``obs.STAGES`` is in the
+lowered text of the program it belongs to; the fetch counts the bytes it
+moved; the main path's host syncs are counted and steady; ``obs.root`` is
+the sum of the depth-0 spans; the HBM gauges read ``memory_stats()``; and
+``tools/trace_report.py --device`` reduces a recorded chip trace to the
+stage table kept beside it.
+"""
+import importlib.util
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cylon_tpu import Table, config, obs
+from cylon_tpu.config import JoinType
+from cylon_tpu.obs import metrics as obs_metrics
+from cylon_tpu.obs import spans as obs_spans
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+FIXTURES = os.path.join(HERE, "fixtures")
+ROWS = 1 << 10
+
+
+@pytest.fixture()
+def clean_obs():
+    obs_spans.reset()
+    obs_metrics.reset()
+    yield
+    obs_spans.reset()
+    obs_metrics.reset()
+
+
+@pytest.fixture(scope="module")
+def trace_report():
+    spec = importlib.util.spec_from_file_location(
+        "trace_report", os.path.join(HERE, "..", "tools", "trace_report.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _pair(ctx, rows, seed=3):
+    rng = np.random.default_rng(seed)
+    return tuple(
+        Table.from_numpy(["k", v], [rng.integers(0, rows, rows),
+                                    rng.random(rows)], ctx=ctx)
+        for v in ("a", "b"))
+
+
+# ---------------------------------------------------------------------------
+# spans as host events of the profiler's trace
+# ---------------------------------------------------------------------------
+
+def test_span_is_a_host_event_of_the_profiler_trace(clean_obs, tmp_path,
+                                                    trace_report):
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("x.y", k=1) as s:
+            s.set(rows=5)
+            jnp.arange(4).block_until_ready()
+        with config.knob_env(CYLON_TPU_TRACE="0"):
+            off = obs.span("x.off", k=1)
+            with off:
+                pass
+    assert off is obs_spans._NULL
+    from bench.trace_reduce import find_xplane
+
+    path = find_xplane(str(tmp_path))
+    host = trace_report.load_xplane(path)["host"]
+    assert [e[0] for e in host if e[0].startswith("x.")] == ["x.y"]
+    # the attributes travel as the event's statistics
+    data = jax.profiler.ProfileData.from_file(path)
+    (event,) = [ev for plane in data.planes for line in plane.lines
+                for ev in line.events if ev.name == "x.y"]
+    assert dict(event.stats) == {"k": 1, "rows": 5}
+    assert trace_report.is_program_span("x.y")
+    assert not trace_report.is_program_span("PjitFunction(sort)")
+
+
+def test_obs_root_is_the_sum_of_depth0_spans(clean_obs):
+    with obs.span("a.outer"):
+        with obs.span("a.inner"):
+            pass
+    with obs.span("b.outer"):
+        pass
+    with obs.span("a.outer"):
+        pass
+    rep = obs_spans.aggregate_report()
+    assert rep[obs_spans.ROOT][1] == 3
+    assert rep[obs_spans.ROOT][0] == pytest.approx(
+        rep["a.outer"][0] + rep["b.outer"][0], rel=1e-12)
+    assert rep["a.inner"][0] <= rep["a.outer"][0]
+
+
+# ---------------------------------------------------------------------------
+# stage names inside the programs
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lowered(ctx4):
+    """Lowered text (with locations) of each kind of program, at 2^10
+    rows, made on first use."""
+    from jax.sharding import PartitionSpec as P
+
+    from cylon_tpu.context import CylonContext
+    from cylon_tpu.ops import compact, groupby, join, sort
+    from cylon_tpu.parallel import shuffle
+    from cylon_tpu.utils import shard_map
+
+    texts = {}
+
+    def text(kind):
+        if kind in texts:
+            return texts[kind]
+        left, right = _pair(CylonContext.Init(), ROWS)
+        count = left.row_counts[0]
+        if kind == "join":
+            low = join.join_gather.lower(
+                left.columns, count, right.columns, right.row_counts[0],
+                left_on=(0,), right_on=(0,), join_type=JoinType.INNER,
+                out_capacity=2 * ROWS)
+        elif kind == "groupby":
+            low = groupby.hash_groupby.lower(
+                left.columns, count, key_idx=(0,),
+                aggs=((1, groupby.AggOp.SUM), (1, groupby.AggOp.COUNT)))
+        elif kind == "sort":
+            low = jax.jit(lambda cols, n: sort.sort_rows(cols, n, (0,))
+                          ).lower(left.columns, count)
+        elif kind == "compact":
+            low = jax.jit(lambda mask, perm, x: (
+                compact.compact_indices(mask),
+                compact.inverse_permute(perm, x))).lower(
+                    left.columns[0].validity,
+                    jnp.arange(ROWS, dtype=jnp.int32), left.columns[1].data)
+        else:  # the packed exchange, on the 4-device mesh
+            sharded = _pair(ctx4, ROWS)[0]
+
+            def body(t):
+                targets = jnp.zeros((ROWS // 4,), jnp.int32)
+                cols, total = shuffle.shuffle_shard(
+                    t.columns, t.row_counts[0], targets, 4, ROWS // 4, ROWS)
+                return Table(cols, jnp.reshape(total, (1,)), t.names, ctx4)
+
+            with config.knob_env(CYLON_TPU_SHUFFLE_PACK="packed"):
+                low = jax.jit(shard_map(
+                    body, mesh=ctx4.mesh, in_specs=P("p"), out_specs=P("p"),
+                    check_vma=False)).lower(sharded)
+        texts[kind] = low.as_text(debug_info=True)
+        return texts[kind]
+
+    return text
+
+
+_PROGRAM_OF = {"join": "join", "groupby": "groupby", "sort": "sort",
+               "compact": "compact", "plane": "exchange"}
+
+
+@pytest.mark.parametrize("stage", obs.STAGES)
+def test_stage_name_is_in_its_program(lowered, stage):
+    # a location reads "jit(join_gather)/join.ranges/sort"; inside a
+    # shard_map body the stack starts anew: "plane.pack/shift_left"
+    text = lowered(_PROGRAM_OF[stage.split(".")[0]])
+    assert re.search(rf'[/"]{re.escape(stage)}/', text)
+
+
+def test_stage_refuses_a_name_outside_the_list():
+    assert len(obs.STAGES) <= 16 and len(set(obs.STAGES)) == len(obs.STAGES)
+    with pytest.raises(ValueError):
+        obs.stage("join.nowhere")
+
+
+# ---------------------------------------------------------------------------
+# the fetch and the host syncs
+# ---------------------------------------------------------------------------
+
+def _buffer_bytes(cols, rows=None) -> int:
+    return sum(np.asarray(b[:rows]).nbytes for c in cols
+               for b in (c.data, c.validity))
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_fetch_counts_the_bytes_it_moved(clean_obs, local_ctx, ctx4, shards):
+    table = _pair(local_ctx if shards == 1 else ctx4, 200)[0]
+    out = table.to_numpy()
+    assert len(out["k"]) == 200
+    counters = obs_metrics.snapshot()["counters"]
+    live = _buffer_bytes(table.columns, 200) if shards == 1 else 200 * (
+        8 + 1 + 8 + 1)
+    if shards == 1:
+        moved = table.row_counts.nbytes + live
+        assert "table.fetch.h2d_bytes" not in counters
+    else:
+        # every shard's whole buffers, then the re-uploaded live rows again
+        moved = table.row_counts.nbytes + _buffer_bytes(table.columns) + live
+        assert counters["table.fetch.h2d_bytes"] == live
+    assert counters["table.fetch.bytes"] == moved
+    rep = obs_spans.aggregate_report()
+    assert rep["table.fetch"][1] == 1
+    assert rep["table.fetch.d2h"][0] <= rep["table.fetch"][0]
+    assert "host.sync" not in rep  # the fetch's reads are its own
+
+
+def test_the_query_syncs_the_same_number_of_times(clean_obs, local_ctx):
+    """bench/drivers/join_gbs: run + fetch at 2^12 rows.  The first query
+    sizes the join (``join.count``), the later ones check the cached
+    capacity: each reads the device once."""
+    from bench.drivers import join_gbs
+
+    rows = 1 << 12
+    rng = np.random.default_rng(11)
+    data = {side: {"k": rng.integers(0, rows, rows), v: rng.random(rows)}
+            for side, v in (("left", "a"), ("right", "b"))}
+    state = join_gbs.build(local_ctx, {}, data)
+    syncs, fetched = [], []
+    for _ in range(3):
+        before = obs_metrics.counter_value("host.syncs")
+        fetched.append(join_gbs.fetch(join_gbs.run(state, {})))
+        syncs.append(obs_metrics.counter_value("host.syncs") - before)
+    assert syncs[1] == syncs[2] >= 1
+    assert all(len(f["l_k"]) == len(fetched[0]["l_k"]) > 0 for f in fetched)
+    assert obs_spans.aggregate_report()["host.sync"][1] == sum(syncs)
+
+
+def test_hbm_gauges_read_memory_stats_of_the_fullest_device(clean_obs,
+                                                             monkeypatch):
+    class Dev:
+        def __init__(self, stats):
+            self._stats = stats
+
+        def memory_stats(self):
+            return self._stats
+
+    x = jnp.zeros((256,), jnp.float32)
+    # the CPU backend reports none: the live-array sum stands alone
+    assert obs_metrics.record_hbm_watermark() >= x.nbytes
+    assert "hbm.peak_bytes" not in obs_metrics.snapshot()["gauges"]
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        Dev({"bytes_in_use": 10, "peak_bytes_in_use": 70}),
+        Dev({"bytes_in_use": 30, "peak_bytes_in_use": 50}), Dev(None)])
+    assert obs_metrics.record_hbm_watermark() >= x.nbytes
+    gauges = obs_metrics.snapshot()["gauges"]
+    assert gauges["hbm.bytes_in_use"] == 30 and gauges["hbm.peak_bytes"] == 70
+    assert gauges["hbm.live_bytes"] >= x.nbytes
+
+
+# ---------------------------------------------------------------------------
+# tools/trace_report.py --device on a recorded chip trace
+# ---------------------------------------------------------------------------
+
+def test_device_report_of_the_recorded_chip_trace(trace_report, capsys):
+    with open(os.path.join(FIXTURES, "join_gbs_2p16.expected.json")) as f:
+        expected = json.load(f)
+    rep = trace_report.device_report(
+        os.path.join(FIXTURES, "join_gbs_2p16.xplane.pb"))
+    assert rep["chips"] == expected["chips"]
+    for key in ("window_s", "busy_s", "idle_s_total"):
+        assert rep[key] == pytest.approx(expected[key], rel=1e-9)
+    for table in ("stages_s", "idle_s"):
+        assert rep[table] == pytest.approx(expected[table], rel=1e-9)
+    assert rep["spans"] == {k: [v[0], pytest.approx(v[1], rel=1e-9)]
+                            for k, v in expected["spans"].items()}
+    # every stage the query runs has device time, and its spans are there
+    innermost = {name.rsplit("/", 1)[-1] for name in rep["stages_s"]}
+    assert {s for s in obs.STAGES
+            if not s.startswith("plane.")} <= innermost
+    assert {"table.distributed_join", "join.gather", "host.sync",
+            "table.groupby", "table.distributed_sort", "table.sort",
+            "table.fetch", "table.fetch.d2h"} <= set(rep["spans"])
+    assert trace_report.main(["--device", os.path.join(
+        FIXTURES, "join_gbs_2p16.xplane.pb")]) == 0
+    out = capsys.readouterr().out
+    assert "join.gather_right" in out and "table.fetch.d2h" in out

@@ -20,7 +20,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def make_fused_pipeline(out_cap: int, algo: str = "sort"):
-    """The fused program this model and tools/profile_pipeline.py lower:
+    """The fused program this model lowers:
     key_grouped inner join + boundary-scan pipeline group-by, with
     projection pushdown skipping the unused right-key output column's
     out_cap-sized gather.  Reference driver shape:

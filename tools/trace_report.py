@@ -22,15 +22,24 @@ them would launder bad numbers into good-looking tables.
 (totals, skew table, SLO rows) so CI and the battery can assert on
 content instead of grepping human text.
 
+``--device`` reads a ``jax.profiler`` trace instead (the directory the
+profiler wrote, or its ``.xplane.pb``): device self seconds by kernel
+stage (``cylon_tpu.obs.STAGES``, from each operation's ``op_name``;
+operations under no stage as ``<program>/unscoped``), the idlest chip's
+idle seconds by the innermost program span open on the host, and the
+program's spans found on the profiler's clock.
+
 Usage:
     python tools/trace_report.py TRACE.json [METRICS.json] [--top K]
                                  [--json]
+    python tools/trace_report.py --device PROFILE_DIR [--json]
 """
 from __future__ import annotations
 
 import argparse
 import json
 import os
+import re
 import sys
 from collections import defaultdict
 from typing import Dict, List, Optional, Tuple
@@ -258,8 +267,6 @@ def _sibling_metrics(trace_path: str,
     """Resolve the metrics artifact beside a trace (explicit path wins)."""
     if metrics_path is not None:
         return metrics_path if os.path.exists(metrics_path) else None
-    import re
-
     d, base = os.path.split(trace_path)
     head, _, rest = base.partition(".")
     cands = [
@@ -421,12 +428,224 @@ def print_report(trace_path: str, metrics_path: "str | None",
                   f"{int(c.get('elastic.resume', 0))}")
 
 
+# ---------------------------------------------------------------------------
+# --device: a jax.profiler trace, by kernel stage and by program span
+# ---------------------------------------------------------------------------
+
+def _pb_fields(buf):
+    """(field number, value) pairs of one protobuf message: varints as
+    int, length-delimited fields as a memoryview, fixed ones skipped.
+    The profiler's xplane file needs no more, and ``ProfileData`` does
+    not surface the statistics of the event METADATA, where each device
+    operation's ``op_name`` (stat ``tf_op``) lives."""
+    i, n = 0, len(buf)
+
+    def varint():
+        nonlocal i
+        v = shift = 0
+        while True:
+            b = buf[i]
+            i += 1
+            v |= (b & 0x7F) << shift
+            shift += 7
+            if b < 0x80:
+                return v
+
+    while i < n:
+        key = varint()
+        num, wire = key >> 3, key & 7
+        if wire == 0:
+            yield num, varint()
+        elif wire == 2:
+            size = varint()
+            yield num, buf[i:i + size]
+            i += size
+        elif wire in (1, 5):
+            i += 8 if wire == 1 else 4
+        else:
+            raise ValueError(f"wire type {wire} in an xplane file")
+
+
+def _pb_text(view) -> str:
+    return bytes(view).decode("utf-8", "replace")
+
+
+def load_xplane(path: str) -> dict:
+    """{"devices": {plane: [[program, op_name, start_s, dur_s], ...]},
+    "host": [[name, start_s, dur_s], ...]} on the trace's clock: every
+    operation of each chip's "XLA Ops" line with the jitted program that
+    was running ("XLA Modules") and its ``tf_op``, and every host event."""
+    import bisect
+
+    with open(path, "rb") as fh:
+        space = memoryview(fh.read())
+    devices, host = {}, []
+    for num, plane in _pb_fields(space):
+        if num != 1:
+            continue
+        name, lines, event_meta, stat_names = "", [], {}, {}
+        for f, v in _pb_fields(plane):
+            if f == 2:
+                name = _pb_text(v)
+            elif f == 3:
+                lines.append(v)
+            elif f in (4, 5):  # map<int64, X{Event,Stat}Metadata>
+                entry = dict(_pb_fields(v))
+                (event_meta if f == 4 else stat_names)[entry[1]] = entry[2]
+        on_device = name.startswith("/device:TPU:")
+        if not (on_device or name.startswith("/host:")):
+            continue
+        stat_names = {k: _pb_text(dict(_pb_fields(v)).get(2, b""))
+                      for k, v in stat_names.items()}
+        names = {}  # metadata id -> (event name, tf_op)
+        for mid, meta in event_meta.items():
+            ev_name, tf_op = "", ""
+            for f, v in _pb_fields(meta):
+                if f == 2:
+                    ev_name = _pb_text(v)
+                elif f == 5:
+                    stat = dict(_pb_fields(v))
+                    if stat_names.get(stat.get(1)) == "tf_op":
+                        tf_op = _pb_text(stat.get(5, b"")).rstrip(":")
+            names[mid] = (ev_name, tf_op)
+        by_line = {}
+        for line in lines:
+            line_name, t0_ns, events = "", 0, []
+            for f, v in _pb_fields(line):
+                if f == 2:
+                    line_name = _pb_text(v)
+                elif f == 3:
+                    t0_ns = v
+                elif f == 4:
+                    ev = dict(_pb_fields(v))
+                    events.append((ev[1], ev.get(2, 0), ev.get(3, 0)))
+            by_line.setdefault(line_name, []).extend(
+                (names[mid], t0_ns * 1e-9 + off * 1e-12, dur * 1e-12)
+                for mid, off, dur in events)
+        if not on_device:
+            host.extend([n[0], s, d] for evs in by_line.values()
+                        for n, s, d in evs)
+            continue
+        modules = sorted((s, s + d, n[0].split("(")[0])
+                         for n, s, d in by_line.get("XLA Modules", []))
+        starts = [m[0] for m in modules]
+        ops = []
+        for (_, tf_op), s, d in by_line.get("XLA Ops", []):
+            i = bisect.bisect_right(starts, s) - 1
+            program = modules[i][2] if i >= 0 and s < modules[i][1] else "?"
+            ops.append([program, tf_op, s, d])
+        devices[name] = ops
+    return {"devices": devices, "host": sorted(host, key=lambda e: e[1])}
+
+
+_PROGRAM_SPAN = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z0-9_]+)+$")
+
+
+def is_program_span(name: str) -> bool:
+    """The program's spans are dotted lower-case names (``join.gather``,
+    ``table.fetch.d2h``); the runtime's own host events never are.
+    ``bench.*`` is the harness's."""
+    return bool(_PROGRAM_SPAN.match(name))
+
+
+def device_report(path: str) -> dict:
+    """The ``--device`` report as one object.  Device seconds are self
+    times (each instant goes to the innermost operation), averaged over
+    the chips; idle seconds are the idlest chip's, each instant given to
+    the span that opened last among those open on the host."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.trace_reduce import find_xplane, self_times as op_self, union
+    from cylon_tpu.obs.spans import STAGES
+
+    if os.path.isdir(path):
+        path = find_xplane(path)
+    trace = load_xplane(path)
+    if not trace["devices"]:
+        raise ValueError(f"{path}: no TPU device plane with an XLA Ops line")
+    spans = [e for e in trace["host"] if is_program_span(e[0])]
+
+    def label(program: str, tf_op: str) -> str:
+        stages = [c for c in tf_op.split("/") if c in STAGES]
+        return "/".join(stages) if stages else f"{program}/unscoped"
+
+    stages_s, busy = {}, {}
+    n = len(trace["devices"])
+    for plane, ops in trace["devices"].items():
+        for name, secs in op_self(
+                [[label(p, t), s, d] for p, t, s, d in ops]).items():
+            stages_s[name] = stages_s.get(name, 0.0) + secs / n
+        busy[plane] = union([[s, s + d] for _, _, s, d in ops])
+    busy_s = {p: sum(e - s for s, e in b) for p, b in busy.items()}
+    idlest = min(busy_s, key=busy_s.get)
+    every = [iv for b in busy.values() for iv in b] + \
+        [[s, s + d] for _, s, d in spans]
+    lo, hi = min(s for s, _ in every), max(e for _, e in every)
+    idle_s = {}
+    edge = lo
+    for s, e in busy[idlest] + [[hi, hi]]:
+        if s > edge:
+            for name, secs in _by_innermost_span(spans, edge, s).items():
+                idle_s[name] = idle_s.get(name, 0.0) + secs
+        edge = max(edge, e)
+    host_spans = {}
+    for name, _, dur in spans:
+        count, secs = host_spans.get(name, (0, 0.0))
+        host_spans[name] = (count + 1, secs + dur)
+    return {"trace": path, "chips": n, "window_s": hi - lo,
+            "busy_s": sum(busy_s.values()) / n,
+            "idlest_chip": idlest, "idle_s_total": hi - lo - busy_s[idlest],
+            "stages_s": dict(sorted(stages_s.items(), key=lambda kv: -kv[1])),
+            "idle_s": dict(sorted(idle_s.items(), key=lambda kv: -kv[1])),
+            "spans": {k: list(v) for k, v in sorted(host_spans.items())}}
+
+
+def _by_innermost_span(spans: List[list], lo: float, hi: float) -> dict:
+    """{span name: seconds of [lo, hi] in which it was the span that
+    opened last among those open}; "-" where none was."""
+    open_here = [(s, s + d, name) for name, s, d in spans
+                 if s < hi and s + d > lo]
+    cuts = sorted({lo, hi, *(t for s, e, _ in open_here for t in (s, e)
+                             if lo < t < hi)})
+    out: Dict[str, float] = {}
+    for a, b in zip(cuts, cuts[1:]):
+        inner = max((sp for sp in open_here if sp[0] <= a and sp[1] >= b),
+                    default=(0, 0, "-"))
+        out[inner[2]] = out.get(inner[2], 0.0) + (b - a)
+    return out
+
+
+def print_device_report(rep: dict) -> None:
+    print(f"device trace: {rep['trace']}  chips={rep['chips']}  "
+          f"window {rep['window_s']:.6f} s  busy {rep['busy_s']:.6f} s")
+    print(f"\ndevice self seconds by stage:\n{'stage':52s} {'seconds':>12s} "
+          f"{'busy %':>7s}")
+    busy = rep["busy_s"] or 1.0
+    for name, secs in rep["stages_s"].items():
+        print(f"{name:52s} {secs:12.6f} {100 * secs / busy:6.2f}%")
+    unscoped = sum(v for k, v in rep["stages_s"].items()
+                   if k.endswith("/unscoped"))
+    print(f"{'(all unscoped)':52s} {unscoped:12.6f} "
+          f"{100 * unscoped / busy:6.2f}%")
+    print(f"\nidle seconds of {rep['idlest_chip']} by innermost program "
+          f"span ({rep['idle_s_total']:.6f} s idle):")
+    for name, secs in rep["idle_s"].items():
+        print(f"{name:52s} {secs:12.6f}")
+    print(f"\nprogram spans on the profiler's clock:\n{'span':52s} "
+          f"{'count':>7s} {'seconds':>12s}")
+    for name, (count, secs) in rep["spans"].items():
+        print(f"{name:52s} {count:7d} {secs:12.6f}")
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="trace_report",
         description="top-K self-time + collective/bytes summary of a "
                     "cylon_tpu.obs trace export")
-    ap.add_argument("trace", help="trace JSON written by obs.export")
+    ap.add_argument("trace", help="trace JSON written by obs.export (with "
+                                  "--device: a jax.profiler directory or "
+                                  ".xplane.pb)")
     ap.add_argument("metrics", nargs="?", default=None,
                     help="metrics JSON (default: sibling of the trace)")
     ap.add_argument("--top", type=int, default=15)
@@ -445,7 +664,18 @@ def main(argv=None) -> int:
     ap.add_argument("--trace-id", default=None,
                     help="request trace to analyze with --critical-path "
                          "(default: the serve.request root)")
+    ap.add_argument("--device", action="store_true",
+                    help="TRACE is a jax.profiler trace: device seconds by "
+                         "kernel stage, idle seconds by program span")
     args = ap.parse_args(argv)
+    if args.device:
+        rep = device_report(args.trace)
+        if args.json:
+            json.dump(rep, sys.stdout, indent=1)
+            print()
+        else:
+            print_device_report(rep)
+        return 0
     if args.json:
         rep = report_dict(args.trace, args.metrics, args.top, args.plan,
                           critical_path=args.critical_path,
