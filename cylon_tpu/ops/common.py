@@ -75,7 +75,7 @@ def combined_sorted_runs(cols_a: Sequence[Column], count_a,
     for ia, ib in zip(key_a, key_b):
         combined = concat_columns(cols_a[ia], cols_b[ib])
         operands.extend(keys.column_operands(combined))
-    perm, sorted_ops = keys.lexsort_indices(operands, n)
+    perm, sorted_ops, _ = keys.lexsort_indices(operands, n)
     new_group = ~keys.rows_equal_adjacent(sorted_ops)
     is_run_end = jnp.concatenate([new_group[1:], jnp.ones((1,), bool)])
     pos = jnp.arange(n, dtype=jnp.int32)

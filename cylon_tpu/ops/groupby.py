@@ -6,7 +6,8 @@ group-id assignment + per-group State streaming) and pipeline group-by
 (groupby/pipeline_groupby.cpp:29-115 — boundary scan over a pre-sorted key
 column).  A hash table is the wrong shape for a vector machine; instead:
 
-1. lexsort rows by the key columns (one fused ``lax.sort``),
+1. lexsort rows by the key columns (one fused ``lax.sort``, which carries
+   the value columns as payload operands into group order),
 2. dense group ids via adjacent equality + prefix sum,
 3. each aggregation is a masked ``jax.ops.segment_*`` keyed by group id.
 
@@ -219,9 +220,20 @@ def hash_groupby(cols: Tuple[Column, ...], count,
     """
     cap = cols[0].data.shape[0]
     key_cols = [cols[i] for i in key_idx]
+    # the value columns ride the sort into group order (keys.pack_payload)
+    values = {i: cols[i] for i, _ in aggs}
+    with stage("groupby.gather"):
+        buffers, columns = jax.tree.flatten(values)
+        lanes, layout = keys.pack_payload(buffers)
     with stage("groupby.sort"):
         operands = keys.build_operands(key_cols, count, cap)
-        perm, sorted_ops = keys.lexsort_indices(operands, cap)
+        perm, sorted_ops, lanes = keys.lexsort_indices(operands, cap, lanes)
+    with stage("groupby.gather"):
+        values = jax.tree.unflatten(columns, [
+            jnp.take(buffer, perm, axis=0, mode="clip") if rode is None
+            else rode
+            for buffer, rode in zip(buffers,
+                                    keys.unpack_payload(lanes, layout))])
     with stage("groupby.boundaries"):
         new_group = ~keys.rows_equal_adjacent(sorted_ops)
         gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
@@ -243,10 +255,8 @@ def hash_groupby(cols: Tuple[Column, ...], count,
             out_cols.append(kc.take(leader_src, valid_mask=group_live))
 
     for col_idx, op in aggs:
-        with stage("groupby.gather"):
-            vcol = cols[col_idx].take(perm)
-        out_cols.append(_aggregate(op, vcol, live, gid, cap, ddof, start, end,
-                                   new_group, group_live,
+        out_cols.append(_aggregate(op, values[col_idx], live, gid, cap, ddof,
+                                   start, end, new_group, group_live,
                                    cols[col_idx].dtype))
     return tuple(out_cols), num_groups
 
@@ -275,7 +285,7 @@ def _aggregate(op: AggOp, vcol: Column, live, gid, cap: int, ddof: int,
 def _nunique(vcol: Column, vvalid, gid, cap: int):
     """Distinct non-null values per group via a (gid, value) lexsort."""
     ops = [~vvalid, gid] + keys.column_operands(vcol, with_validity=False)
-    perm, sorted_ops = keys.lexsort_indices(ops, cap)
+    perm, sorted_ops, _ = keys.lexsort_indices(ops, cap)
     eq = keys.rows_equal_adjacent(sorted_ops)
     # sorted_ops are packed words: recover fields through the permutation
     svalid = jnp.take(vvalid, perm)

@@ -17,15 +17,22 @@ flat sortable operands:
 Row equality (multi-column, the job of TableRowComparator) becomes adjacent
 comparison of these operands after a lexsort, which then yields dense group
 ids via a prefix sum — the backbone of groupby/unique/set-ops/joins here.
+
+Rows that have to follow a sort's permutation ride a sort as non-key
+*payload* operands (``pack_payload`` / ``lexsort_indices`` /
+``unpack_payload``) and come back sorted: on a TPU a 32-bit lane through a
+sort costs a tenth of the same lane through an index vector.
 """
 from __future__ import annotations
 
+import math
 from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..column import Column
+from ..obs import metrics as obs_metrics
 from . import compact, radix
 
 
@@ -174,20 +181,94 @@ def _pack_encoded(enc: Sequence[Tuple[jax.Array, int]]) -> List[jax.Array]:
     return out
 
 
-def lexsort_indices(operands: Sequence[jax.Array], capacity: int) -> Tuple[jax.Array, List[jax.Array]]:
+#: Most 32-bit payload lanes one sort carries beside its keys; what is past
+#: it moves through ``take(perm)``.  PERF.md Findings PR 26 has the compile
+#: and device seconds that chose it.
+_MAX_PAYLOAD_LANES = 12
+
+
+def _row_lanes(buffer: jax.Array) -> int:
+    """32-bit lanes one row of ``buffer`` fills."""
+    row_bytes = buffer.dtype.itemsize * math.prod(buffer.shape[1:])
+    return -(-row_bytes // 4)
+
+
+def pack_payload(buffers: Sequence[jax.Array]):
+    """Payload operands for ``lexsort_indices`` that carry ``buffers`` (each
+    of the sort's ``capacity`` rows) through the sort, so that no index
+    vector is built for them.  Returns ``(lanes, layout)``; ``layout`` is
+    for ``unpack_payload`` and holds ``None`` for a buffer that cannot ride
+    and is left to ``take(perm)``: a 2-D byte matrix, whatever is past
+    ``_MAX_PAYLOAD_LANES``, and everything under ``CYLON_TPU_SORT=radix``.
+
+    1-D ``bool`` buffers (validity) ride as one bit each, 32 to a ``uint32``
+    word; every other 1-D buffer rides as it is: a sort moves a non-key
+    operand as bits, so NaN payloads, -0.0 and float64 (emulated on a TPU)
+    come back exact."""
+    budget = 0 if radix.sort_mode() == "radix" else _MAX_PAYLOAD_LANES
+    flat = [i for i, b in enumerate(buffers) if b.ndim == 1]
+    bits = [i for i in flat if buffers[i].dtype == jnp.bool_][:32 * budget]
+    budget -= -(-len(bits) // 32)
+    layout: list = [None] * len(buffers)
+    lanes: List[jax.Array] = []
+    for at in range(0, len(bits), 32):
+        word = jnp.zeros(buffers[bits[at]].shape, jnp.uint32)
+        for bit, i in enumerate(bits[at:at + 32]):
+            word = word | (buffers[i].astype(jnp.uint32) << jnp.uint32(bit))
+            layout[i] = (len(lanes), bit)
+        lanes.append(word)
+    for i in flat:
+        if layout[i] is None and buffers[i].dtype != jnp.bool_ \
+                and _row_lanes(buffers[i]) <= budget:
+            budget -= _row_lanes(buffers[i])
+            layout[i] = (len(lanes), None)
+            lanes.append(buffers[i])
+    rode = sum(_row_lanes(lane) for lane in lanes)
+    obs_metrics.counter_add("sort.payload_lanes", rode)
+    obs_metrics.counter_add("sort.take_lanes", sum(
+        _row_lanes(b) for b, where in zip(buffers, layout) if where is None))
+    return lanes, tuple(layout)
+
+
+def unpack_payload(sorted_lanes: Sequence[jax.Array], layout) -> list:
+    """The buffers ``pack_payload`` packed, in sorted order; ``None`` where
+    the layout holds ``None``."""
+    out = []
+    for where in layout:
+        if where is None:
+            out.append(None)
+            continue
+        lane, bit = where
+        out.append(sorted_lanes[lane] if bit is None else
+                   (sorted_lanes[lane] >> jnp.uint32(bit)) & 1 != 0)
+    return out
+
+
+def lexsort_indices(operands: Sequence[jax.Array], capacity: int,
+                    payload: Sequence[jax.Array] = (),
+                    ) -> Tuple[jax.Array, List[jax.Array], List[jax.Array]]:
     """Stable lexicographic argsort over bit-packed operands.  Returns
-    (permutation, sorted PACKED operands) — the packed words support
-    adjacency/equality tests (rows_equal_adjacent, dense_group_ids) but
-    not per-field access; gather original fields through the permutation
-    when field values are needed.
+    (permutation, sorted PACKED operands, sorted payload) — the packed
+    words support adjacency/equality tests (rows_equal_adjacent,
+    dense_group_ids) but not per-field access.  A buffer whose rows are
+    needed in sorted order rides a sort as ``payload``: 1-D arrays of
+    ``capacity`` rows (``pack_payload`` builds them), never compared,
+    returned as ``take(x, permutation)`` would return them with no gather.
 
     Fast path: when every key field plus a row index fits 64 bits (e.g.
     padding + validity + a 32-bit key + up to 30 index bits — the
     hash-partitioned join/groupby shape), the sort runs over one or two
-    u32 words with the index in the low bits: no payload operand, and
-    uniqueness makes stability free.  The words stay 32-bit — narrow
-    mode's zero-64-bit-arrays guarantee holds (64-bit ops are emulated on
-    TPU)."""
+    u32 words with the index in the low bits: no index operand, and
+    uniqueness makes stability free; payload is operands 2... of the same
+    sort.  The words stay 32-bit — narrow mode's zero-64-bit-arrays
+    guarantee holds (64-bit ops are emulated on TPU).
+
+    General path: a stable sort of the packed words and a row index, as
+    without payload; the payload then rides a sort of its own, keyed on
+    the rows' ranks (``_ride_ranks``).  A lane added to the many-word
+    stable sort costs the chip's compiler several times what it costs in
+    a one-key sort (PERF.md Findings PR 26)."""
+    payload = tuple(payload)
     enc = [_ordered_unsigned(o) for o in operands]
     total_bits = sum(w for _, w in enc)
     idx_bits = compact.index_bits(capacity)
@@ -209,28 +290,42 @@ def lexsort_indices(operands: Sequence[jax.Array], capacity: int) -> Tuple[jax.A
             append(bits.astype(jnp.uint32), w)
         append(jnp.arange(capacity, dtype=jnp.uint32), idx_bits)
 
-        use_radix = radix.sort_mode() == "radix"
-        if total_bits + idx_bits <= 32:  # everything landed in lo
-            if use_radix:
-                _, s_lo = radix.radix_sort_packed(
-                    None, lo, idx_bits, idx_bits + total_bits)
-            else:
-                s_lo = jax.lax.sort(lo, is_stable=False)  # keys are unique
-            perm = (s_lo & jnp.uint32((1 << idx_bits) - 1)).astype(jnp.int32)
-            return perm, [s_lo >> jnp.uint32(idx_bits)]
-        if use_radix:
+        words = (lo,) if total_bits + idx_bits <= 32 else (hi, lo)
+        index_mask = jnp.uint32((1 << idx_bits) - 1)
+        if radix.sort_mode() == "radix":
+            # the A/B arm sorts words alone: payload goes through its perm
             s_hi, s_lo = radix.radix_sort_packed(
-                hi, lo, idx_bits, idx_bits + total_bits)
-        else:
-            s_hi, s_lo = jax.lax.sort((hi, lo), num_keys=2, is_stable=False)
-        perm = (s_lo & jnp.uint32((1 << idx_bits) - 1)).astype(jnp.int32)
-        return perm, [s_hi, s_lo >> jnp.uint32(idx_bits)]
+                hi if len(words) == 2 else None, lo, idx_bits,
+                idx_bits + total_bits)
+            perm = (s_lo & index_mask).astype(jnp.int32)
+            moved = [jnp.take(x, perm) for x in payload]
+        else:  # keys are unique: no stability needed
+            sorted_all = jax.lax.sort(words + payload, num_keys=len(words),
+                                      is_stable=False)
+            s_hi, s_lo = sorted_all[0], sorted_all[len(words) - 1]
+            perm = (s_lo & index_mask).astype(jnp.int32)
+            moved = list(sorted_all[len(words):])
+        s_lo = s_lo >> jnp.uint32(idx_bits)
+        return perm, ([s_lo] if len(words) == 1 else [s_hi, s_lo]), moved
     packed = _pack_encoded(enc)
     iota = jnp.arange(capacity, dtype=jnp.int32)
     sorted_all = jax.lax.sort(tuple(packed) + (iota,),
                               num_keys=len(packed), is_stable=True)
     perm = sorted_all[-1]
-    return perm, list(sorted_all[:-1])
+    return perm, list(sorted_all[:-1]), _ride_ranks(perm, payload)
+
+
+def _ride_ranks(perm: jax.Array, payload: Tuple[jax.Array, ...]):
+    """``[take(x, perm) for x in payload]`` by two one-key sorts and no
+    gather: ``perm`` sorted with a row index gives each row its rank (the
+    inverse permutation), and the payload rides one unstable sort keyed on
+    the ranks (the shape of ``compact.inverse_permute``)."""
+    if not payload:
+        return []
+    iota = jnp.arange(perm.shape[0], dtype=jnp.int32)
+    _, rank = jax.lax.sort((perm, iota), num_keys=1, is_stable=False)
+    return list(jax.lax.sort((rank,) + payload, num_keys=1,
+                             is_stable=False)[1:])
 
 
 def rows_equal_adjacent(sorted_operands: Sequence[jax.Array]) -> jax.Array:

@@ -31,7 +31,7 @@ def unique(cols: Tuple[Column, ...], count, key_idx: Tuple[int, ...],
     cap = cols[0].data.shape[0]
     key_cols = [cols[i] for i in key_idx]
     operands = keys.build_operands(key_cols, count, cap)
-    perm, sorted_ops = keys.lexsort_indices(operands, cap)
+    perm, sorted_ops, _ = keys.lexsort_indices(operands, cap)
     live_sorted = jnp.arange(cap, dtype=jnp.int32) < count
 
     new_group = ~keys.rows_equal_adjacent(sorted_ops)

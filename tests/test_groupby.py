@@ -8,6 +8,7 @@ import pandas as pd
 import pytest
 
 from cylon_tpu import Table
+from tests.test_setops_sort_unique import PAYLOAD_CASES, _payload_frame
 
 
 def _check(t, df, ops, ddof=0):
@@ -58,6 +59,34 @@ def test_groupby_int_values(local_ctx, rng):
     assert (g["sum_v"].to_numpy() == grp.sum().sort_index().to_numpy()).all()
     assert (g["min_v"].to_numpy() == grp.min().sort_index().to_numpy()).all()
     assert (g["max_v"].to_numpy() == grp.max().sort_index().to_numpy()).all()
+
+
+@pytest.mark.parametrize("case", PAYLOAD_CASES)
+def test_hash_groupby_matches_pandas(local_ctx, rng, case):
+    """The value columns arrive in group order with their nulls, whether
+    they rode the group-by's sort or went through its permutation."""
+    df, cap = _payload_frame(case, rng)
+    t = Table.from_pandas(df, ctx=local_ctx, capacity=cap)
+    numeric = [c for c in df.columns if c not in ("k", "i", "b", "s")]
+    aggs = {c: ["sum", "count", "max"] for c in numeric}
+    named = {f"{op}_{c}": (c, op) for c in numeric for op in aggs[c]}
+    if "s" in df:
+        aggs["s"] = ["nunique"]
+        named["nunique_s"] = ("s", "nunique")
+    got = t.groupby(["k", "i"], aggs).to_pandas()
+    exp = df.groupby(["k", "i"], dropna=False).agg(**named).reset_index()
+    exp = exp.sort_values(["k", "i"], na_position="first")
+    assert list(got.columns) == ["k", "i"] + list(named)
+    assert len(got) == len(exp)
+    for name, (c, op) in named.items():
+        want = exp[name].to_numpy(dtype=float)
+        if op == "sum":     # a sum of no values is null here, 0 in pandas
+            want = np.where(exp[f"count_{c}"] == 0, np.nan, want)
+        np.testing.assert_allclose(got[name].to_numpy(dtype=float), want,
+                                   rtol=1e-6, err_msg=name)
+    for key in ("k", "i"):
+        np.testing.assert_array_equal(got[key].to_numpy(dtype=float),
+                                      exp[key].to_numpy(dtype=float))
 
 
 def test_groupby_nunique_local(local_ctx):

@@ -24,7 +24,7 @@ def _device_perm(cols_np, count, cap, ascending=None):
         cols.append(colmod.from_numpy(data, validity=valid))
     ops = keys.build_operands(cols, jnp.asarray(count, jnp.int32), cap,
                               ascending=ascending)
-    perm, sorted_ops = keys.lexsort_indices(ops, cap)
+    perm, sorted_ops, _ = keys.lexsort_indices(ops, cap)
     return np.asarray(perm), [np.asarray(o) for o in sorted_ops]
 
 
@@ -182,3 +182,105 @@ def test_lexsort_64bit_boundary(rng):
             [jnp.asarray(o) for o in sorted_ops]))[:count]
         exp_eq = [False] + [got[i] == got[i - 1] for i in range(1, count)]
         assert eq.tolist() == exp_eq, f"cap={cap}"
+
+
+def _bits(x):
+    """A payload's bytes, row by row, so NaN payloads and -0.0 compare."""
+    x = np.asarray(x)
+    return x.view(np.uint8).reshape(x.shape[0], -1)
+
+
+_NAN_BITS = {np.float32: np.uint32(0x7FC0BEEF),
+             np.float64: np.uint64(0x7FF80000DEADBEEF)}
+
+
+def _payload(dtype, cap, rng):
+    if dtype is np.bool_:
+        return rng.random(cap) > 0.5
+    if np.issubdtype(dtype, np.floating):
+        x = rng.standard_normal(cap).astype(dtype)
+        x[1], x[2] = -0.0, 0.0
+        nan = _NAN_BITS[dtype]
+        x.view(nan.dtype)[[3, 5]] = nan, nan | nan.dtype.type(1 << 31)
+        return x
+    info = np.iinfo(dtype)
+    return rng.integers(info.min, info.max, cap, dtype=dtype)
+
+
+@pytest.mark.parametrize("payload_dtype", [np.bool_, np.int32, np.int64,
+                                           np.float32, np.float64])
+@pytest.mark.parametrize("key_dtype", [np.int16, np.int32, np.float64],
+                         ids=["one_word", "two_words", "general"])
+def test_lexsort_payload_rides_bit_for_bit(key_dtype, payload_dtype, rng):
+    """Each shape of the sort returns its payload as ``take(x, perm)`` would,
+    bit for bit, through ``pack_payload`` and back; and the permutation and
+    the sorted words are those of the sort without payload."""
+    import jax.numpy as jnp
+
+    from cylon_tpu import column as colmod
+    from cylon_tpu.ops import keys
+
+    cap, count = 64, 50
+    key = colmod.from_numpy(rng.integers(-9, 9, cap).astype(key_dtype),
+                            validity=rng.random(cap) > 0.2)
+    ops = keys.build_operands([key], jnp.asarray(count, jnp.int32), cap)
+    buffers = [jnp.asarray(_payload(payload_dtype, cap, rng)),
+               jnp.asarray(_payload(payload_dtype, cap, rng))]
+
+    perm0, words0, none = keys.lexsort_indices(ops, cap)
+    assert none == []
+    assert [str(w.dtype) for w in words0] == {       # the shape that ran
+        np.int16: ["uint32"], np.int32: ["uint32"] * 2,
+        np.float64: ["uint32", "uint64"]}[key_dtype]
+    lanes, layout = keys.pack_payload(buffers)
+    assert None not in layout
+    assert len(lanes) == (1 if payload_dtype is np.bool_ else 2)
+    perm, words, lanes = keys.lexsort_indices(ops, cap, lanes)
+    np.testing.assert_array_equal(np.asarray(perm), np.asarray(perm0))
+    for w, w0 in zip(words, words0, strict=True):
+        np.testing.assert_array_equal(np.asarray(w), np.asarray(w0))
+    for moved, x in zip(keys.unpack_payload(lanes, layout), buffers,
+                        strict=True):
+        assert moved.dtype == x.dtype
+        np.testing.assert_array_equal(_bits(moved),
+                                      _bits(np.asarray(x)[np.asarray(perm)]))
+
+
+def test_pack_payload_leaves_to_the_take_what_cannot_ride(monkeypatch):
+    """A byte matrix never rides; lanes past the constant do not; validity
+    bits share words of 32; under the radix arm nothing rides.  The counters
+    say how many 32-bit lanes went each way."""
+    import jax.numpy as jnp
+
+    from cylon_tpu.obs import metrics
+    from cylon_tpu.ops import keys
+
+    cap = 16
+    flags = [jnp.arange(cap) % (i + 2) == 0 for i in range(33)]
+    matrix = jnp.zeros((cap, 12), jnp.uint8)
+    wide = [jnp.arange(cap, dtype=jnp.int64)] * keys._MAX_PAYLOAD_LANES
+
+    def counted(buffers):
+        before = [metrics.counter_value(f"sort.{k}_lanes")
+                  for k in ("payload", "take")]
+        lanes, layout = keys.pack_payload(buffers)
+        after = [metrics.counter_value(f"sort.{k}_lanes")
+                 for k in ("payload", "take")]
+        return lanes, layout, [a - b for a, b in zip(after, before)]
+
+    lanes, layout, (rode, took) = counted(flags + [matrix])
+    assert [lane.dtype for lane in lanes] == [jnp.uint32, jnp.uint32]
+    assert layout[-1] is None and None not in layout[:-1]
+    assert (rode, took) == (2, 3)
+    for flag, back in zip(flags, keys.unpack_payload(lanes, layout)):
+        np.testing.assert_array_equal(np.asarray(back), np.asarray(flag))
+
+    lanes, layout, (rode, took) = counted(flags[:1] + wide)
+    budget = keys._MAX_PAYLOAD_LANES - 1           # one word of validity
+    assert rode == 1 + budget // 2 * 2 and rode + took == 1 + 2 * len(wide)
+    assert [w is None for w in layout[1:]] == [
+        i >= budget // 2 for i in range(len(wide))]
+
+    monkeypatch.setenv("CYLON_TPU_SORT", "radix")
+    lanes, layout, (rode, took) = counted(flags[:2] + wide[:1])
+    assert lanes == [] and set(layout) == {None} and (rode, took) == (0, 4)

@@ -25,12 +25,12 @@ def _operands_int(vals: np.ndarray, count: int, capacity: int):
 
 def _ab(operands, capacity, monkeypatch, bits="1", scan=None):
     monkeypatch.delenv("CYLON_TPU_SORT", raising=False)
-    perm_cmp, ops_cmp = keys.lexsort_indices(operands, capacity)
+    perm_cmp, ops_cmp, _ = keys.lexsort_indices(operands, capacity)
     monkeypatch.setenv("CYLON_TPU_SORT", "radix")
     monkeypatch.setenv("CYLON_TPU_RADIX_BITS", bits)
     if scan is not None:
         monkeypatch.setenv("CYLON_TPU_RADIX_SCAN", scan)
-    perm_rad, ops_rad = keys.lexsort_indices(operands, capacity)
+    perm_rad, ops_rad, _ = keys.lexsort_indices(operands, capacity)
     np.testing.assert_array_equal(np.asarray(perm_cmp), np.asarray(perm_rad))
     assert len(ops_cmp) == len(ops_rad)
     for a, b in zip(ops_cmp, ops_rad):

@@ -59,6 +59,60 @@ def test_local_sort_strings(local_ctx):
     assert t.to_pydict()["s"] == sorted(vals)
 
 
+def _nullable(rng, values, share=0.25):
+    """``values`` as float64 / object with a share of them missing."""
+    missing = rng.random(len(values)) < share
+    if values.dtype.kind in "OU":
+        return np.where(missing, None, values.astype(object))
+    return np.where(missing, np.nan, values).astype(
+        values.dtype if values.dtype.kind == "f" else np.float64)
+
+
+def _payload_frame(case, rng):
+    """(frame, capacity) for one case of what rides the sort and what is
+    left to the take; ``k`` and ``i`` are the keys, with nulls in ``k``."""
+    n = {"count_0": 0, "count_capacity": 64}.get(case, 50)
+    cols = {"k": _nullable(rng, rng.integers(0, 5, n).astype(np.float64)),
+            "i": rng.integers(-3, 3, n),
+            "v": _nullable(rng, rng.random(n).astype(np.float32)),
+            "b": rng.random(n) > 0.5}
+    if case == "string_beside":       # byte matrix takes, the rest rides
+        cols["s"] = _nullable(rng, np.array([f"w{j % 7}" for j in range(n)]))
+    elif case == "33_columns":        # two words of validity bits
+        for j in range(31):
+            cols[f"c{j}"] = _nullable(rng, rng.random(n).astype(np.float32))
+    elif case == "wider_than_lanes":  # 64-bit lanes past the sort's budget
+        for j in range(8):
+            cols[f"w{j}"] = _nullable(rng, rng.random(n))
+            cols[f"x{j}"] = rng.integers(-2 ** 40, 2 ** 40, n)
+    return pd.DataFrame(cols), 64
+
+
+PAYLOAD_CASES = ["nulls", "string_beside", "33_columns", "wider_than_lanes",
+                 "count_0", "count_capacity"]
+
+
+@pytest.mark.parametrize("case", PAYLOAD_CASES)
+def test_sort_rows_matches_pandas(local_ctx, rng, case):
+    """Every buffer comes back in the key order, nulls included, whether it
+    rode the sort or went through the permutation."""
+    from cylon_tpu.ops import keys
+
+    df, cap = _payload_frame(case, rng)
+    t = Table.from_pandas(df, ctx=local_ctx, capacity=cap)
+    lanes, layout = keys.pack_payload(
+        [b for c in t.columns for b in (c.data, c.validity, c.lengths)
+         if b is not None])
+    assert (None in layout) == (case in (
+        "string_beside", "33_columns", "wider_than_lanes"))
+    assert len(lanes) > len(df.columns) / 32
+    got = t.sort(["k", "i"], ascending=[False, True]).to_pandas()
+    exp = df.sort_values(["k", "i"], ascending=[False, True],
+                         na_position="first", kind="stable")
+    pd.testing.assert_frame_equal(got, exp.reset_index(drop=True),
+                                  check_dtype=False)
+
+
 @pytest.mark.parametrize("world", [2, pytest.param(4, marks=pytest.mark.slow), pytest.param(8, marks=pytest.mark.slow)])
 def test_distributed_sort(request, rng, world):
     ctx = request.getfixturevalue(f"ctx{world}")

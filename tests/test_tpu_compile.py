@@ -16,6 +16,8 @@ tests steer it with what exists — ``precision.on_tpu`` patched true resolves
 every "auto" default the way the chip does — and with no option of the
 program.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -27,6 +29,7 @@ from jax.sharding import SingleDeviceSharding
 from cylon_tpu import dtypes, precision
 from cylon_tpu.column import Column
 from cylon_tpu.context import PARTITION_AXIS
+from cylon_tpu.obs import STAGES
 from cylon_tpu.ops import compact, pallas_kernels, pallas_scan, segments
 
 ROWS = 1 << 24
@@ -134,23 +137,65 @@ def test_entry_step_compiles(one_chip, as_on_chip, log2_rows):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("program", ["sort_rows", "unique", "sort_permute"])
+def _gather_stages(compiled) -> set:
+    """The stage (``obs.STAGES``, else the whole ``op_name``) of every
+    gather instruction in the compiled program."""
+    stages = set()
+    for line in compiled.as_text().splitlines():
+        if re.search(r"= \S+ gather\(", line):
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            named = [part for part in op_name.split("/") if part in STAGES]
+            stages.add(named[-1] if named else op_name)
+    return stages
+
+
+@pytest.mark.parametrize("program", [
+    "sort_rows", "hash_groupby", "unique", "sort_permute",
+    pytest.param("sort_rows_int64", marks=pytest.mark.slow),
+    pytest.param("hash_groupby_int64", marks=pytest.mark.slow)])
 def test_local_kernels_compile(one_chip, as_on_chip, program):
+    """The rows ride the sorts: ``sort_rows`` compiles with no gather at
+    all, ``hash_groupby`` with none but the compactions through the group
+    leaders and the segment ends.  The ``int64`` cases are the benchmark
+    cell's shapes (int64 key, float64 or float32 values): minutes each,
+    for the table of forms in PERF.md, by hand."""
+    from cylon_tpu.ops import groupby as groupby_mod
     from cylon_tpu.ops import sort as sort_mod
     from cylon_tpu.ops import unique as unique_mod
 
     count = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     kv = _kv(ROWS, one_chip)
+    if program.endswith("_int64"):
+        kv = (_col(ROWS, jnp.int64, dtypes.int64, one_chip),
+              _col(ROWS, jnp.float64, dtypes.double, one_chip))
     assert compact.permute_mode() == "sort"
-    if program == "sort_rows":
+    gathers = None
+    if program.startswith("sort_rows"):
+        if program.endswith("_int64"):     # [count desc, key asc], 4 columns
+            kv = (kv[0], _col(ROWS, jnp.float32, dtypes.float_, one_chip),
+                  _col(ROWS, jnp.float32, dtypes.float_, one_chip),
+                  _col(ROWS, jnp.int32, dtypes.int64, one_chip))
+        by, ascending = ((3, 0), (False, True)) if len(kv) == 4 else (
+            (0,), (True,))
         lowered = jax.jit(lambda c, n: sort_mod.sort_rows(
-            c, n, (0,), (True,), True)).lower(kv, count)
+            c, n, by, ascending, True)).lower(kv, count)
+        gathers = set()
+    elif program.startswith("hash_groupby"):
+        lowered = groupby_mod.hash_groupby.lower(
+            kv, count, key_idx=(0,), aggs=tuple(
+                (1, op) for op in (groupby_mod.AggOp.SUM,
+                                   groupby_mod.AggOp.MEAN,
+                                   groupby_mod.AggOp.COUNT)))
+        gathers = {"groupby.keys", "groupby.reduce"}
     elif program == "unique":
         lowered = unique_mod.unique.lower(kv, count, (0,), "first")
     else:
         mask = jax.ShapeDtypeStruct((ROWS,), jnp.bool_, sharding=one_chip)
         lowered = jax.jit(compact.partition_indices).lower(mask)
-    assert _device_bytes(lowered.compile()) < HBM_BYTES
+    compiled = lowered.compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    if gathers is not None:
+        assert _gather_stages(compiled) <= gathers
 
 
 @pytest.mark.parametrize("with_string", [False, True],
