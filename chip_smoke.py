@@ -39,8 +39,9 @@ KERNEL_ROWS = 1 << 20
 MAIN_ROWS = 1 << 24
 WIDE_ROWS = 1 << 20
 # XLA:TPU lays every row of a RaggedAllToAll out as one 128-lane vector
-# (512 B in, 512 B out) and halts on a send operand of 2^31 such bytes, so an
-# exchange moves fewer than 2^22 rows per shard (PERF.md, PR 22)
+# (512 B in, 512 B out) and halts on a send operand of 2^31 such bytes
+# (PERF.md, PR 22); a shard of 2^22 rows and more goes in rounds
+# (parallel/shuffle.py, PR 27), this phase keeps to the one collective
 SHUFFLE_ROWS = 1 << 21
 # so four chips take 2^23 rows per side in total: 2^21 a shard, and the join's
 # output shards (~2^21 rows, rounded up) stay under the limit as well

@@ -18,11 +18,21 @@ def allreduce_sum(x):
     return jax.lax.psum(x, PARTITION_AXIS)
 
 
+def _sums_only(x) -> bool:
+    """XLA:TPU lowers an all-reduce of a 64-bit type only for sums: its
+    extremes are gathered and reduced on every shard instead."""
+    return jnp.dtype(x.dtype).itemsize == 8
+
+
 def allreduce_min(x):
+    if _sums_only(x):
+        return jnp.min(allgather(x), axis=0)
     return jax.lax.pmin(x, PARTITION_AXIS)
 
 
 def allreduce_max(x):
+    if _sums_only(x):
+        return jnp.max(allgather(x), axis=0)
     return jax.lax.pmax(x, PARTITION_AXIS)
 
 

@@ -205,27 +205,6 @@ def _probe_ragged(ctx) -> bool:
     return True
 
 
-#: XLA:TPU lays every row of a RaggedAllToAll operand out as one 128-lane
-#: u32 vector, whatever the plane's width, and a send operand of 2^31 bytes
-#: in that layout halts the core with a DMA bounds check (v5e, libtpu
-#: 0.0.34: 4,190,000 rows per shard pass, 2^22 halt — PERF.md, PR 22)
-_RAGGED_ROW_BYTES = 128 * 4
-_RAGGED_OPERAND_LIMIT = 1 << 31
-
-
-def _check_ragged_operand(t) -> None:
-    """Refuse, classified, the exchange the chip would halt on."""
-    if precision.on_tpu() \
-            and t.shard_capacity * _RAGGED_ROW_BYTES >= _RAGGED_OPERAND_LIMIT:
-        raise CylonError(
-            Code.CapacityError,
-            f"shuffle of {t.shard_capacity} rows per shard: the TPU's "
-            f"RaggedAllToAll takes fewer than "
-            f"{_RAGGED_OPERAND_LIMIT // _RAGGED_ROW_BYTES} — use more "
-            f"shards, or the out-of-core engine (cylon_tpu.exec) to "
-            f"stream the table in passes")
-
-
 def _ragged_enabled(ctx) -> bool:
     """Capability check, cached PER CONTEXT: a process that touches a
     CPU-mesh context first (probe -> False) and later a TPU context must
@@ -262,20 +241,30 @@ def _row_bytes(cols, packed: bool, spec=None) -> int:
 
 
 def _record_exchange(cols, packed: bool, family: str,
-                     rows_exchanged: int, spec=None) -> None:
+                     rows_exchanged: int, spec=None, rounds: int = 1,
+                     operand_rows=None) -> None:
     """Account one collective exchange that actually ran: data-collective
     launch count (1 packed vs one per buffer — the PR-3 budget goldens'
     1-vs-13 on the canonical 6-column frame), the counts all_gather, and
     global bytes moved.  Under a compression spec, ``shuffle.bytes_sent``
     records the bytes that really traveled; the uncompressed-minus-sent
     delta lands in ``shuffle.bytes_saved`` and the per-exchange ratio in
-    the ``shuffle.compress_ratio`` gauge."""
+    the ``shuffle.compress_ratio`` gauge.  ``shuffle.rounds`` counts the
+    rounds the exchange went in and ``shuffle.operand_bytes`` the bytes of
+    its send operands in the layout the collective moves: the ragged
+    family's ``operand_rows`` at one 128-lane vector a row, the bucketed
+    family's padded buckets, which are the bytes sent."""
     launches = 1 if packed else shuffle_mod.buffer_count(cols)
     bytes_sent = rows_exchanged * _row_bytes(cols, packed, spec)
     obs_metrics.counter_add("shuffle.exchanges")
     obs_metrics.counter_add("shuffle.collective_launches", launches)
     obs_metrics.counter_add("shuffle.counts_gathers")
     obs_metrics.counter_add("shuffle.bytes_sent", bytes_sent)
+    obs_metrics.counter_add("shuffle.rounds", rounds)
+    obs_metrics.counter_add(
+        "shuffle.operand_bytes",
+        bytes_sent if operand_rows is None
+        else operand_rows * launches * shuffle_mod.RAGGED_ROW_BYTES)
     if spec is not None:
         raw_bytes = rows_exchanged * _row_bytes(cols, packed)
         obs_metrics.counter_add("shuffle.bytes_saved",
@@ -424,7 +413,6 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
         # decodes under a stale layout.
         compress = pack and plane_mod.compress_enabled()
         if _ragged_enabled(ctx):
-            _check_ragged_operand(t)
             with obs_spans.span("shuffle.plan", mode=mode, world=world,
                       family="ragged"):
                 # sized here, inside the retried exchange — the task-graph
@@ -445,21 +433,28 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
                 cm = np.asarray(host_sync(counts, "shuffle.plan")).reshape(
                     world, world)
                 _, out_cap = shuffle_mod.plan_shuffle(cm)
+                # the rounds of a shard over the collective's operand
+                # limit, sized from the count matrix already here
+                rounds, operand_rows = shuffle_mod.plan_rounds(
+                    cm, t.shard_capacity)
 
             def rfn(tt, tgt):
                 cols, total = shuffle_mod.shuffle_shard_ragged(
-                    tt.columns, tgt, world, out_cap, spec=spec)
+                    tt.columns, tgt, world, out_cap, spec=spec,
+                    rounds=rounds)
                 return Table(cols, jnp.reshape(total, (1,)), names, ctx)
 
             with obs_spans.span("shuffle.exchange", packed=pack, family="ragged",
-                      world=world, compressed=spec is not None):
+                      world=world, compressed=spec is not None,
+                      rounds=rounds):
                 out = _shard_map(ctx, rfn,
                                  ("shuffle-ragged", key_idx, out_cap, pack,
-                                  spec),
+                                  spec, rounds),
                                  _shapes_key(t))(t, targets)
             # ragged moves exactly the rows that exist
             _record_exchange(t.columns, pack, "ragged", int(cm.sum()),
-                             spec=spec)
+                             spec=spec, rounds=rounds,
+                             operand_rows=world * operand_rows)
             return out
 
         with obs_spans.span("shuffle.plan", mode=mode, world=world, family="bucketed"):

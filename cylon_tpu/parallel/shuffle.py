@@ -39,6 +39,7 @@ import jax.numpy as jnp
 from ..column import Column
 from ..obs import spans as obs_spans
 from ..ops import compact as compact_mod
+from ..status import Code, CylonError
 from . import collectives
 from . import plane as plane_mod
 
@@ -252,8 +253,124 @@ def ragged_plan(cm, me):
     return recv_sizes, output_offsets, total
 
 
+#: XLA:TPU lays every row of a RaggedAllToAll operand out as one 128-lane
+#: u32 vector, whatever the plane's width, and a send operand of 2^31 bytes
+#: in that layout halts the core with a DMA bounds check (v5e, libtpu
+#: 0.0.34: 4,190,000 rows per shard pass, 2^22 halt — PERF.md, PR 22).  A
+#: shard under the limit goes through one collective; a larger one goes in
+#: rounds whose send operand and receive buffer are both half the limit.
+RAGGED_ROW_BYTES = 128 * 4
+_RAGGED_OPERAND_LIMIT = 1 << 31
+
+
+def ragged_round_quota(shard_capacity: int, world: int):
+    """Rows a source sends to ONE destination in a round of the ragged
+    exchange, or None where a shard of ``shard_capacity`` rows is under the
+    collective's operand limit and goes whole.  ``world`` quotas fill a
+    round's send operand (a source's rows for every destination) and its
+    receive buffer (every source's rows for one destination), so neither
+    can pass half the limit whatever the targets are."""
+    if shard_capacity * RAGGED_ROW_BYTES < _RAGGED_OPERAND_LIMIT:
+        return None
+    return _RAGGED_OPERAND_LIMIT // RAGGED_ROW_BYTES // 2 // world
+
+
+def plan_rounds(cm, shard_capacity: int) -> Tuple[int, int]:
+    """Host-side sizing from the count matrix the plan already fetched:
+    (rounds, rows in each shard's send operands over the whole exchange).
+    Round r moves rows [r * quota, (r + 1) * quota) of every (src, dst)
+    segment, so the fullest segment sets the count."""
+    import numpy as np
+
+    world = cm.shape[0]
+    quota = ragged_round_quota(shard_capacity, world)
+    if quota is None:
+        return 1, shard_capacity
+    rounds = max(1, -(-int(np.max(cm)) // quota))
+    return rounds, rounds * world * quota
+
+
+def ragged_round_plan(cm, me, r, quota: int):
+    """Rank ``me``'s sizing of round ``r``: (send_sizes, recv_sizes,
+    output_offsets, landing).  The round moves rows [r * quota,
+    (r + 1) * quota) of every (src, dst) segment of the count matrix;
+    ``output_offsets[t]`` is where my slice lands in receiver t's round
+    buffer (after every lower-ranked source's), ``landing`` where that
+    buffer's rows go in my dense output: after all I received in earlier
+    rounds.  Closed form in ``r``, so a loop over rounds needs no carried
+    offsets; shared by the device kernel and the host-side emulation."""
+    world = cm.shape[0]
+    moved = jnp.clip(cm - r * quota, 0, quota).astype(jnp.int32)
+    src_rank = jnp.arange(world, dtype=jnp.int32)
+    output_offsets = jnp.sum(
+        jnp.where((src_rank < me)[:, None], moved, 0), axis=0).astype(jnp.int32)
+    landing = jnp.sum(jnp.minimum(cm[:, me], r * quota), dtype=jnp.int32)
+    return moved[me], moved[:, me], output_offsets, landing
+
+
+def _ragged_exchange(sorted_buf: jax.Array, cm: jax.Array, me,
+                     out_capacity: int, rounds):
+    """One 2-D buffer whose rows are grouped by target, through
+    ``ragged_all_to_all`` into a dense ``[out_capacity, ...]`` result.
+
+    Under the operand limit: one collective over the whole buffer, rows
+    landing in source-rank order.  Over it: ``rounds`` collectives (a
+    count the caller sized with ``plan_rounds`` from the same count
+    matrix), each sending ``quota`` rows at most of every destination's
+    segment from an operand of ``world * quota`` rows and receiving into a
+    buffer of the same size, which lands in the result after the earlier
+    rounds' rows: round-major, source-rank order inside a round."""
+    cap, width = sorted_buf.shape
+    world = cm.shape[0]
+    counts = cm[me]
+    starts = (jnp.cumsum(counts, dtype=jnp.int32) - counts).astype(jnp.int32)
+    quota = ragged_round_quota(cap, world)
+    if quota is None:
+        recv_sizes, output_offsets, _ = ragged_plan(cm, me)
+        out = jnp.zeros((out_capacity, width), sorted_buf.dtype)
+        return collectives.ragged_all_to_all(
+            sorted_buf, out, starts, counts, output_offsets, recv_sizes)
+    if rounds is None:
+        raise CylonError(
+            Code.CapacityError,
+            f"ragged exchange of {cap} rows per shard goes in rounds: the "
+            f"caller sizes them with plan_rounds from the count matrix")
+    slots = jnp.arange(world, dtype=jnp.int32) * quota
+
+    def one_round(r, landed):
+        send_sizes, recv_sizes, output_offsets, landing = ragged_round_plan(
+            cm, me, r, quota)
+        # a slice that would run off the buffer's end starts earlier
+        # instead, and the rows wanted lie ``want - begin`` into it; a
+        # destination with nothing left is given an offset inside the
+        # operand all the same
+        want = starts + r * quota
+        begin = jnp.clip(want, 0, cap - quota)
+        operand = jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(sorted_buf, begin[t], quota)
+            for t in range(world)])
+        input_offsets = jnp.where(send_sizes > 0, slots + want - begin, 0)
+        got = collectives.ragged_all_to_all(
+            operand, jnp.zeros_like(operand), input_offsets, send_sizes,
+            output_offsets, recv_sizes)
+        # the whole buffer lands, zeros past its rows included: the next
+        # round overwrites them, the last leaves the tail zero
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(lane, got[:, w], landing, 0)
+            for w, lane in enumerate(landed))
+
+    # the result is carried a lane at a time: a [rows, width] carry that
+    # is updated by rows gets a row-major layout on a TPU, a whole
+    # 128-lane tile to a row (9.7 GB for three words of 2^24 rows)
+    landed = jax.lax.fori_loop(0, rounds, one_round, tuple(
+        jnp.zeros((out_capacity + world * quota,), sorted_buf.dtype)
+        for _ in range(width)))
+    return jnp.stack([lane[:out_capacity] for lane in landed], axis=1)
+
+
 def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
-                         world: int, out_capacity: int, spec=None):
+                         world: int, out_capacity: int, spec=None,
+                         rounds=None):
     """Skew-proof shard-local shuffle body over ``lax.ragged_all_to_all``.
 
     Where ``shuffle_shard`` pads every (src,dst) pair to one static bucket
@@ -263,6 +380,13 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
     contiguous, the all-gathered count matrix yields send/recv sizes and
     the packed output offsets, and XLA's RaggedAllToAll moves the slices.
     Received rows land front-packed, so no compaction gather is needed.
+
+    A shard over the collective's operand limit (``ragged_round_quota``)
+    goes in ``rounds`` rounds, a static count the caller takes from
+    ``plan_rounds`` over the count matrix it planned ``out_capacity``
+    from; a smaller shard goes whole and ``rounds`` is not read.  Rows of
+    a received shard are in source-rank order when it went whole and in
+    round-major order otherwise: no caller may rely on either.
 
     ``targets`` is taken as an argument (not recomputed) so the caller can
     reuse the targets pass that sized ``out_capacity`` — the reference
@@ -275,19 +399,15 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
     the plane); per-buffer — one collective and one sort-gather per
     buffer.  Bit-identical outputs either way.
     """
-    cap = cols[0].data.shape[0]
-
     counts = target_counts(targets, world)
     perm_t = _perm_by_target(targets, world)
-    input_offsets = jnp.concatenate(
-        [jnp.zeros((1,), jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)[:-1]])
 
     # on-device count-matrix exchange (the 6-int header protocol's job);
     # trace-time child spans, like shuffle_shard's (cylint CY101-clean)
     with obs_spans.span("shuffle.counts_gather", world=world):
         cm = collectives.allgather(counts, axis=0).reshape(world, world)
     me = collectives.my_rank()
-    recv_sizes, output_offsets, total = ragged_plan(cm, me)
+    total = jnp.sum(cm[:, me], dtype=jnp.int32)
 
     if plane_mod.pack_enabled():
         codec = plane_mod.PlaneCodec(cols, spec)
@@ -297,11 +417,9 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
             sorted_plane = jnp.take(packed, perm_t, axis=0)
         with obs_spans.span("shuffle.collective",
                             family="ragged_all_to_all", packed=True,
-                            launches=1):
-            out = jnp.zeros((out_capacity, packed.shape[1]), packed.dtype)
-            got = collectives.ragged_all_to_all(
-                sorted_plane, out, input_offsets, counts, output_offsets,
-                recv_sizes)
+                            launches=1, rounds=rounds or 1):
+            got = _ragged_exchange(sorted_plane, cm, me, out_capacity,
+                                   rounds)
         # NO validity mask on decode: the per-buffer path below moves raw
         # buffers (a null row's bytes pass through untouched), and the
         # plane must stay bit-identical to it; rows past ``total`` decode
@@ -324,17 +442,15 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
         orig = buf.dtype
         if orig == jnp.bool_:
             buf = buf.astype(jnp.uint8)
-        sorted_buf = jnp.take(buf, perm_t, axis=0)
-        out = jnp.zeros((out_capacity,) + buf.shape[1:], buf.dtype)
-        got = collectives.ragged_all_to_all(
-            sorted_buf, out, input_offsets, counts, output_offsets,
-            recv_sizes)
+        got = _ragged_exchange(jnp.take(buf, perm_t, axis=0), cm, me,
+                               out_capacity, rounds)
         if orig == jnp.bool_:
             got = got.astype(jnp.bool_)
         return got[:, 0] if squeeze else got
 
     with obs_spans.span("shuffle.collective", family="ragged_all_to_all",
-                        packed=False, launches=buffer_count(cols)):
+                        packed=False, launches=buffer_count(cols),
+                        rounds=rounds or 1):
         out_cols = tuple(
             Column(exchange(c.data), exchange(c.validity),
                    None if c.lengths is None else exchange(c.lengths),

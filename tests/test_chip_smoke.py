@@ -128,18 +128,46 @@ def test_ragged_probe_raises_instead_of_falling_back(ctx2, monkeypatch):
         par_ops._probe_ragged(ctx2)
 
 
-def test_oversized_ragged_operand_is_refused_classified(monkeypatch):
-    """2^22 rows per shard halt the v5e inside RaggedAllToAll: the engine
-    refuses them by name instead; one row fewer goes through."""
-    from types import SimpleNamespace
+@pytest.mark.parametrize("case", ["2^22", "2^24", "2^24 hot"])
+def test_large_shards_go_in_rounds_under_the_operand_limit(case):
+    """2^22 rows a shard halt the v5e inside one RaggedAllToAll: they and
+    more go in rounds, and each round's send operand and receive buffer
+    stay under 2^31 bytes whatever the targets are; one row fewer goes
+    whole, as it did."""
+    import numpy as np
 
-    from cylon_tpu import precision
-    from cylon_tpu.parallel import ops as par_ops
-    from cylon_tpu.status import Code, CylonError
+    from cylon_tpu.parallel import shuffle
 
-    par_ops._check_ragged_operand(SimpleNamespace(shard_capacity=1 << 22))
-    monkeypatch.setattr(precision, "on_tpu", lambda: True)
-    par_ops._check_ragged_operand(SimpleNamespace(shard_capacity=(1 << 22) - 1))
-    with pytest.raises(CylonError) as err:
-        par_ops._check_ragged_operand(SimpleNamespace(shard_capacity=1 << 22))
-    assert err.value.code == Code.CapacityError
+    world, limit = 4, 1 << 31
+    shard = 1 << (22 if case == "2^22" else 24)
+    under = (1 << 22) - 1
+    assert shuffle.ragged_round_quota(under, world) is None
+    assert shuffle.plan_rounds(np.zeros((world, world), int),
+                               under) == (1, under)
+    quota = shuffle.ragged_round_quota(shard, world)
+    assert world * quota * shuffle.RAGGED_ROW_BYTES == limit // 2
+    live = shard - shard // 21        # 16,000,000 of 2^24
+    if case.endswith("hot"):          # every source sends all it has to 0
+        cm = np.zeros((world, world), int)
+        cm[:, 0] = live
+    else:
+        cm = np.full((world, world), live // world)
+    rounds, operand_rows = shuffle.plan_rounds(cm, shard)
+    assert rounds == -(-cm.max() // quota)
+    assert operand_rows == rounds * world * quota
+    landed = np.zeros(world, int)
+    for r in range(rounds):
+        plans = [[np.asarray(x) for x in
+                  shuffle.ragged_round_plan(cm, me, r, quota)]
+                 for me in range(world)]
+        moved = np.stack([send for send, _, _, _ in plans])
+        for me, (send, recv, offsets, landing) in enumerate(plans):
+            assert send.sum() * shuffle.RAGGED_ROW_BYTES < limit
+            assert recv.sum() * shuffle.RAGGED_ROW_BYTES < limit
+            # what the sources send is what this receiver expects, each
+            # slice after the lower ranks', the buffer after earlier rounds'
+            np.testing.assert_array_equal(recv, moved[:, me])
+            np.testing.assert_array_equal(offsets, moved[:me].sum(axis=0))
+            assert landing == landed[me]
+            landed[me] += recv.sum()
+    np.testing.assert_array_equal(landed, cm.sum(axis=0))

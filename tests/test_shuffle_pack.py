@@ -20,7 +20,8 @@ import pandas as pd
 import pytest
 
 from cylon_tpu import column as colmod
-from cylon_tpu import precision
+from cylon_tpu import dtypes, precision
+from cylon_tpu.column import Column
 from cylon_tpu.parallel import plane, shuffle as shuffle_mod
 
 PACK_MODES = ("0", "1")
@@ -82,6 +83,32 @@ def test_plane_roundtrip_all_dtypes(cap, rng):
     bits_b = np.asarray(out[4].data).view(np.uint32)
     np.testing.assert_array_equal(bits_a, bits_b)
     _assert_cols_equal(cols, out, "roundtrip")
+
+
+def test_plane_carries_a_float64_as_the_float32_pair_a_tpu_holds(
+        monkeypatch, rng):
+    """On a TPU a float64 is a pair of float32 and has no bitcast: the
+    plane carries the two halves.  Every value such a pair can hold comes
+    back bit for bit, -0.0, the infinities and NaN included, and the zero
+    rows of an unwritten tail decode to 0.0."""
+    head = rng.standard_normal(256).astype(np.float32)
+    rest = (head * rng.uniform(-2.0 ** -25, 2.0 ** -25, 256)).astype(
+        np.float32)
+    values = head.astype(np.float64) + rest.astype(np.float64)
+    values[:6] = [0.0, -0.0, np.inf, -np.inf, np.nan, 1.0]
+    cols = (colmod.from_numpy(np.arange(256, dtype=np.int64) * -(2 ** 40)),
+            Column(jnp.asarray(values), jnp.ones(256, bool), None,
+                   dtypes.double))
+    exact = plane.unpack_plane(plane.pack_plane(cols), cols)
+    monkeypatch.setattr(precision, "on_tpu", lambda: True)
+    packed = plane.pack_plane(cols)
+    assert packed.shape == (256, plane.plane_words(cols))
+    out = plane.unpack_plane(packed, cols)
+    np.testing.assert_array_equal(
+        np.asarray(out[1].data).view(np.uint64), values.view(np.uint64))
+    _assert_cols_equal(out, exact, "float32 pair")
+    tail = plane.unpack_plane(jnp.zeros_like(packed), cols)
+    assert not np.asarray(tail[1].data).view(np.uint64).any()
 
 
 def test_plane_valid_mask_zeroes_tail(rng):

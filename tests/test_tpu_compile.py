@@ -202,7 +202,8 @@ def test_local_kernels_compile(one_chip, as_on_chip, program):
                          ids=["i32_f32", "i32_f32_str"])
 def test_ragged_shuffle_compiles_on_four_chips(topo, as_on_chip, with_string):
     """shuffle_shard_ragged under shard_map on the four described devices,
-    over the packed and compressed plane."""
+    over the packed and compressed plane; 2^22 rows a shard is the
+    smallest shard that goes in rounds."""
     from cylon_tpu.parallel import plane, shuffle
     from cylon_tpu.utils import shard_map
 
@@ -221,9 +222,47 @@ def test_ragged_shuffle_compiles_on_four_chips(topo, as_on_chip, with_string):
     targets = jax.ShapeDtypeStruct((world * shard,), jnp.int32,
                                    sharding=sharded)
 
+    rounds, _ = shuffle.plan_rounds(np.full((world, world), shard // world),
+                                    shard)
+    assert rounds == 2
+
     def body(cc, tgt):
         out, total = shuffle.shuffle_shard_ragged(cc, tgt, world, 2 * shard,
-                                                  spec=spec)
+                                                  spec=spec, rounds=rounds)
+        return out, jnp.reshape(total, (1,))
+
+    compiled = jax.jit(shard_map(
+        body, mesh=mesh, in_specs=P(PARTITION_AXIS),
+        out_specs=P(PARTITION_AXIS), check_vma=False)).lower(
+            cols, targets).compile()
+    assert "ragged-all-to-all" in compiled.as_text()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_rounded_shuffle_compiles_at_the_weak_scaling_shard(topo, as_on_chip):
+    """The benchmark's four-chip cell: (k int64, a float64) at 2^24 rows a
+    shard, over the collective's operand limit, so the exchange goes in
+    rounds of 2^21 rows and none of its buffers is a shard in the
+    collective's 512 B rows (8 GB to send and 8 GB to receive)."""
+    from cylon_tpu.parallel import plane, shuffle
+    from cylon_tpu.utils import shard_map
+
+    world, shard = 4, ROWS
+    mesh = Mesh(np.array(topo.devices), (PARTITION_AXIS,))
+    sharded = NamedSharding(mesh, P(PARTITION_AXIS))
+    cols = (_col(world * shard, jnp.int64, dtypes.int64, sharded),
+            _col(world * shard, jnp.float64, dtypes.double, sharded))
+    spec = plane.build_spec(cols, [0, 64_000_000 - 1], world, shard)
+    assert spec is not None and spec[0][0] == "narrow"
+    cm = np.full((world, world), 16_000_000 // world + 2_000)
+    rounds, operand_rows = shuffle.plan_rounds(cm, shard)
+    assert rounds == 8 and operand_rows == 8 << 21
+    targets = jax.ShapeDtypeStruct((world * shard,), jnp.int32,
+                                   sharding=sharded)
+
+    def body(cc, tgt):
+        out, total = shuffle.shuffle_shard_ragged(cc, tgt, world, shard,
+                                                  spec=spec, rounds=rounds)
         return out, jnp.reshape(total, (1,))
 
     compiled = jax.jit(shard_map(
