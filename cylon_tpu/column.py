@@ -398,7 +398,12 @@ def fetch_d2h(buffers, n: Optional[int] = None, get=jax.device_get):
     """One blocking device->host copy of a fetch, as NumPy: a pytree of
     buffers, or the first ``n`` rows of one buffer (the device slice is
     part of the copy).  Span ``table.fetch.d2h``; the bytes that arrived
-    add to counter ``table.fetch.bytes``."""
+    add to counter ``table.fetch.bytes``.  A buffer that is NumPy already
+    (a sharded table's fetched rows, ``Table._fetched_columns``) was
+    counted when it arrived: its rows come back as they are, with no span
+    and nothing counted."""
+    if isinstance(buffers, np.ndarray):
+        return buffers if n is None else buffers[:n]
     with obs_span("table.fetch.d2h"):
         out = get(buffers if n is None else buffers[:n])
         obs_metrics.counter_add(

@@ -372,7 +372,7 @@ def write_csv(table, path: PathLike, options: Optional[CSVWriteOptions] = None,
             _write_csv_columns(cols, count, names, _shard_path(path, sid),
                                options)
         return
-    cols, total = table._gathered_columns()
+    cols, total = table._export_columns()
     _write_csv_columns(cols, total, names, str(path), options)
 
 

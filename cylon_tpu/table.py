@@ -19,6 +19,7 @@ TPU-native analog of ``cylon::Table`` (reference: cpp/src/cylon/table.hpp:
 """
 from __future__ import annotations
 
+import collections
 import math
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple, Union
@@ -214,56 +215,76 @@ class Table:
     # exporters (host boundary)
     # ------------------------------------------------------------------
     def _gathered_columns(self) -> Tuple[List[Column], int]:
-        """Collect live rows of every shard into one local column set."""
+        """Live rows of every shard as one local DEVICE column set, for
+        the callers that go on computing on them (the planner's ``limit``,
+        ``DataFrame.<column>``): a one-shard table's own columns, else
+        ``_fetched_columns`` down once and up once (span
+        ``table.fetch.h2d``, counter ``table.fetch.h2d_bytes``: the live
+        bytes).  The exporters upload nothing: ``_export_columns``."""
         if self.num_shards == 1:
             return (list(self.columns),
                     int(column_mod.fetch_d2h(self.row_counts)[0]))
 
-        # ONE host transfer for the whole table (a pytree gather); on
-        # multi-host the shards live on remote processes, so the gather is
-        # a cross-process all-gather (the reference's analog is a
-        # gather-to-rank pattern over MPI)
-        counts_h, cols_h = column_mod.fetch_d2h(
-            (self.row_counts, self.columns), get=_get_everywhere)
-
-        counts = np.asarray(counts_h)
-        cap = self.shard_capacity
-        total = int(counts.sum())
-        out_cols: List[Column] = []
-        for col, col_h in zip(self.columns, cols_h):
-            data = np.asarray(col_h.data)
-            validity = np.asarray(col_h.validity)
-            lengths = None if col.lengths is None else np.asarray(col_h.lengths)
-            parts_d, parts_v, parts_l = [], [], []
-            for s in range(self.num_shards):
-                lo, hi = s * cap, s * cap + int(counts[s])
-                parts_d.append(data[lo:hi])
-                parts_v.append(validity[lo:hi])
-                if lengths is not None:
-                    parts_l.append(lengths[lo:hi])
-            d = np.concatenate(parts_d) if parts_d else data[:0]
-            v = np.concatenate(parts_v) if parts_v else validity[:0]
-            l = np.concatenate(parts_l) if lengths is not None else None
-            with obs_span("table.fetch.h2d"):
-                out_cols.append(Column(
-                    jnp.asarray(d), jnp.asarray(v),
-                    None if l is None else jnp.asarray(l), col.dtype))
-                obs_metrics.counter_add(
-                    "table.fetch.h2d_bytes",
-                    d.nbytes + v.nbytes + (0 if l is None else l.nbytes))
+        cols_h, total = self._fetched_columns()
+        with obs_span("table.fetch.h2d"):
+            out_cols = [jax.tree_util.tree_map(jnp.asarray, c)
+                        for c in cols_h]
+            obs_metrics.counter_add(
+                "table.fetch.h2d_bytes",
+                sum(b.nbytes for b in jax.tree_util.tree_leaves(cols_h)))
         return out_cols, total
+
+    def _fetched_columns(self) -> Tuple[List[Column], int]:
+        """Live rows of every shard of a sharded table, shard 0 … n-1, as
+        HOST-backed columns (NumPy buffers, which ``column.to_numpy`` /
+        ``to_arrow`` and the writers read in place) and their total.
+
+        In one process every live row crosses to the host once
+        (``_live_shard_rows``: device slices, all copies in flight
+        together).  Across processes the shards live elsewhere, so the
+        whole buffers come through one cross-process all-gather (the
+        reference's analog is a gather-to-rank pattern over MPI) and are
+        cut to their counts here.  Either way the blocking copies are span
+        ``table.fetch.d2h`` and what arrived adds to ``table.fetch.bytes``;
+        nothing is uploaded."""
+        cap = self.shard_capacity
+        buffers, treedef = jax.tree_util.tree_flatten(self.columns)
+        if jax.process_count() > 1:
+            counts, whole = column_mod.fetch_d2h(
+                (self.row_counts, buffers), get=_get_everywhere)
+            counts = np.asarray(counts)
+            pieces = ([np.asarray(w)[s * cap: s * cap + int(n)]
+                       for s, n in enumerate(counts)] for w in whole)
+        else:
+            counts = np.asarray(column_mod.fetch_d2h(self.row_counts))
+            pieces = ([rows[s] for s in range(self.num_shards)]
+                      for rows in _live_shard_rows(buffers, counts, cap))
+        # a buffer is assembled while the later ones are still on their way
+        cols = jax.tree_util.tree_unflatten(
+            treedef, [np.concatenate(parts) for parts in pieces])
+        return list(cols), int(counts.sum())
+
+    def _export_columns(self) -> Tuple[List[Column], int]:
+        """What an export to the host reads, and its row count: a
+        one-shard table's own device columns (``column.to_numpy`` slices
+        and copies them), a sharded table's live rows on the host."""
+        if self.num_shards == 1:
+            return self._gathered_columns()
+        return self._fetched_columns()
 
     def _addressable_host_shards(self) -> List[Tuple[int, List[Column], int]]:
         """Host views of every shard whose device buffers live on this
-        process: [(shard_id, columns, live_count)], shard-cap buffers.
+        process: [(shard_id, columns, live_count)].
 
-        The gather-free twin of ``_gathered_columns`` — on multi-host each
+        The gather-free twin of ``_fetched_columns`` — on multi-host each
         process sees only its own shards, mirroring the reference's
         rank-local table writes (table.cpp:243-256 WriteCSV writes the
-        calling rank's partition, never a gathered table)."""
-        # columns here hold HOST (numpy) buffers: the writers only slice and
-        # np.asarray them, so wrapping back into device arrays would buy a
-        # pointless H2D+D2H round-trip per shard
+        calling rank's partition, never a gathered table).  The columns
+        hold HOST (NumPy) buffers — a sharded table's cut to the live
+        count (``_live_shard_rows``), a one-shard table's whole, and a
+        writer cuts to the count either way; the copies are spans
+        ``table.fetch.d2h``, counted in ``table.fetch.bytes`` once, and
+        nothing is uploaded."""
         with obs_span("table.fetch", shards=self.num_shards):
             counts = np.asarray(column_mod.fetch_d2h(self.row_counts,
                                                      get=_get_everywhere))
@@ -274,27 +295,18 @@ class Table:
                                else np.asarray(c.lengths), co.dtype)
                         for co, c in zip(self.columns, cols_h)]
                 return [(0, cols, int(counts[0]))]
-            cap = self.shard_capacity
-            piece_maps = []
-            for col in self.columns:
-                dm = _host_shard_pieces(col.data, cap)
-                vm = _host_shard_pieces(col.validity, cap)
-                lm = (None if col.lengths is None
-                      else _host_shard_pieces(col.lengths, cap))
-                piece_maps.append((dm, vm, lm))
-            out: List[Tuple[int, List[Column], int]] = []
-            for sid in sorted(piece_maps[0][0]):
-                cols = [Column(dm[sid], vm[sid],
-                               None if lm is None else lm[sid], col.dtype)
-                        for col, (dm, vm, lm) in zip(self.columns, piece_maps)]
-                out.append((sid, cols, int(counts[sid])))
-            return out
+            buffers, treedef = jax.tree_util.tree_flatten(self.columns)
+            rows = list(_live_shard_rows(buffers, counts,
+                                         self.shard_capacity))
+            return [(sid, list(jax.tree_util.tree_unflatten(
+                        treedef, [r[sid] for r in rows])), int(counts[sid]))
+                    for sid in sorted(rows[0])]
 
     def to_arrow(self):
         import pyarrow as pa
 
         with obs_span("table.fetch", shards=self.num_shards):
-            cols, total = self._gathered_columns()
+            cols, total = self._export_columns()
             arrays = [column_mod.to_arrow(c, total) for c in cols]
         return pa.table(arrays, names=list(self.names))
 
@@ -306,7 +318,7 @@ class Table:
 
     def to_numpy(self) -> Dict[str, np.ndarray]:
         with obs_span("table.fetch", shards=self.num_shards):
-            cols, total = self._gathered_columns()
+            cols, total = self._export_columns()
             return {n: column_mod.to_numpy(c, total)
                     for n, c in zip(self.names, cols)}
 
@@ -1068,20 +1080,60 @@ def _shard_wise(ctx: CylonContext, fn, *tables: Table, key: tuple):
     return entry(*tables)
 
 
-def _host_shard_pieces(arr: jax.Array, cap: int) -> Dict[int, np.ndarray]:
-    """shard_id -> host ndarray of that shard's rows, from the array's
-    process-addressable device buffers only (no cross-process transfer).
-    A replicated buffer spans every shard and is sliced accordingly."""
-    out: Dict[int, np.ndarray] = {}
-    for sh in arr.addressable_shards:
-        idx = sh.index[0] if sh.index else slice(None)
-        start = 0 if idx.start is None else int(idx.start)
-        rows = column_mod.fetch_d2h(sh.data)
-        for k in range(rows.shape[0] // cap):
-            sid = (start + k * cap) // cap
-            if sid not in out:
-                out[sid] = rows[k * cap:(k + 1) * cap]
-    return out
+# a shard's live rows leave the device in slices of a multiple of
+# capacity / _FETCH_STEPS rows: at most that many slice programs a buffer
+# shape, and under 1% of the capacity moved for nothing
+_FETCH_STEPS = 128
+
+
+def _live_shard_rows(buffers: Sequence[jax.Array], counts: np.ndarray,
+                     cap: int):
+    """For each of ``buffers`` (global arrays of ``cap`` rows a shard) in
+    turn: {shard_id: host ndarray of that shard's ``counts[shard_id]`` live
+    rows}, from the array's process-addressable device buffers only (no
+    cross-process transfer).  The one piece of code that reads shards.
+
+    A shard's rows are sliced on the device that holds them, to the count
+    rounded up to a step (a new count seldom compiles a new slice program;
+    the surplus is cut off here), and every copy of every buffer is started
+    before the first is waited for: each chip has its own link to the
+    host.  An empty shard moves nothing; a replicated buffer spans every
+    shard and is sliced accordingly.  The dispatch and each buffer's wait
+    are spans ``table.fetch.d2h``; the bytes that arrived add to counter
+    ``table.fetch.bytes``."""
+    step = max(1, cap // _FETCH_STEPS)
+    in_flight = collections.deque()
+    with obs_span("table.fetch.d2h"):
+        for arr in buffers:
+            slices: Dict[int, Optional[jax.Array]] = {}
+            for sh in arr.addressable_shards:
+                idx = sh.index[0] if sh.index else slice(None)
+                first = (0 if idx.start is None else int(idx.start)) // cap
+                for k in range(sh.data.shape[0] // cap):
+                    sid = first + k
+                    if sid in slices:
+                        continue
+                    slices[sid] = None
+                    n = int(counts[sid])
+                    if n:
+                        rows = min(cap, -(-n // step) * step)
+                        slices[sid] = sh.data[k * cap: k * cap + rows]
+                        slices[sid].copy_to_host_async()
+            in_flight.append((arr, slices))
+
+    def landed():
+        # a buffer's device slices are let go as soon as it has landed
+        while in_flight:
+            arr, slices = in_flight.popleft()
+            with obs_span("table.fetch.d2h"):
+                rows = {sid: (np.empty((0,) + arr.shape[1:], arr.dtype)
+                              if piece is None else np.asarray(piece))
+                        for sid, piece in slices.items()}
+                obs_metrics.counter_add(
+                    "table.fetch.bytes", sum(r.nbytes for r in rows.values()))
+            yield {sid: r[:int(counts[sid])] for sid, r in rows.items()}
+
+    return landed()
 
 
 def _get_everywhere(tree):

@@ -190,20 +190,32 @@ def test_fetch_counts_the_bytes_it_moved(clean_obs, local_ctx, ctx4, shards):
     out = table.to_numpy()
     assert len(out["k"]) == 200
     counters = obs_metrics.snapshot()["counters"]
+    # four shards of 50 rows, full to capacity: the live rows, once
     live = _buffer_bytes(table.columns, 200) if shards == 1 else 200 * (
         8 + 1 + 8 + 1)
-    if shards == 1:
-        moved = table.row_counts.nbytes + live
-        assert "table.fetch.h2d_bytes" not in counters
-    else:
-        # every shard's whole buffers, then the re-uploaded live rows again
-        moved = table.row_counts.nbytes + _buffer_bytes(table.columns) + live
-        assert counters["table.fetch.h2d_bytes"] == live
-    assert counters["table.fetch.bytes"] == moved
+    assert "table.fetch.h2d_bytes" not in counters
+    assert counters["table.fetch.bytes"] == table.row_counts.nbytes + live
     rep = obs_spans.aggregate_report()
     assert rep["table.fetch"][1] == 1
     assert rep["table.fetch.d2h"][0] <= rep["table.fetch"][0]
     assert "host.sync" not in rep  # the fetch's reads are its own
+
+
+def test_the_limit_uploads_the_live_rows_it_gathered(clean_obs, ctx4):
+    """``_gathered_columns`` of a sharded table (the planner's ``limit``,
+    ``DataFrame.<column>``) goes on computing on the device: live rows down
+    once, up once, nothing else."""
+    rows = 203  # shards of 51, 51, 51, 50 in buffers of 64
+    table = Table.from_numpy(["k", "a"], [np.arange(rows),
+                                          np.arange(rows) / 7],
+                             ctx=ctx4, capacity=4 * 64)
+    head = table.plan().limit(5).execute()
+    counters = obs_metrics.snapshot()["counters"]
+    live = rows * (8 + 1 + 8 + 1)
+    assert counters["table.fetch.h2d_bytes"] == live
+    assert counters["table.fetch.bytes"] == table.row_counts.nbytes + live
+    assert "table.fetch.h2d" in obs_spans.aggregate_report()
+    assert head.to_numpy()["k"].tolist() == list(range(5))
 
 
 def test_the_query_syncs_the_same_number_of_times(clean_obs, local_ctx):
