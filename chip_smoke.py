@@ -158,6 +158,9 @@ def first_and_second(calls: dict):
 
 
 def phase_kernels(rows: int, seed: int, interpret: bool) -> dict:
+    from unittest import mock
+
+    import jax
     import jax.numpy as jnp
 
     from cylon_tpu import column as colmod
@@ -165,7 +168,7 @@ def phase_kernels(rows: int, seed: int, interpret: bool) -> dict:
     from cylon_tpu.config import JoinType
     from cylon_tpu.ops import groupby as gmod
     from cylon_tpu.ops import join as jmod
-    from cylon_tpu.ops import pallas_kernels, pallas_scan, segments
+    from cylon_tpu.ops import pallas_kernels, pallas_scan, realization, segments
     from cylon_tpu.ops import sort as smod
     from cylon_tpu.ops import unique as umod
     from cylon_tpu.ops.groupby import AggOp
@@ -261,17 +264,24 @@ def phase_kernels(rows: int, seed: int, interpret: bool) -> dict:
                                csum - base[np.cumsum(rh) - 1], rtol=RTOL,
                                atol=1e-5)
 
-    # the default segment reduction must agree with the scatter-add one
-    # (pinned, so no operator env can turn this into one path against
-    # itself)
+    # the default segment reduction must agree with the scatter-add one:
+    # this platform's row of the table with the other's ``segsum``.
+    # Traced programs do not see the substitution, so they are dropped on
+    # the way in and out (set_accumulation drops them too, when it changes
+    # the mode)
+    scatter_row = realization.current()._replace(segsum="scatter")
     precision.set_accumulation("narrow")
-    segments.set_segsum("scatter")
+    jax.clear_caches()
     try:
-        arm, arm_seconds = first_and_second({"groupby_scatter": groupby(stats)})
+        with mock.patch.object(realization, "current",
+                               return_value=scatter_row):
+            assert segments.effective_mode() == "scatter"
+            arm, arm_seconds = first_and_second(
+                {"groupby_scatter": groupby(stats)})
         seconds.update(arm_seconds)
         scat = [np.asarray(c.data)[:ng] for c in arm["groupby_scatter"][0]]
     finally:
-        segments.set_segsum(None)
+        jax.clear_caches()
         precision.set_accumulation(None)
     for got, ref in zip(got_g[1:3], scat[1:3]):
         np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)

@@ -171,38 +171,6 @@ KNOBS: Dict[str, Knob] = {k.name: k for k in [
             "(CYLON_TPU_SHUFFLE_PACK); auto enables on TPU-family "
             "backends.  The observed spec is static layout, so it also "
             "enters every exchange plan cache key (cylint CY109)."),
-    _K("CYLON_TPU_PERMUTE", "enum", "auto", TRACE, cache_key=True,
-       choices=("scatter", "sort", "auto"),
-       accessors=("cylon_tpu.ops.compact.permute_mode",),
-       help="Permutation/compaction realization: scatter vs single-word "
-            "sort; auto sorts on TPU-family backends."),
-    _K("CYLON_TPU_INVPERM", "enum", "sort", TRACE, cache_key=True,
-       choices=("sort", "gather"),
-       accessors=("cylon_tpu.ops.compact.invperm_mode",),
-       help="Inverse-permutation apply: one multi-operand sort vs sort-"
-            "once + per-field gathers."),
-    _K("CYLON_TPU_SORT", "enum", "cmp", TRACE, cache_key=True,
-       choices=("cmp", "radix"),
-       accessors=("cylon_tpu.ops.radix.sort_mode",),
-       help="Packed fast-path sort family: lax.sort (cmp) vs the radix "
-            "kernel."),
-    _K("CYLON_TPU_RADIX_BITS", "int", 1, TRACE, cache_key=True,
-       accessors=("cylon_tpu.ops.radix.radix_bits",),
-       help="Radix digit width in bits (clamped to 1..8 at the call site)."),
-    _K("CYLON_TPU_RADIX_SCAN", "str", "", TRACE, cache_key=True,
-       accessors=("cylon_tpu.ops.radix._cumsum_i32",),
-       help="'xla' reverts the radix kernel's matmul cumsum to jnp.cumsum "
-            "for A/B."),
-    _K("CYLON_TPU_SCAN", "str", "", TRACE, cache_key=True,
-       accessors=("cylon_tpu.ops.segments._pallas_plain_scan_selected",),
-       help="run_extents' cumsum/cummax/cummin: pallas (the two-sweep "
-            "scan kernel) | xla; unset prefers pallas on TPU."),
-    _K("CYLON_TPU_SEGSUM", "str", "", TRACE, cache_key=True,
-       accessors=("cylon_tpu.ops.segments.prefix_reductions_enabled",
-                  "cylon_tpu.ops.segments.effective_mode",
-                  "cylon_tpu.ops.segments._pallas_scan_selected"),
-       help="Segment-reduction path: prefix | pallas | scatter; unset "
-            "prefers pallas on TPU, scatter elsewhere."),
     _K("CYLON_TPU_ACCUM", "enum", "auto", TRACE, cache_key=True,
        choices=("wide", "narrow", "auto"),
        accessors=("cylon_tpu.precision.accumulation_mode",

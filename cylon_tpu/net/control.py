@@ -145,6 +145,9 @@ class JsonServer:
                 conn, _ = self._sock.accept()
             except OSError:
                 return  # socket closed: server death or clean stop
+            if self._closed.is_set():
+                conn.close()  # arrived while closing: a dead server is mute
+                return
             threading.Thread(target=self._serve_one, args=(conn,),
                              daemon=True).start()
 
@@ -175,9 +178,18 @@ class JsonServer:
                 pass  # client went away before the reply; nothing to do
 
     def close(self) -> None:
-        """Stop accepting and release the port (idempotent)."""
+        """Stop accepting and release the port before returning
+        (idempotent).  Closing alone leaves the accept thread blocked in
+        ``accept()``, holding the listener until the next connection, which
+        it would still answer: shut down first (that wakes it), then join."""
         self._closed.set()
+        try:
+            self._sock.shutdown(socket.SHUT_RDWR)
+        except OSError:
+            pass  # never listened, or closed already
         try:
             self._sock.close()
         except OSError:
             pass
+        if self._thread not in (None, threading.current_thread()):
+            self._thread.join(timeout=5.0)

@@ -7,15 +7,13 @@ on the inverted mask yields a permutation that packs kept rows to the front
 in original order; the new dynamic row count is the mask popcount.  One fused
 sort+gather instead of a dynamically-sized filter.
 
-Two interchangeable realizations, selected by :func:`permute_mode`:
+Two interchangeable realizations; :func:`permute_mode` says which one the
+platform gets (``realization.py`` has the table and the measurements):
 
 - ``scatter``: cumsum destinations + one permuting scatter (one linear
-  pass — optimal where scatter is cheap, e.g. XLA:CPU).
+  pass, where a scatter is cheap: XLA:CPU).
 - ``sort``: pack (mask bit above row index) into ONE u32 word and
-  ``lax.sort`` it — on TPU a full 64M-word sort measures ~4x FASTER than
-  a same-size scatter (round-4 hardware profile: 213 ms sort vs ~900 ms
-  per scatter pass at 2^26 rows/side), so sort-realized permutations are
-  the TPU default.
+  ``lax.sort`` it (XLA:TPU serializes scatters).
 """
 from __future__ import annotations
 
@@ -24,20 +22,13 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from .. import config, precision
 from ..obs import stage
+from . import realization
 
 
 def permute_mode() -> str:
-    """How permutations/compactions are materialized: "scatter" | "sort".
-
-    CYLON_TPU_PERMUTE overrides; "auto" (default) picks "sort" on
-    TPU-family backends (where XLA's sort is bandwidth-bound but its
-    scatter serializes) and "scatter" elsewhere.  Read at trace time."""
-    mode = config.knob("CYLON_TPU_PERMUTE")
-    if mode in ("scatter", "sort"):
-        return mode
-    return "sort" if precision.on_tpu() else "scatter"
+    """How permutations/compactions are materialized: "scatter" | "sort"."""
+    return realization.current().permute
 
 
 def index_bits(cap: int) -> int:
@@ -137,19 +128,6 @@ def count_leq_dense(sorted_vals: jax.Array, num_queries: int) -> jax.Array:
     return p[:num_queries] - jnp.arange(num_queries, dtype=jnp.int32)
 
 
-def invperm_mode() -> str:
-    """Sub-realization of sort-mode ``inverse_permute``: ``"sort"``
-    (default — one multi-operand sort carries every field) or
-    ``"gather"`` (one 2-operand sort builds the inverse index once, then
-    one bandwidth-linear ``take`` per field).  The trade: a k-field
-    multi-operand sort moves (k+1) operands through every sort pass,
-    while the gather realization pays the sort passes once on 8 B/row
-    and k linear gathers — the crossover is a hardware question
-    (microbench + profiler A/B arms; CYLON_TPU_INVPERM overrides).
-    Only meaningful when permute_mode() == "sort"."""
-    return config.knob("CYLON_TPU_INVPERM")
-
-
 @stage("compact.permute")
 def inverse_permute(perm: jax.Array, *fields: jax.Array) -> Tuple[jax.Array, ...]:
     """``out[perm[i]] = fields[..][i]`` for each field — the inverse-
@@ -157,27 +135,8 @@ def inverse_permute(perm: jax.Array, *fields: jax.Array) -> Tuple[jax.Array, ...
 
     scatter mode: one scatter per field.  sort mode: ONE multi-operand
     ``lax.sort`` keyed on ``perm`` (unique keys, unstable OK) carries all
-    fields to their destinations in a single fused pass — or, under
-    ``invperm_mode() == "gather"``, one 2-operand sort computes
-    ``inv = argsort(perm)`` and each field is one linear gather
-    ``take(f, inv)`` (equivalent because out[j] = f[inv[j]])."""
+    fields to their destinations in a single fused pass."""
     if permute_mode() == "sort":
-        if invperm_mode() == "gather":
-            cap = perm.shape[0]
-            # index dtype must widen with cap like _mask_sort_perm's
-            # fallback: an int32 iota (and a u32 key cast) silently wraps
-            # for cap >= 2^31, scrambling the inverse permutation
-            it = _idx_dtype(cap)
-            iota = jnp.arange(cap, dtype=it)  # payload: no cast back
-            key = (perm.astype(jnp.uint32) if it == jnp.int32
-                   else perm.astype(jnp.int64))
-            _, inv = jax.lax.sort((key, iota), num_keys=1, is_stable=False)
-            # inv is an argsort of a permutation — provably in bounds and
-            # unique; the default fill mode would add a clamp+select per
-            # element inside the very A/B this realization exists to win
-            return tuple(f.at[inv].get(mode="promise_in_bounds",
-                                       unique_indices=True)
-                         for f in fields)
         sorted_ops = jax.lax.sort((perm.astype(jnp.uint32),) + tuple(fields),
                                   num_keys=1, is_stable=False)
         return tuple(sorted_ops[1:])

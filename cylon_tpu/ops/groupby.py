@@ -133,14 +133,15 @@ def _segment_aggregate(op: AggOp, data, valid, gid, num_segments: int,
 
     ``boundaries`` (the run-start mask over the gid-sorted rows) opts the
     float/min/max reductions into the scatter-free segmented scan
-    (segments.segmented_reduce_sorted) when CYLON_TPU_SEGSUM=prefix —
+    (segments.segmented_reduce_sorted) where the platform's segment
+    reductions are scans (segments.effective_mode, on a TPU) —
     rounding stays per-segment because the scan's combine resets at run
     starts.  Integer sums stay on the scatter in every mode: their i64
     accumulator would make the scan a 64-bit prefix program (the class
     that has crashed this XLA TPU backend)."""
     sorted_counts = spans is not None and precision.narrow()
     use_scan = (sorted_counts and boundaries is not None
-                and segments.prefix_reductions_enabled())
+                and segments.effective_mode() == "pallas")
     if sorted_counts:
         start, end = spans
         cnt32 = segments.segment_sum_sorted(valid.astype(jnp.int32), start,

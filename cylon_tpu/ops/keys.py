@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from ..column import Column
 from ..obs import metrics as obs_metrics
-from . import compact, radix
+from . import compact
 
 
 def pack_string_words(data: jax.Array) -> List[jax.Array]:
@@ -198,14 +198,14 @@ def pack_payload(buffers: Sequence[jax.Array]):
     of the sort's ``capacity`` rows) through the sort, so that no index
     vector is built for them.  Returns ``(lanes, layout)``; ``layout`` is
     for ``unpack_payload`` and holds ``None`` for a buffer that cannot ride
-    and is left to ``take(perm)``: a 2-D byte matrix, whatever is past
-    ``_MAX_PAYLOAD_LANES``, and everything under ``CYLON_TPU_SORT=radix``.
+    and is left to ``take(perm)``: a 2-D byte matrix and whatever is past
+    ``_MAX_PAYLOAD_LANES``.
 
     1-D ``bool`` buffers (validity) ride as one bit each, 32 to a ``uint32``
     word; every other 1-D buffer rides as it is: a sort moves a non-key
     operand as bits, so NaN payloads, -0.0 and float64 (emulated on a TPU)
     come back exact."""
-    budget = 0 if radix.sort_mode() == "radix" else _MAX_PAYLOAD_LANES
+    budget = _MAX_PAYLOAD_LANES
     flat = [i for i, b in enumerate(buffers) if b.ndim == 1]
     bits = [i for i in flat if buffers[i].dtype == jnp.bool_][:32 * budget]
     budget -= -(-len(bits) // 32)
@@ -292,19 +292,12 @@ def lexsort_indices(operands: Sequence[jax.Array], capacity: int,
 
         words = (lo,) if total_bits + idx_bits <= 32 else (hi, lo)
         index_mask = jnp.uint32((1 << idx_bits) - 1)
-        if radix.sort_mode() == "radix":
-            # the A/B arm sorts words alone: payload goes through its perm
-            s_hi, s_lo = radix.radix_sort_packed(
-                hi if len(words) == 2 else None, lo, idx_bits,
-                idx_bits + total_bits)
-            perm = (s_lo & index_mask).astype(jnp.int32)
-            moved = [jnp.take(x, perm) for x in payload]
-        else:  # keys are unique: no stability needed
-            sorted_all = jax.lax.sort(words + payload, num_keys=len(words),
-                                      is_stable=False)
-            s_hi, s_lo = sorted_all[0], sorted_all[len(words) - 1]
-            perm = (s_lo & index_mask).astype(jnp.int32)
-            moved = list(sorted_all[len(words):])
+        # keys are unique: no stability needed
+        sorted_all = jax.lax.sort(words + payload, num_keys=len(words),
+                                  is_stable=False)
+        s_hi, s_lo = sorted_all[0], sorted_all[len(words) - 1]
+        perm = (s_lo & index_mask).astype(jnp.int32)
+        moved = list(sorted_all[len(words):])
         s_lo = s_lo >> jnp.uint32(idx_bits)
         return perm, ([s_lo] if len(words) == 1 else [s_hi, s_lo]), moved
     packed = _pack_encoded(enc)

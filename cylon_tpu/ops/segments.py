@@ -18,6 +18,9 @@ running state, evaluated for all groups at once.
 MIN/MAX keep ``jax.ops.segment_min/max`` — their operands stay in the input
 dtype (int32/float32 scatters profile ~8x faster than 64-bit ones) and have
 no cancellation-safe prefix formulation.
+
+Which scan and which segment reduction a platform gets: the table in
+``realization.py``, read by ``plain_scan_mode`` and ``effective_mode``.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import precision
-from . import compact
+from . import compact, realization
 
 
 def segment_spans(new_group: jax.Array) -> Tuple[jax.Array, jax.Array]:
@@ -60,11 +63,10 @@ def run_extents(member: jax.Array, new_group: jax.Array,
     All callers satisfy it because rows_equal_adjacent forces row 0 to
     start a run.
 
-    On TPU the three scans ride the two-sweep Pallas kernel
-    (ops/pallas_scan.scan_1d); CYLON_TPU_SCAN=pallas/xla forces either
-    path — see _pallas_plain_scan_selected for why."""
+    On a TPU the three scans ride the two-sweep Pallas kernel
+    (ops/pallas_scan.scan_1d)."""
     n = member.shape[0]
-    if _pallas_plain_scan_selected():
+    if plain_scan_mode() == "pallas":
         from . import pallas_scan
 
         incl = pallas_scan.scan_1d(member.astype(jnp.int32), "sum")
@@ -83,43 +85,9 @@ def run_extents(member: jax.Array, new_group: jax.Array,
     return start, end - start
 
 
-_SCAN_MODE: "str | None" = None  # None = read CYLON_TPU_SCAN
-
-
-def set_scan(mode: "str | None") -> None:
-    """Force ``"pallas"`` or ``"xla"`` plain scans in run_extents (None =
-    env).  Clears jit caches like set_segsum — the knob is read at trace
-    time inside jitted pipelines, so an env flip alone would silently
-    keep the cached path and poison any in-process A/B."""
-    global _SCAN_MODE
-    if mode not in (None, "pallas", "xla"):
-        raise ValueError(f"scan mode must be pallas/xla, got {mode}")
-    if mode != _SCAN_MODE:
-        jax.clear_caches()
-    _SCAN_MODE = mode
-
-
 def plain_scan_mode() -> str:
-    """The plain-scan path trace-time state selects: ``"pallas"`` |
-    ``"xla"`` (public accessor — bench reporting keys on it, like
-    effective_mode for segsum)."""
-    return "pallas" if _pallas_plain_scan_selected() else "xla"
-
-
-def _pallas_plain_scan_selected() -> bool:
-    """Whether run_extents' cumsum/cummax/cummin ride the Pallas scan.
-    CYLON_TPU_SCAN=pallas/xla (or set_scan) forces it; unset picks Pallas
-    on TPU, where XLA's reduce-window scans cost 18-45 s of compile EACH
-    at 2^20 rows on a v5e (PERF.md, PR 22) against under a second for
-    the kernel.  Read at trace time."""
-    if _SCAN_MODE is not None:
-        return _SCAN_MODE == "pallas"
-    from .. import config
-
-    mode = config.knob("CYLON_TPU_SCAN")
-    if mode in ("pallas", "xla"):
-        return mode == "pallas"
-    return precision.on_tpu()
+    """How run_extents' cumsum/cummax/cummin run: ``"pallas"`` | ``"xla"``."""
+    return realization.current().scan
 
 
 def _span_take(csum0: jax.Array, pos: jax.Array) -> jax.Array:
@@ -153,78 +121,33 @@ def segment_count_sorted(valid: jax.Array, start: jax.Array,
                               jnp.int32).astype(jnp.int64)
 
 
-_SEGSUM_MODE: "str | None" = None  # None = read CYLON_TPU_SEGSUM
-
-
-def set_segsum(mode: "str | None") -> None:
-    """Force ``"prefix"``, ``"pallas"`` or ``"scatter"`` segment reductions
-    (None = env).  ``pallas`` is prefix semantics through the two-sweep
-    Pallas kernel (ops/pallas_scan.py) instead of lax.associative_scan.
-    Clears jit caches like precision.set_accumulation — the knob is read
-    at trace time, so cached kernels would otherwise keep the old path."""
-    global _SEGSUM_MODE
-    if mode not in (None, "prefix", "pallas", "scatter"):
-        raise ValueError(
-            f"segsum mode must be prefix/pallas/scatter, got {mode}")
-    if mode != _SEGSUM_MODE:
-        jax.clear_caches()
-    _SEGSUM_MODE = mode
-
-
 def effective_mode() -> str:
-    """The segment-reduction path narrow-mode float/min/max reductions
-    take: ``"pallas"`` | ``"prefix"`` | ``"scatter"``.  CYLON_TPU_SEGSUM
-    (or set_segsum) forces one; unset is backend-aware like
-    compact.permute_mode — scatter off the TPU (XLA:CPU scatter-adds are
-    cheap and its associative_scan is not), a segmented scan on it
-    (round-4 hardware: XLA:TPU serializes scatters), realized by the
-    two-sweep Pallas kernel rather than ``prefix``'s
-    lax.associative_scan: the chip's compiler takes 72 s for the latter
-    at 2^20 rows and over 400 s at 2^22, about a second for the kernel
-    at any size, and the two agree on the chip (PERF.md, PR 22).  Which
-    is faster to RUN is not measured.  The 64-bit carve-outs in
-    groupby._segment_aggregate are mode-independent: integer sums and
-    wide accumulators keep the scatter in every mode (64-bit prefix
-    fusions have crashed this TPU backend).  Read at trace time: set it
-    before the first jitted compute or use set_segsum, which clears the
-    jit caches."""
-    if _SEGSUM_MODE is not None:
-        return _SEGSUM_MODE
-    from .. import config
-
-    mode = config.knob("CYLON_TPU_SEGSUM")
-    if mode in ("prefix", "pallas", "scatter"):
-        return mode
-    return "pallas" if precision.on_tpu() else "scatter"
-
-
-def prefix_reductions_enabled() -> bool:
-    """Whether segment reductions use a segmented scan (either
-    realization) instead of scatter-adds."""
-    return effective_mode() != "scatter"
-
-
-def _pallas_scan_selected() -> bool:
-    """Whether the Pallas kernel, not lax.associative_scan, backs
-    segmented_reduce_sorted."""
-    return effective_mode() == "pallas"
+    """The path narrow-mode float/min/max segment reductions take:
+    ``"pallas"`` (a segmented scan, segmented_reduce_sorted) |
+    ``"scatter"`` (``jax.ops.segment_*``).  The 64-bit carve-outs in
+    groupby._segment_aggregate hold on every platform: integer sums and
+    wide accumulators keep the scatter (64-bit prefix fusions have crashed
+    this TPU backend)."""
+    return realization.current().segsum
 
 
 def segmented_reduce_sorted(x: jax.Array, new_group: jax.Array,
                             end: jax.Array, op: str) -> jax.Array:
     """Per-segment reduction over rows already grouped into runs, with NO
-    scatter: a segmented ``lax.associative_scan`` over (value, reset-flag)
-    pairs carries each run's running reduction — the combine restarts at
-    run boundaries, so rounding stays per-segment exactly like the
-    scatter-add it replaces — and the per-run total is gathered at the
-    run's last row.  ``x`` must already be masked (null/padding rows set
-    to the op's neutral element).  ``op``: 'sum' | 'min' | 'max'.
+    scatter: a segmented scan over (value, reset-flag) pairs carries each
+    run's running reduction — the combine restarts at run boundaries, so
+    rounding stays per-segment exactly like the scatter-add it replaces —
+    and the per-run total is gathered at the run's last row.  Rows of four
+    bytes go through the two-sweep Pallas kernel (ops/pallas_scan.py),
+    every other width through ``lax.associative_scan``.  ``x`` must already
+    be masked (null/padding rows set to the op's neutral element).  ``op``:
+    'sum' | 'min' | 'max'.
 
     Returns values indexed by segment id (same contract as
     ``jax.ops.segment_*`` with ``num_segments = len(x)``); ids past the
     number of segments read the clipped last row (callers mask by group
     liveness, as they already do for the scatter path)."""
-    if _pallas_scan_selected() and x.dtype.itemsize == 4:
+    if x.dtype.itemsize == 4:
         from . import pallas_scan
 
         run_val = pallas_scan.segmented_scan(x, new_group, op)

@@ -43,10 +43,12 @@ def _shard_map(ctx: CylonContext, fn, key: tuple, shapes_key: tuple,
 
     cache = ctx_cache(ctx, "_plan_cache")
     # every trace-scope knob participates in every plan key: flipping e.g.
-    # CYLON_TPU_PERMUTE or CYLON_TPU_SHUFFLE_PACK must retrace, never serve
+    # CYLON_TPU_SHUFFLE_PACK or CYLON_TPU_ACCUM must retrace, never serve
     # a program traced under the other realization (the PR 2 bug class,
     # generalized; cylint rule CY103 treats builders that append this token
-    # as key-complete)
+    # as key-complete).  How the local kernels are realized is not in the
+    # key: it follows the platform (ops/realization.py) and cannot change
+    # in a process
     cache_key = (key, shapes_key, config.trace_cache_token())
     entry = cache.get(cache_key)
     if entry is None:

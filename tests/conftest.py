@@ -8,6 +8,8 @@ shard_map programs that run on a TPU pod execute here on 8 host devices.
 Must run before anything imports jax: the platform and the device count
 are read when the backend initializes.
 """
+import contextlib
+import gc
 import os
 import sys
 
@@ -99,3 +101,55 @@ def ctx8():
 @pytest.fixture()
 def rng():
     return np.random.default_rng(42)
+
+
+def _drop_traced_programs():
+    """Forget every traced program: JAX's caches and the package's jit-plan
+    caches (a context's ``_plan_cache`` and ``_shard_fn_cache``, the stream
+    kernels).  Their keys do not hold how the local kernels are realized:
+    in production that follows the platform and cannot change."""
+    from cylon_tpu.context import CylonContext
+    from cylon_tpu.stream import incremental
+
+    jax.clear_caches()
+    incremental._KERNELS.clear()
+    for obj in gc.get_objects():
+        if isinstance(obj, CylonContext):
+            for name in ("_plan_cache", "_shard_fn_cache"):
+                getattr(obj, name, {}).clear()
+
+
+@contextlib.contextmanager
+def _realize(row):
+    """Run the body under ``row`` (an ``ops.realization.Realization``) in
+    place of the platform's own: the one door by which a test runs the
+    other platform's kernels.  Asserts from a jaxpr that the substitution
+    took, so that an agreement test never compares a path with itself."""
+    from unittest import mock
+
+    import jax.numpy as jnp
+
+    from cylon_tpu.ops import compact, realization
+
+    other = {"sort": "scatter", "scatter": "sort"}[row.permute]
+    # the platform's own row substitutes nothing: what is traced stays valid
+    substituted = row != realization.current()
+    if substituted:
+        _drop_traced_programs()
+    try:
+        with mock.patch.object(realization, "current", return_value=row):
+            # a new function: make_jaxpr caches a trace by function
+            jaxpr = str(jax.make_jaxpr(
+                lambda m: compact.compact_indices(m))(jnp.zeros((8,), bool)))
+            assert f" {row.permute}[" in jaxpr, jaxpr
+            assert f" {other}[" not in jaxpr, jaxpr
+            yield row
+    finally:
+        if substituted:
+            _drop_traced_programs()
+
+
+@pytest.fixture()
+def realize():
+    """``with realize(row): ...`` — see ``_realize``."""
+    return _realize

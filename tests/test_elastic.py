@@ -915,6 +915,30 @@ def test_kill_one_of_three_survivors_bit_identical_to_oracle(tmp_path):
         coord.stop()
 
 
+def _await_bindable(host, port, within_s=5.0):
+    """Wait, bounded, until ``(host, port)`` can be bound again.  stop()
+    releases the listener before it returns; what can still hold the port
+    for a moment is another process's outgoing connection that drew it as
+    its source port (six xdist workers share the ephemeral range; an agent
+    hammering the closed port can even connect to itself).  Constructing
+    the successor is not retried instead: it journals its incarnation
+    before it binds."""
+    import errno
+    import socket
+
+    deadline = time.monotonic() + within_s
+    while True:
+        with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as probe:
+            probe.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
+            try:
+                probe.bind((host, port))
+                return
+            except OSError as e:
+                if e.errno != errno.EADDRINUSE or time.monotonic() > deadline:
+                    raise
+        time.sleep(0.05)
+
+
 @pytest.mark.fault
 def test_coordinator_restart_mid_run_survivors_ride_through(tmp_path):
     """THE acceptance criterion: 3 OS processes mid-run, the coordinator
@@ -957,6 +981,7 @@ def test_coordinator_restart_mid_run_survivors_ride_through(tmp_path):
         host, port = coord.address
         coord.stop()  # kill -9 semantics: no goodbye to anyone
         time.sleep(1.0)  # workers accumulate failures, enter the window
+        _await_bindable(host, port)
         coord2 = elastic.Coordinator(3, heartbeat_timeout_s=2.5,
                                      log_dir=coord_dir, host=host,
                                      port=port).start()

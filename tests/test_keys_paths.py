@@ -121,7 +121,7 @@ def test_perm_by_target_wide_mesh_fallback(rng):
             assert (np.diff(idx) > 0).all(), "must be stable within target"
 
 
-def test_target_counts_wide_mesh_sort_mode(rng, monkeypatch):
+def test_target_counts_wide_mesh_sort_mode(rng, realize):
     """sort permute mode switches from the dense alphabet compare to the
     sort + count_leq_dense derivation past world=32 (round-4 advice: the
     O(cap*world) broadcast intermediate); every path must agree with the
@@ -129,20 +129,21 @@ def test_target_counts_wide_mesh_sort_mode(rng, monkeypatch):
     out-of-range remap."""
     import jax.numpy as jnp
 
+    from cylon_tpu.ops import realization
     from cylon_tpu.parallel import shuffle
 
     n = 4096
+    cases = {}
     for world in (8, 40, 100):
         t = np.append(rng.integers(0, world, n - 7),
                       [world] * 5 + [-3, world + 9]).astype(np.int32)
-        targets = jnp.asarray(t)
-        monkeypatch.setenv("CYLON_TPU_PERMUTE", "scatter")
-        ref = np.asarray(shuffle.target_counts(targets, world))
-        monkeypatch.setenv("CYLON_TPU_PERMUTE", "sort")
-        got = np.asarray(shuffle.target_counts(targets, world))
-        expected = np.bincount(t[(t >= 0) & (t < world)], minlength=world)
-        np.testing.assert_array_equal(ref, expected)
-        np.testing.assert_array_equal(got, expected)
+        cases[world] = (t, np.bincount(t[(t >= 0) & (t < world)],
+                                       minlength=world))
+    for permute in ("scatter", "sort"):
+        with realize(realization.current()._replace(permute=permute)):
+            for world, (t, expected) in cases.items():
+                got = np.asarray(shuffle.target_counts(jnp.asarray(t), world))
+                np.testing.assert_array_equal(got, expected)
 
 
 def test_compact_index_dtype_selection():
@@ -246,10 +247,10 @@ def test_lexsort_payload_rides_bit_for_bit(key_dtype, payload_dtype, rng):
                                       _bits(np.asarray(x)[np.asarray(perm)]))
 
 
-def test_pack_payload_leaves_to_the_take_what_cannot_ride(monkeypatch):
+def test_pack_payload_leaves_to_the_take_what_cannot_ride():
     """A byte matrix never rides; lanes past the constant do not; validity
-    bits share words of 32; under the radix arm nothing rides.  The counters
-    say how many 32-bit lanes went each way."""
+    bits share words of 32.  The counters say how many 32-bit lanes went
+    each way."""
     import jax.numpy as jnp
 
     from cylon_tpu.obs import metrics
@@ -280,7 +281,3 @@ def test_pack_payload_leaves_to_the_take_what_cannot_ride(monkeypatch):
     assert rode == 1 + budget // 2 * 2 and rode + took == 1 + 2 * len(wide)
     assert [w is None for w in layout[1:]] == [
         i >= budget // 2 for i in range(len(wide))]
-
-    monkeypatch.setenv("CYLON_TPU_SORT", "radix")
-    lanes, layout, (rode, took) = counted(flags[:2] + wide[:1])
-    assert lanes == [] and set(layout) == {None} and (rode, took) == (0, 4)

@@ -186,3 +186,24 @@ def test_exchange_bytes_ndarray_views(ctx4):
             expect = np.ascontiguousarray(base[:, ::2][: s + 1])
             got = np.frombuffer(bytes(received[r][s]), np.int32)
             assert np.array_equal(got, expect.ravel())
+
+
+def test_json_server_close_releases_the_port_before_it_returns():
+    """A successor binds the address a closed server held at once, with no
+    connection in between to wake the old accept loop (the coordinator's
+    restart at the SAME address), and a closed server answers nothing."""
+    from cylon_tpu.net import control
+
+    srv = control.JsonServer(lambda req: {"ok": True}).start()
+    assert control.request(srv.address, {"cmd": "x"}) == {"ok": True}
+    srv.close()
+    assert not srv._thread.is_alive()
+    with pytest.raises(OSError):
+        control.request(srv.address, {"cmd": "x"}, timeout=1.0)
+    successor = control.JsonServer(lambda req: {"ok": 2}, host=srv.address[0],
+                                   port=srv.address[1]).start()
+    try:
+        assert control.request(successor.address, {"cmd": "x"}) == {"ok": 2}
+    finally:
+        successor.close()
+        srv.close()  # idempotent

@@ -1,15 +1,15 @@
 """The two-sweep Pallas segmented scan (ops/pallas_scan.py) must be a
-bit-faithful drop-in for the associative-scan path it can replace:
+faithful drop-in for the scatters and XLA scans it stands in for on a TPU:
 identical segment semantics (restart at boundaries, element-order
 rounding) across ops, dtypes, block boundaries, and the end-to-end
-groupby that consumes it (CYLON_TPU_SEGSUM=pallas)."""
+groupby that consumes it (a TPU's ``segsum``, ops/realization.py)."""
 import numpy as np
 import pandas as pd
 import pytest
 
 import jax.numpy as jnp
 
-from cylon_tpu.ops import pallas_scan, segments
+from cylon_tpu.ops import pallas_scan, realization, segments
 
 
 @pytest.fixture
@@ -61,33 +61,35 @@ def test_segmented_scan_single_segment_and_all_boundaries(rng):
     np.testing.assert_array_equal(got, x)
 
 
-def test_segmented_reduce_sorted_pallas_mode_agrees(rng):
-    """segments.segmented_reduce_sorted under set_segsum('pallas') must
-    agree with the associative-scan path (to float tolerance: the two
-    combine trees differ in shape, so f32 sums are not bitwise equal)."""
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+def test_segmented_reduce_sorted_pallas_mode_agrees(rng, op):
+    """segments.segmented_reduce_sorted on four-byte rows (the Pallas
+    kernel) must agree with the scatter it stands in for,
+    ``jax.ops.segment_*`` (to float tolerance for sums: the combine trees
+    differ in shape, so f32 sums are not bitwise equal)."""
+    import jax
+
     n = 10000
     x = rng.random(n).astype(np.float32)
     r = rng.random(n) < 0.01
     r[0] = True
     seg = np.cumsum(r) - 1
-    end = np.searchsorted(seg, np.arange(seg[-1] + 1), side="right")
+    groups = int(seg[-1]) + 1
+    end = np.searchsorted(seg, np.arange(groups), side="right")
     end_full = np.full(n, 1, np.int32)
     end_full[:len(end)] = end
-    args = (jnp.asarray(x), jnp.asarray(r), jnp.asarray(end_full))
-    try:
-        segments.set_segsum("prefix")
-        exp = np.asarray(segments.segmented_reduce_sorted(*args, "sum"))
-        segments.set_segsum("pallas")
-        got = np.asarray(segments.segmented_reduce_sorted(*args, "sum"))
-    finally:
-        segments.set_segsum(None)
-    np.testing.assert_allclose(got, exp, rtol=1e-5)
+    got = np.asarray(segments.segmented_reduce_sorted(
+        jnp.asarray(x), jnp.asarray(r), jnp.asarray(end_full), op))
+    scatter = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+               "max": jax.ops.segment_max}[op]
+    exp = np.asarray(scatter(jnp.asarray(x), jnp.asarray(seg), groups))
+    np.testing.assert_allclose(got[:groups], exp, rtol=1e-5)
 
 
-def test_groupby_end_to_end_pallas_segsum(rng):
+def test_groupby_end_to_end_pallas_segsum(rng, realize):
     """Full pipeline groupby with the Pallas scan backing segment
-    reductions — the A/B the battery runs on hardware, checked here in
-    interpret mode against the default path."""
+    reductions — a TPU's ``segsum``, checked here in interpret mode
+    against pandas."""
     from cylon_tpu.context import CylonContext
     from cylon_tpu.table import Table
 
@@ -96,12 +98,9 @@ def test_groupby_end_to_end_pallas_segsum(rng):
                        "v": rng.random(n).astype(np.float64)})
     ctx = CylonContext.Init()
     t = Table.from_pandas(df, ctx=ctx)
-    try:
-        segments.set_segsum("pallas")
+    with realize(realization.current()._replace(segsum="pallas")):
         got = (t.groupby("k", {"v": ["sum", "mean", "min", "max"]})
                .to_pandas().sort_values("k").reset_index(drop=True))
-    finally:
-        segments.set_segsum(None)
     exp = (df.groupby("k").agg(sum_v=("v", "sum"), mean_v=("v", "mean"),
                                min_v=("v", "min"), max_v=("v", "max"))
            .reset_index().sort_values("k").reset_index(drop=True))
@@ -138,9 +137,9 @@ def test_scan_1d_matches_xla(rng, op, reverse):
         np.testing.assert_array_equal(got, exp)
 
 
-def test_run_extents_pallas_scan_agrees(rng, monkeypatch):
-    """run_extents under CYLON_TPU_SCAN=pallas must agree exactly with
-    the XLA scan path (int32 scans are exact in both)."""
+def test_run_extents_pallas_scan_agrees(rng, realize):
+    """run_extents through the Pallas scans (a TPU's ``scan``) must agree
+    exactly with the XLA scan path (int32 scans are exact in both)."""
     n = 20000
     member = rng.random(n) < 0.5
     # synthetic run structure: starts every ~10 rows, ends before starts
@@ -150,10 +149,10 @@ def test_run_extents_pallas_scan_agrees(rng, monkeypatch):
     is_run_end[-1] = True
     args = (jnp.asarray(member), jnp.asarray(new_group),
             jnp.asarray(is_run_end))
-    monkeypatch.delenv("CYLON_TPU_SCAN", raising=False)
+    assert segments.plain_scan_mode() == "xla"
     s0, c0 = segments.run_extents(*args)
-    monkeypatch.setenv("CYLON_TPU_SCAN", "pallas")
-    s1, c1 = segments.run_extents(*args)
+    with realize(realization.current()._replace(scan="pallas")):
+        s1, c1 = segments.run_extents(*args)
     np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
     np.testing.assert_array_equal(np.asarray(c0), np.asarray(c1))
 

@@ -17,27 +17,25 @@ import pandas as pd
 import pytest
 
 from cylon_tpu import column as colmod
-from cylon_tpu import precision
+from cylon_tpu import config, precision
 from cylon_tpu.config import JoinType
 from cylon_tpu.ops import compact, join as join_mod, unique as unique_mod
+from cylon_tpu.ops import keys, realization, segments
 
 
 MODES = ("scatter", "sort")
 
 
-def _per_mode(monkeypatch, fn):
-    out = {}
+def _per_mode(realize, fn):
+    out = []
     for mode in MODES:
-        monkeypatch.setenv("CYLON_TPU_PERMUTE", mode)
-        jax.clear_caches()
-        out[mode] = fn()
-    monkeypatch.delenv("CYLON_TPU_PERMUTE", raising=False)
-    jax.clear_caches()
-    return out[MODES[0]], out[MODES[1]]
+        with realize(realization.current()._replace(permute=mode)):
+            out.append(fn())
+    return out
 
 
 @pytest.mark.parametrize("cap", [1, 7, 256, 1 << 12])
-def test_compact_partition_agree(monkeypatch, cap):
+def test_compact_partition_agree(realize, cap):
     rng = np.random.default_rng(cap)
     mask = jnp.asarray(rng.integers(0, 2, cap).astype(bool))
 
@@ -46,7 +44,7 @@ def test_compact_partition_agree(monkeypatch, cap):
         perm, nt = compact.partition_indices(mask)
         return (np.asarray(idx), int(n), np.asarray(perm), int(nt))
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a[1] == b[1] and a[3] == b[3]
     n = a[1]
     # compact contract: first n entries identical; tail is caller-masked
@@ -57,7 +55,7 @@ def test_compact_partition_agree(monkeypatch, cap):
     assert (b[0] >= 0).all() and (b[0] < cap).all()
 
 
-def test_inverse_permute_agree(monkeypatch):
+def test_inverse_permute_agree(realize):
     rng = np.random.default_rng(42)
     n = 1 << 11
     perm = jnp.asarray(rng.permutation(n).astype(np.int32))
@@ -68,24 +66,18 @@ def test_inverse_permute_agree(monkeypatch):
         a, b = compact.inverse_permute(perm, f1, f2)
         return np.asarray(a), np.asarray(b)
 
-    (a1, a2), (b1, b2) = _per_mode(monkeypatch, run)
+    (a1, a2), (b1, b2) = _per_mode(realize, run)
     np.testing.assert_array_equal(a1, b1)
     np.testing.assert_array_equal(a2, b2)
     # ground truth
     ref = np.empty(n, np.int32)
     ref[np.asarray(perm)] = np.asarray(f1)
     np.testing.assert_array_equal(a1, ref)
-    # third realization: sort-family gather (argsort once + take per field)
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", "sort")
-    monkeypatch.setenv("CYLON_TPU_INVPERM", "gather")
-    g1, g2 = run()
-    np.testing.assert_array_equal(g1, ref)
-    np.testing.assert_array_equal(g2, a2)
 
 
 @pytest.mark.parametrize("jt", [JoinType.INNER, JoinType.LEFT,
                                 JoinType.RIGHT, JoinType.FULL_OUTER])
-def test_join_gather_agree(monkeypatch, jt):
+def test_join_gather_agree(realize, jt):
     rng = np.random.default_rng(int(jt.value) + 1)
     cap = 1 << 10
     lk = rng.integers(0, 200, cap).astype(np.int32)
@@ -106,12 +98,12 @@ def test_join_gather_agree(monkeypatch, jt):
                 for i in range(n)]
         return m, n, sorted(rows)
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a[0] == b[0] and a[1] == b[1]
     assert a[2] == b[2]
 
 
-def test_join_key_grouped_agree(monkeypatch):
+def test_join_key_grouped_agree(realize):
     rng = np.random.default_rng(99)
     cap = 1 << 10
     lk = rng.integers(0, 64, cap).astype(np.int32)
@@ -127,14 +119,14 @@ def test_join_key_grouped_agree(monkeypatch):
         n = int(n)
         return n, np.asarray(out[0].data)[:n]
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a[0] == b[0]
     # key_grouped output order is fully pinned by the combined sort
     np.testing.assert_array_equal(a[1], b[1])
 
 
 @pytest.mark.parametrize("keep", ["first", "last"])
-def test_unique_agree(monkeypatch, keep):
+def test_unique_agree(realize, keep):
     rng = np.random.default_rng(7 if keep == "first" else 8)
     cap = 1 << 11
     vals = rng.integers(0, 100, cap).astype(np.int32)
@@ -146,7 +138,7 @@ def test_unique_agree(monkeypatch, keep):
         m = int(m)
         return m, np.asarray(out[0].data)[:m]
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a[0] == b[0]
     np.testing.assert_array_equal(a[1], b[1])
 
@@ -162,7 +154,7 @@ def test_count_leq_dense_matches_searchsorted():
         np.testing.assert_array_equal(got, want.astype(np.int32))
 
 
-def test_nunique_agree_across_modes(monkeypatch):
+def test_nunique_agree_across_modes(realize):
     from cylon_tpu.ops import groupby as groupby_mod
 
     rng = np.random.default_rng(21)
@@ -181,7 +173,7 @@ def test_nunique_agree_across_modes(monkeypatch):
         g = int(g)
         return g, np.asarray(out[0].data)[:g], np.asarray(out[1].data)[:g]
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a[0] == b[0]
     np.testing.assert_array_equal(a[1], b[1])
     np.testing.assert_array_equal(a[2], b[2])
@@ -191,19 +183,88 @@ def test_nunique_agree_across_modes(monkeypatch):
     np.testing.assert_array_equal(a[2], want.to_numpy())
 
 
-def test_permute_mode_default_by_backend(monkeypatch):
-    monkeypatch.delenv("CYLON_TPU_PERMUTE", raising=False)
-    want = "sort" if precision.on_tpu() else "scatter"
-    assert compact.permute_mode() == want
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", "sort")
-    assert compact.permute_mode() == "sort"
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", "scatter")
-    assert compact.permute_mode() == "scatter"
+#: the table of ops/realization.py, written out: the TPU's row is the
+#: configuration every line of PERF_LEDGER.jsonl was measured under
+TABLE = {"tpu": {"permute": "sort", "scan": "pallas", "segsum": "pallas"},
+         "host": {"permute": "scatter", "scan": "xla", "segsum": "scatter"}}
+
+
+@pytest.mark.parametrize("field,accessor", [
+    ("permute", compact.permute_mode), ("scan", segments.plain_scan_mode),
+    ("segsum", segments.effective_mode)], ids=["permute", "scan", "segsum"])
+@pytest.mark.parametrize("platform", ["tpu", "host"])
+def test_realization_follows_the_platform(monkeypatch, platform, field,
+                                          accessor):
+    monkeypatch.setattr(precision, "on_tpu", lambda: platform == "tpu")
+    assert realization.current()._asdict() == TABLE[platform]
+    assert accessor() == TABLE[platform][field]
+
+
+def _steered_kernels():
+    """Per removed knob: the environment that used to select the other
+    path, and the kernel it steered as an un-jitted function of small
+    arrays."""
+    mask = jnp.arange(64) % 3 == 0
+    perm = jnp.arange(64, dtype=jnp.int32)[::-1]
+    word = jnp.arange(64, dtype=jnp.int32)
+
+    def lexsort():
+        return keys.lexsort_indices([mask, word], 64, (word,))
+
+    def segsum():
+        from cylon_tpu.ops import groupby as groupby_mod
+
+        gid = jnp.cumsum(mask.astype(jnp.int32))
+        return groupby_mod._segment_aggregate(
+            groupby_mod.AggOp.SUM, word.astype(jnp.float32), ~mask, gid, 64,
+            0, spans=segments.segment_spans(mask.at[0].set(True)),
+            boundaries=mask.at[0].set(True))
+
+    radix = {"CYLON_TPU_SORT": "radix"}   # its two sub-knobs counted under it
+    return {
+        "CYLON_TPU_PERMUTE": ({"CYLON_TPU_PERMUTE": "sort"},
+                              lambda: compact.compact_indices(mask)),
+        "CYLON_TPU_INVPERM": ({"CYLON_TPU_INVPERM": "gather"},
+                              lambda: compact.inverse_permute(perm, word)),
+        "CYLON_TPU_SORT": (radix, lexsort),
+        "CYLON_TPU_RADIX_BITS": ({**radix, "CYLON_TPU_RADIX_BITS": "4"},
+                                 lexsort),
+        "CYLON_TPU_RADIX_SCAN": ({**radix, "CYLON_TPU_RADIX_SCAN": "xla"},
+                                 lexsort),
+        "CYLON_TPU_SCAN": ({"CYLON_TPU_SCAN": "pallas"},
+                           lambda: segments.run_extents(
+                               mask, mask.at[0].set(True),
+                               jnp.roll(mask, -1).at[-1].set(True))),
+        "CYLON_TPU_SEGSUM": ({"CYLON_TPU_SEGSUM": "prefix"}, segsum),
+    }
+
+
+@pytest.mark.parametrize("name", [
+    "CYLON_TPU_PERMUTE", "CYLON_TPU_INVPERM", "CYLON_TPU_SORT",
+    "CYLON_TPU_RADIX_BITS", "CYLON_TPU_RADIX_SCAN", "CYLON_TPU_SCAN",
+    "CYLON_TPU_SEGSUM"])
+def test_removed_knob_is_gone(monkeypatch, name):
+    """No operator can change how a kernel is realized: the name is
+    unregistered, and setting the variables to what used to select the
+    other path leaves the traced kernel as it was, on either platform."""
+    assert name not in config.KNOBS
+    with pytest.raises(KeyError):
+        config.knob(name)
+    old_env, kernel = _steered_kernels()[name]
+    for on_tpu in (False, True):
+        monkeypatch.setattr(precision, "on_tpu", lambda: on_tpu)
+        for variable in old_env:
+            monkeypatch.delenv(variable, raising=False)
+        # a new function each time: make_jaxpr caches a trace by function
+        before = str(jax.make_jaxpr(lambda: kernel())())
+        for variable, value in old_env.items():
+            monkeypatch.setenv(variable, value)
+        assert str(jax.make_jaxpr(lambda: kernel())()) == before
 
 
 @pytest.mark.parametrize("kg,algo", [(True, "sort"), (True, "hash"),
                                      (False, "sort"), (False, "hash")])
-def test_join_projection_key_grouped_and_hash(monkeypatch, kg, algo):
+def test_join_projection_key_grouped_and_hash(kg, algo):
     """The production configuration (key_grouped + project, both
     algorithms): projected output must equal the full materialization's
     selected columns row-for-row (key_grouped order is pinned)."""
@@ -235,7 +296,7 @@ def test_join_projection_key_grouped_and_hash(monkeypatch, kg, algo):
 
 
 @pytest.mark.parametrize("jt", [JoinType.INNER, JoinType.FULL_OUTER])
-def test_join_projection_pushdown(monkeypatch, jt):
+def test_join_projection_pushdown(realize, jt):
     """project= must return exactly the selected columns of the full
     materialization, in the requested order, in both permute modes."""
     rng = np.random.default_rng(5)
@@ -263,5 +324,5 @@ def test_join_projection_pushdown(monkeypatch, jt):
                 np.asarray(got.validity)[:n])
         return n
 
-    a, b = _per_mode(monkeypatch, run)
+    a, b = _per_mode(realize, run)
     assert a == b

@@ -160,39 +160,34 @@ def test_narrow_join_groupby_pipeline(ctx4, rng, narrow_mode):
     np.testing.assert_allclose(got[got.columns[1]], exp["s"], rtol=1e-3)
 
 
-@pytest.fixture()
-def prefix_segsum(narrow_mode):
+@pytest.mark.parametrize("op", ["sum", "min", "max"])
+@pytest.mark.parametrize("dtype", [np.int8, np.int16, np.float64],
+                         ids=["i8", "i16", "f64"])
+def test_prefix_segmented_reductions_match_scatter(rng, dtype, op):
+    """segmented_reduce_sorted on rows that are not four bytes wide — the
+    ``lax.associative_scan`` branch, which 1- and 2-byte min / max reach on
+    a TPU — must agree with the scatter it stands in for."""
+    import jax
+    import jax.numpy as jnp
+
     from cylon_tpu.ops import segments
 
-    segments.set_segsum("prefix")
-    yield
-    segments.set_segsum(None)
-
-
-@pytest.mark.slow
-def test_prefix_segmented_reductions_match_scatter(ctx4, rng, prefix_segsum):
-    """CYLON_TPU_SEGSUM=prefix: the segmented-scan reductions must agree
-    with pandas (and hence with the default scatter path) on every float
-    op, min/max, and the two-phase distributed pipeline."""
-    n = 6000
-    df = pd.DataFrame({
-        "k": rng.integers(0, 40, n),
-        "v": rng.random(n).astype(np.float32),
-    })
-    df.loc[rng.integers(0, n, 60), "v"] = np.nan
-    t = _table(ctx4, df)
-    g = t.groupby(["k"], {"v": ["sum", "mean", "min", "max",
-                              "std", "var"]})
-    got = g.to_pandas().sort_values("k").reset_index(drop=True)
-    gb = df.groupby("k")["v"]
-    exp = pd.DataFrame({
-        "sum": gb.sum(min_count=1), "mean": gb.mean(),
-        "min": gb.min(), "max": gb.max(),
-        "std": gb.std(ddof=0), "var": gb.var(ddof=0),
-    }).reset_index()
-    assert len(got) == len(exp)
-    np.testing.assert_array_equal(got.iloc[:, 0].to_numpy(), exp["k"].to_numpy())
-    for i, c in enumerate(["sum", "mean", "min", "max", "std", "var"], start=1):
-        np.testing.assert_allclose(got.iloc[:, i].to_numpy(),
-                                   exp[c].to_numpy().astype(np.float32),
-                                   rtol=2e-4, atol=1e-5, err_msg=c)
+    n = 500
+    x = (rng.random(n) * 100 - 50).astype(dtype)
+    starts = rng.random(n) < 0.05
+    starts[0] = True
+    seg = np.cumsum(starts) - 1
+    groups = int(seg[-1]) + 1
+    end = np.full(n, 1, np.int32)
+    end[:groups] = np.searchsorted(seg, np.arange(groups), side="right")
+    got = np.asarray(jax.jit(segments.segmented_reduce_sorted,
+                             static_argnames="op")(
+        jnp.asarray(x), jnp.asarray(starts), jnp.asarray(end), op=op))
+    scatter = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
+               "max": jax.ops.segment_max}[op]
+    exp = np.asarray(scatter(jnp.asarray(x), jnp.asarray(seg), groups))
+    assert got.dtype == exp.dtype == np.dtype(dtype)
+    if dtype is np.float64:
+        np.testing.assert_allclose(got[:groups], exp, rtol=1e-12)
+    else:
+        np.testing.assert_array_equal(got[:groups], exp)

@@ -434,15 +434,17 @@ def test_quarantine_expires_and_streak_resets(svc):
                 t.result(timeout=WAIT_S)
 
     with config.knob_env(CYLON_TPU_SERVE_QUARANTINE_AFTER="2",
-                         CYLON_TPU_SERVE_QUARANTINE_S="0.05",
+                         CYLON_TPU_SERVE_QUARANTINE_S="1.0",
                          CYLON_TPU_RETRY_MAX="0",
                          CYLON_TPU_RETRY_BASE_S="0"):
         fail_once()
         fail_once()
+        # the cooldown has to outlast a waiter's wake-up on a loaded host
+        # (six xdist workers): at 0.05 s it had run out before this submit
         with pytest.raises(CylonError) as ei:
             svc.submit("t", "join", left, right, on="k")
         assert ei.value.code == Code.Unavailable
-        time.sleep(0.08)
+        time.sleep(1.1)
         # cooldown elapsed: the tenant re-enters with a CLEAN streak —
         # one post-cooldown failure must NOT re-quarantine (threshold 2)
         fail_once()

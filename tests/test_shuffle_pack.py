@@ -22,6 +22,7 @@ import pytest
 from cylon_tpu import column as colmod
 from cylon_tpu import dtypes, precision
 from cylon_tpu.column import Column
+from cylon_tpu.ops import realization
 from cylon_tpu.parallel import plane, shuffle as shuffle_mod
 
 PACK_MODES = ("0", "1")
@@ -233,12 +234,12 @@ def _ab_shuffle(monkeypatch, t, keys):
                                            "ctx8"])
 @pytest.mark.parametrize("permute", PERMUTE_MODES)
 def test_packed_vs_perbuffer_worlds(world_fixture, permute, monkeypatch,
-                                    rng, request):
+                                    realize, rng, request):
     ctx = request.getfixturevalue(world_fixture)
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", permute)
     n = 2000
     df = _mixed_df(n, rng)
-    assert _ab_shuffle(monkeypatch, _table(ctx, df), ["k"]) == n
+    with realize(realization.current()._replace(permute=permute)):
+        assert _ab_shuffle(monkeypatch, _table(ctx, df), ["k"]) == n
 
 
 @pytest.mark.parametrize("world_fixture", ["ctx4", "ctx8"])
@@ -431,12 +432,13 @@ def _abc_shuffle(monkeypatch, t, keys):
 @pytest.mark.parametrize("world_fixture", ["local_ctx", "ctx2", "ctx4"])
 @pytest.mark.parametrize("permute", PERMUTE_MODES)
 def test_compressed_vs_uncompressed_worlds(world_fixture, permute,
-                                           monkeypatch, rng, request):
+                                           monkeypatch, realize, rng,
+                                           request):
     ctx = request.getfixturevalue(world_fixture)
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", permute)
     n = 1200
-    assert _abc_shuffle(monkeypatch, _table(ctx, _mixed_df(n, rng)),
-                        ["k"]) == n
+    with realize(realization.current()._replace(permute=permute)):
+        assert _abc_shuffle(monkeypatch, _table(ctx, _mixed_df(n, rng)),
+                            ["k"]) == n
 
 
 @pytest.mark.parametrize("world_fixture", ["local_ctx", "ctx2", "ctx4"])

@@ -152,7 +152,7 @@ def test_cy103_keyword_only_key_param(tmp_path):
     # param and call sites pass key= — the rule must still see it
     found = _scan(tmp_path, """\
         import jax
-        from cylon_tpu.ops import compact as compact_mod
+        from cylon_tpu.parallel import plane as plane_mod
 
         _cache = {}
 
@@ -165,13 +165,13 @@ def test_cy103_keyword_only_key_param(tmp_path):
 
         def select(ctx, t):
             def body(tt):
-                if compact_mod.permute_mode() == "sort":
+                if plane_mod.pack_enabled():
                     return tt
                 return tt
             return shard_wise(ctx, body, t, key=("select", 1))
         """)
     assert _rules_at(found) == [("CY103", 18)]
-    assert "CYLON_TPU_PERMUTE" in found[0].msg
+    assert "CYLON_TPU_SHUFFLE_PACK" in found[0].msg
 
 
 def test_cy103_token_complete_builder_is_exempt(tmp_path):
@@ -951,8 +951,8 @@ def test_knob_defaults_and_parsing(monkeypatch):
     assert config.knob("CYLON_TPU_RETRY_MAX") == 7
     monkeypatch.setenv("CYLON_TPU_RETRY_MAX", "junk")
     assert config.knob("CYLON_TPU_RETRY_MAX") == 2  # parse error -> default
-    monkeypatch.setenv("CYLON_TPU_PERMUTE", "bogus")
-    assert config.knob("CYLON_TPU_PERMUTE") == "auto"  # enum guard
+    monkeypatch.setenv("CYLON_TPU_SHUFFLE_PACK", "bogus")
+    assert config.knob("CYLON_TPU_SHUFFLE_PACK") == "auto"  # enum guard
     with pytest.raises(KeyError):
         config.knob_raw("CYLON_TPU_NOT_A_KNOB")
 

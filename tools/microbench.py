@@ -2,9 +2,10 @@
 
 Times the building blocks every kernel composes — sorts (1/2/3 operand),
 gathers (random / sorted indices), scatters (permute-set / add), scans
-(cumsum / cummax) — at N elements, so design choices (permute_mode,
-segsum mode, sort-vs-scatter realizations) rest on measured per-op costs
-instead of folklore.  Round-4 motivation: the first hardware window
+(cumsum / cummax) — at N elements, so design choices (the table in
+cylon_tpu/ops/realization.py: sort-vs-scatter, scan and segment-reduction
+realizations) rest on measured per-op costs instead of folklore.
+Round-4 motivation: the first hardware window
 showed lax.sort at 213 ms vs ~900 ms per permuting scatter at 64M
 elements, inverting the CPU cost model.
 
@@ -342,38 +343,10 @@ timed("associative_scan (sum,flag)", lambda x, f: jax.lax.associative_scan(
 timed("elementwise a*b+c", lambda x, y: x * y + 1.0, c, c,
       traffic_bytes=3 * B4)
 
-# round-4b composite primitives (sort-realized permutation machinery) —
-# measured per-mode so the permute_mode default rests on this backend's
-# numbers, not the other's
+# round-4b composite primitive (sort-realized permutation machinery), as
+# this backend's row of ops/realization.py realizes it
 from cylon_tpu.ops import compact  # noqa: E402
 
-mask = a < jnp.uint32(1 << 29)
-for mode in ("scatter", "sort"):
-    os.environ["CYLON_TPU_PERMUTE"] = mode
-    timed(f"compact_indices ({mode})",
-          lambda m: compact.compact_indices(m)[0], mask,
-          traffic_bytes=2 * B4)
-    timed(f"inverse_permute 2-field ({mode})",
-          lambda p, x, y: compact.inverse_permute(p, x, y), perm,
-          a.astype(jnp.int32), b.astype(jnp.int32), traffic_bytes=6 * B4)
-# sort-family gather realization of inverse_permute (CYLON_TPU_INVPERM):
-# one 2-op sort + k linear takes vs the (k+1)-operand sort — measured at
-# 2 and 4 fields so the crossover (if any) is visible
-# (2-field sort/sort is already timed above as "inverse_permute 2-field
-# (sort)" — not repeated)
-os.environ["CYLON_TPU_PERMUTE"] = "sort"
-timed("inverse_permute 4-field (sort/sort)",
-      lambda p, x, y: compact.inverse_permute(p, x, y, x, y), perm,
-      a.astype(jnp.int32), b.astype(jnp.int32), traffic_bytes=10 * B4)
-os.environ["CYLON_TPU_INVPERM"] = "gather"
-timed("inverse_permute 2-field (sort/gather)",
-      lambda p, x, y: compact.inverse_permute(p, x, y), perm,
-      a.astype(jnp.int32), b.astype(jnp.int32), traffic_bytes=6 * B4)
-timed("inverse_permute 4-field (sort/gather)",
-      lambda p, x, y: compact.inverse_permute(p, x, y, x, y), perm,
-      a.astype(jnp.int32), b.astype(jnp.int32), traffic_bytes=10 * B4)
-os.environ.pop("CYLON_TPU_INVPERM", None)
-os.environ.pop("CYLON_TPU_PERMUTE", None)
 timed("count_leq_dense", lambda v: compact.count_leq_dense(v, N),
       jnp.sort(a.astype(jnp.int32) % N), traffic_bytes=4 * B4)
 
