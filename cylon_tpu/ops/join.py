@@ -18,11 +18,22 @@ build/probe; join_utils.cpp build_final_table).  Design:
    join.cpp:179-235) is realized as a static-capacity gather: each emitting
    row scatters its index at its first output slot and a ``cummax`` forward
    fill maps every slot back to its (left row, match ordinal) — one scan,
-   no sort.
+   no sort.  Two lanes go through an index for it (``_expansion``).
+4. The output takes move a side's rows through its slot -> row index: one
+   take a data buffer, and the side's validity vectors as bits of one
+   ``uint32`` word for 32 columns (``take_side``).  The cell's ``(int64,
+   float64)`` sides cost 13 lanes through an index in all; a lane is the
+   unit of cost on a TPU (0.145 s for 2^24 rows, PERF.md §6).
 
 Everything is a static-shape XLA program; the only dynamic quantity is the
 returned row count.  ``join_row_count`` exposes the exact output size so the
-host can pick (and cache) an output capacity before running ``join_gather``.
+host can pick (and cache) an output capacity before running ``join_gather``:
+``_indices`` (steps 1-3) and a ``take_side`` a side (step 4) in ONE
+program.  A take among the join's others costs what it costs in a program
+of its own, once the compiler can keep the table it reads in its fast
+memory space (PERF.md §6, PR 30: the split was measured and is not
+shipped); ``join_indices`` is steps 1-3 alone, the seam for a caller that
+would materialize a column when it is read.
 """
 from __future__ import annotations
 
@@ -35,9 +46,11 @@ import jax.numpy as jnp
 from ..column import Column
 from ..config import JoinType
 from ..obs import stage
-from . import common, compact, segments
+from . import common, compact, keys, segments
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
+#: ``_expansion``'s ``delta`` of a left row that matches nothing
+_NO_MATCH = jnp.iinfo(jnp.int32).min
 
 
 def _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
@@ -107,8 +120,9 @@ def _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
 
 @stage("join.emit")
 def _emission(matches, live_l, join_type: JoinType):
-    outer_left = join_type in (JoinType.LEFT, JoinType.FULL_OUTER)
-    emit = jnp.where(live_l & (matches == 0), jnp.int32(1 if outer_left else 0), matches)
+    emit = matches
+    if join_type in (JoinType.LEFT, JoinType.FULL_OUTER):
+        emit = jnp.where(live_l & (matches == 0), jnp.int32(1), matches)
     csum = jnp.cumsum(emit, dtype=jnp.int32)
     total = csum[-1] if emit.shape[0] else jnp.zeros((), jnp.int32)
     return emit, csum, total
@@ -128,32 +142,39 @@ def _ranges(cols_l, count_l, cols_r, count_r, left_on, right_on, join_type,
 
 
 @stage("join.expand")
-def _key_grouped_order(lo, matches, live_l, left_key_order):
+def _key_grouped_order(lo, matches, left_key_order):
     """Left rows in key order with the matched ones front-packed: (lo,
-    matches, live_l) reordered, and the permutation that did it."""
+    matches) reordered, and the permutation that did it.  ``matches`` is 0
+    on a dead row, so it says which rows are matched by itself."""
     cap_l = lo.shape[0]
     if left_key_order is None:  # hash path: order by match-range offset
-        order_key = jnp.where(live_l & (matches > 0), lo, _I32_MAX)
+        order_key = jnp.where(matches > 0, lo, _I32_MAX)
         iota_l = jnp.arange(cap_l, dtype=jnp.int32)
         _, perm_l = jax.lax.sort((order_key, iota_l), num_keys=1,
                                  is_stable=True)
     else:  # sort path: key order is known; partition matched to front
-        lm = jnp.take(live_l & (matches > 0), left_key_order)
-        part, _ = compact.partition_indices(lm)
+        part, _ = compact.partition_indices(
+            jnp.take(matches, left_key_order) > 0)
         perm_l = jnp.take(left_key_order, part)
-    lo = jnp.take(lo, perm_l)
-    matches = jnp.take(matches, perm_l)
-    live_l = jnp.take(live_l, perm_l)
-    return lo, matches, live_l, perm_l
+    return jnp.take(lo, perm_l), jnp.take(matches, perm_l), perm_l
 
 
 @stage("join.expand")
 def _expansion(lo, matches, perm_r, unmatched_r, perm_l, emit, csum, total,
                join_type: JoinType, out_capacity: int):
     """Output slot -> (left row, right row): (lidx, ridx, lvalid, rvalid,
-    out_count) over ``out_capacity`` slots."""
+    out_count) over ``out_capacity`` slots.
+
+    Two lanes go through an index here, whatever the join type: what slot
+    ``k`` needs of its left row ``li`` is one ``int32``, ``delta = lo -
+    base_l`` (its position among the key-ordered right rows is ``k +
+    delta[li]``), and ``delta`` can say "no match" as well, because both
+    terms lie in ``[0, 2^31)`` and so ``INT32_MIN`` is free; the position
+    then reads the right row off one table, the key-ordered right rows
+    with the unmatched ones (RIGHT / FULL_OUTER's tail) behind them."""
     k = jnp.arange(out_capacity, dtype=jnp.int32)
     cap_l = emit.shape[0]
+    cap_r = perm_r.shape[0]
     base_l = csum - emit
     if compact.permute_mode() == "sort":
         # slot -> left row is searchsorted(csum, k, 'right') — csum is
@@ -171,28 +192,26 @@ def _expansion(lo, matches, perm_r, unmatched_r, perm_l, emit, csum, total,
             iota_l, mode="drop")
         li = jax.lax.cummax(marker)
     li = jnp.clip(li, 0, cap_l - 1)
-    base = jnp.take(base_l, li)
-    within = k - base
-    matched = jnp.take(matches, li) > 0
-    r_sorted_pos = jnp.take(lo, li) + within
-    ridx_inner = jnp.take(perm_r, jnp.clip(r_sorted_pos, 0, perm_r.shape[0] - 1))
+    delta = jnp.take(jnp.where(matches > 0, lo - base_l, _NO_MATCH), li)
 
     in_main = k < total
     lvalid = in_main
-    rvalid = in_main & matched
+    rvalid = in_main & (delta != _NO_MATCH)
     lidx = li if perm_l is None else jnp.take(perm_l, li)
-    ridx = jnp.where(rvalid, ridx_inner, 0)
+    pos = jnp.where(rvalid, jnp.clip(k + delta, 0, cap_r - 1), 0)
+    right_rows = perm_r
 
     out_count = total
     if join_type in (JoinType.RIGHT, JoinType.FULL_OUTER):
         perm_u, m = compact.compact_indices(unmatched_r)
         tail = k - total
         in_tail = (k >= total) & (tail < m)
-        ridx_tail = jnp.take(perm_u, jnp.clip(tail, 0, perm_u.shape[0] - 1))
-        ridx = jnp.where(in_tail, ridx_tail, ridx)
+        pos = jnp.where(in_tail, cap_r + jnp.clip(tail, 0, cap_r - 1), pos)
+        right_rows = jnp.concatenate([perm_r, perm_u])
         rvalid = rvalid | in_tail
         lvalid = lvalid & ~in_tail
         out_count = total + m
+    ridx = jnp.where(rvalid, jnp.take(right_rows, pos), 0)
     return lidx, ridx, lvalid, rvalid, out_count
 
 
@@ -212,6 +231,66 @@ def join_row_count(cols_l: Tuple[Column, ...], count_l,
     return total
 
 
+def _indices(cols_l: Tuple[Column, ...], count_l,
+             cols_r: Tuple[Column, ...], count_r,
+             left_on: Tuple[int, ...], right_on: Tuple[int, ...],
+             join_type: JoinType, out_capacity: int,
+             algorithm: str = "sort", key_grouped: bool = False):
+    """The join up to its output takes: ``(lidx, ridx, lvalid, rvalid,
+    out_count)`` — for each of ``out_capacity`` slots the left and the right
+    row it holds and whether it holds one (an outer join's fill holds none
+    on one side), and the dynamic output row count.  ``take_side`` turns a
+    side's columns and these into output columns; ``join_gather`` is both."""
+    lo, matches, perm_r, live_l, unmatched_r, left_key_order = _ranges(
+        cols_l, count_l, cols_r, count_r, left_on, right_on, join_type,
+        algorithm)
+    perm_l = None
+    if key_grouped:
+        if join_type != JoinType.INNER:
+            raise ValueError("key_grouped join output requires INNER")
+        lo, matches, perm_l = _key_grouped_order(lo, matches, left_key_order)
+        live_l = None  # in the old order, and INNER's emission reads none
+    emit, csum, total = _emission(matches, live_l, join_type)
+    return _expansion(lo, matches, perm_r, unmatched_r, perm_l, emit, csum,
+                      total, join_type, out_capacity)
+
+
+join_indices = jax.jit(_indices, static_argnames=(
+    "left_on", "right_on", "join_type", "out_capacity", "algorithm",
+    "key_grouped"))
+
+
+def take_side(cols: Sequence[Column], idx, slot_valid,
+              side: str) -> Tuple[Column, ...]:
+    """``[c.take(idx, valid_mask=slot_valid) for c in cols]``, bit for bit,
+    with no ``pred`` vector through the index: the side's validity vectors
+    go as bits of one ``uint32`` word for 32 columns (``keys.pack_bits``),
+    ``take_lanes(cols)`` lanes in all.  ``side`` is ``"left"`` or
+    ``"right"``, the stage the device time is booked to."""
+    with stage(f"join.gather_{side}"):
+        def take(buffer):
+            return jnp.take(buffer, idx, axis=0, mode="clip")
+
+        words = [take(w) for w in keys.pack_bits([c.validity for c in cols])]
+        out = []
+        for i, c in enumerate(cols):
+            # a slot that holds no row of the side, or a null, reads zero
+            valid = keys.unpack_bit(words, i) & slot_valid
+            data = jnp.where(valid if c.data.ndim == 1 else valid[:, None],
+                             take(c.data), jnp.zeros((), c.data.dtype))
+            lengths = None if c.lengths is None else jnp.where(
+                valid, take(c.lengths), 0)
+            out.append(Column(data, valid, lengths, c.dtype))
+        return tuple(out)
+
+
+def take_lanes(cols: Sequence[Column]) -> int:
+    """32-bit lanes ``take_side`` sends through the index for ``cols``."""
+    return -(-len(cols) // 32) + sum(
+        keys.row_lanes(buffer) for c in cols for buffer in (c.data, c.lengths)
+        if buffer is not None)
+
+
 @partial(jax.jit, static_argnames=("left_on", "right_on", "join_type",
                                    "out_capacity", "algorithm",
                                    "key_grouped", "project"))
@@ -222,7 +301,8 @@ def join_gather(cols_l: Tuple[Column, ...], count_l,
                 algorithm: str = "sort", key_grouped: bool = False,
                 project: "Tuple[int, ...] | None" = None):
     """Produce gathered output columns (left columns ++ right columns) with
-    capacity ``out_capacity`` and the dynamic output row count.
+    capacity ``out_capacity`` and the dynamic output row count: ``_indices``
+    and a ``take_side`` a side in one program.
 
     ``key_grouped=True`` (INNER only): rows with equal join keys come out
     adjacent, so a downstream group-by on the key can use the boundary-scan
@@ -234,19 +314,9 @@ def join_gather(cols_l: Tuple[Column, ...], count_l,
     offset ``lo``, which uniquely identifies the key group for matched
     rows.  Either way the multi-operand lexsort of the (larger) join
     output downstream is saved."""
-    lo, matches, perm_r, live_l, unmatched_r, left_key_order = _ranges(
+    lidx, ridx, lvalid, rvalid, out_count = _indices(
         cols_l, count_l, cols_r, count_r, left_on, right_on, join_type,
-        algorithm)
-    perm_l = None
-    if key_grouped:
-        if join_type != JoinType.INNER:
-            raise ValueError("key_grouped join output requires INNER")
-        lo, matches, live_l, perm_l = _key_grouped_order(
-            lo, matches, live_l, left_key_order)
-    emit, csum, total = _emission(matches, live_l, join_type)
-    lidx, ridx, lvalid, rvalid, out_count = _expansion(
-        lo, matches, perm_r, unmatched_r, perm_l, emit, csum, total,
-        join_type, out_capacity)
+        out_capacity, algorithm, key_grouped)
 
     # projection pushdown: materialize ONLY the requested output columns
     # (indices into left ++ right), in the requested order — a pruned
@@ -262,12 +332,11 @@ def join_gather(cols_l: Tuple[Column, ...], count_l,
         raise ValueError(f"project indices {bad} out of range for "
                          f"{n_out} output columns (left {n_l} ++ right "
                          f"{n_out - n_l}; negatives not supported)")
-    out = []
-    for j in project:
-        if j < n_l:
-            with stage("join.gather_left"):
-                out.append(cols_l[j].take(lidx, valid_mask=lvalid))
-        else:
-            with stage("join.gather_right"):
-                out.append(cols_r[j - n_l].take(ridx, valid_mask=rvalid))
-    return tuple(out), out_count
+    wanted = sorted(set(project))
+    taken = dict(zip(
+        wanted,
+        take_side([cols_l[j] for j in wanted if j < n_l], lidx, lvalid,
+                  "left")
+        + take_side([cols_r[j - n_l] for j in wanted if j >= n_l], ridx,
+                    rvalid, "right")))
+    return tuple(taken[j] for j in project), out_count

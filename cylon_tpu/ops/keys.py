@@ -187,10 +187,29 @@ def _pack_encoded(enc: Sequence[Tuple[jax.Array, int]]) -> List[jax.Array]:
 _MAX_PAYLOAD_LANES = 12
 
 
-def _row_lanes(buffer: jax.Array) -> int:
+def row_lanes(buffer: jax.Array) -> int:
     """32-bit lanes one row of ``buffer`` fills."""
     row_bytes = buffer.dtype.itemsize * math.prod(buffer.shape[1:])
     return -(-row_bytes // 4)
+
+
+def pack_bits(flags: Sequence[jax.Array]) -> List[jax.Array]:
+    """1-D ``bool`` buffers as bits of ``uint32`` words, 32 to a word: flag
+    ``i`` is bit ``i % 32`` of word ``i // 32`` (``unpack_bit`` reads it).
+    The one packer of validity vectors: a sort's payload and a join's takes
+    move a word where they would move 32 ``pred`` lanes."""
+    words = []
+    for at in range(0, len(flags), 32):
+        word = jnp.zeros(flags[at].shape, jnp.uint32)
+        for bit, flag in enumerate(flags[at:at + 32]):
+            word = word | (flag.astype(jnp.uint32) << jnp.uint32(bit))
+        words.append(word)
+    return words
+
+
+def unpack_bit(words: Sequence[jax.Array], i: int) -> jax.Array:
+    """Flag ``i`` of ``pack_bits``' words, wherever the words' rows went."""
+    return (words[i // 32] >> jnp.uint32(i % 32)) & 1 != 0
 
 
 def pack_payload(buffers: Sequence[jax.Array]):
@@ -202,31 +221,27 @@ def pack_payload(buffers: Sequence[jax.Array]):
     ``_MAX_PAYLOAD_LANES``.
 
     1-D ``bool`` buffers (validity) ride as one bit each, 32 to a ``uint32``
-    word; every other 1-D buffer rides as it is: a sort moves a non-key
-    operand as bits, so NaN payloads, -0.0 and float64 (emulated on a TPU)
-    come back exact."""
+    word (``pack_bits``); every other 1-D buffer rides as it is: a sort
+    moves a non-key operand as bits, so NaN payloads, -0.0 and float64
+    (emulated on a TPU) come back exact."""
     budget = _MAX_PAYLOAD_LANES
     flat = [i for i, b in enumerate(buffers) if b.ndim == 1]
     bits = [i for i in flat if buffers[i].dtype == jnp.bool_][:32 * budget]
-    budget -= -(-len(bits) // 32)
     layout: list = [None] * len(buffers)
-    lanes: List[jax.Array] = []
-    for at in range(0, len(bits), 32):
-        word = jnp.zeros(buffers[bits[at]].shape, jnp.uint32)
-        for bit, i in enumerate(bits[at:at + 32]):
-            word = word | (buffers[i].astype(jnp.uint32) << jnp.uint32(bit))
-            layout[i] = (len(lanes), bit)
-        lanes.append(word)
+    lanes: List[jax.Array] = pack_bits([buffers[i] for i in bits])
+    budget -= len(lanes)
+    for at, i in enumerate(bits):
+        layout[i] = (at // 32, at % 32)
     for i in flat:
         if layout[i] is None and buffers[i].dtype != jnp.bool_ \
-                and _row_lanes(buffers[i]) <= budget:
-            budget -= _row_lanes(buffers[i])
+                and row_lanes(buffers[i]) <= budget:
+            budget -= row_lanes(buffers[i])
             layout[i] = (len(lanes), None)
             lanes.append(buffers[i])
-    rode = sum(_row_lanes(lane) for lane in lanes)
+    rode = sum(row_lanes(lane) for lane in lanes)
     obs_metrics.counter_add("sort.payload_lanes", rode)
     obs_metrics.counter_add("sort.take_lanes", sum(
-        _row_lanes(b) for b, where in zip(buffers, layout) if where is None))
+        row_lanes(b) for b, where in zip(buffers, layout) if where is None))
     return lanes, tuple(layout)
 
 
@@ -240,7 +255,7 @@ def unpack_payload(sorted_lanes: Sequence[jax.Array], layout) -> list:
             continue
         lane, bit = where
         out.append(sorted_lanes[lane] if bit is None else
-                   (sorted_lanes[lane] >> jnp.uint32(bit)) & 1 != 0)
+                   unpack_bit(sorted_lanes, 32 * lane + bit))
     return out
 
 

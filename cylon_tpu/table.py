@@ -1398,6 +1398,9 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
                 cfg.left_on, cfg.right_on, jt, out_cap, algo)
             return Table(cols, jnp.reshape(m, (1,)), names, ctx)
 
+        # 32-bit lanes through the join's slot -> row indices (take_side)
+        obs_metrics.counter_add("join.take_lanes", sum(
+            join_mod.take_lanes(t.columns) for t in (left, right)))
         with obs_span("join.gather"):
             return _shard_wise(ctx, gather_fn, left, right,
                                key=("join", cfg.left_on, cfg.right_on, jt,

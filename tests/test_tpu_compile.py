@@ -16,6 +16,7 @@ tests steer it with what exists — ``precision.on_tpu`` patched true resolves
 every "auto" default the way the chip does — and with no option of the
 program.
 """
+import collections
 import re
 
 import numpy as np
@@ -135,18 +136,34 @@ def test_entry_step_compiles(one_chip, as_on_chip, log2_rows):
     assert precision.narrow() and segments.effective_mode() == "pallas"
     assert "tpu_custom_call" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+    # the join sends two lanes through an index to expand (ops/join.py::
+    # _expansion; four on PR 29's tree) and its validity as bits of a word,
+    # never as a vector.  The expansion's two takes have one signature, so
+    # they share one lowered function and the compiled text names them
+    # "gather" and no stage: they are counted under both names.
+    stages = _gather_stages(compiled)
+    assert 0 < stages["join.expand"] + stages["gather"] <= 2
+    assert stages["join.gather_left"] == 3     # key, value, validity word
+    assert not [line for line in _validity_gathers(compiled)
+                if "jit(join_gather)" in line]
 
 
-def _gather_stages(compiled) -> set:
-    """The stage (``obs.STAGES``, else the whole ``op_name``) of every
-    gather instruction in the compiled program."""
-    stages = set()
+def _gather_stages(compiled) -> collections.Counter:
+    """The gather instructions of the compiled program, counted by stage
+    (``obs.STAGES``, else the whole ``op_name``)."""
+    stages = collections.Counter()
     for line in compiled.as_text().splitlines():
         if re.search(r"= \S+ gather\(", line):
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
             named = [part for part in op_name.split("/") if part in STAGES]
-            stages.add(named[-1] if named else op_name)
+            stages[named[-1] if named else op_name] += 1
     return stages
+
+
+def _validity_gathers(compiled) -> list:
+    """The gather instructions that move a ``pred`` vector."""
+    return [line for line in compiled.as_text().splitlines()
+            if re.search(r"= pred\[\S* gather\(", line)]
 
 
 @pytest.mark.parametrize("program", [
@@ -195,7 +212,7 @@ def test_local_kernels_compile(one_chip, as_on_chip, program):
     compiled = lowered.compile()
     assert _device_bytes(compiled) < HBM_BYTES
     if gathers is not None:
-        assert _gather_stages(compiled) <= gathers
+        assert set(_gather_stages(compiled)) <= gathers
 
 
 @pytest.mark.parametrize("with_string", [False, True],
