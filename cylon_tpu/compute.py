@@ -53,31 +53,38 @@ def _result_col(data: jax.Array, validity: jax.Array, dt: dtypes.DataType) -> Co
     return Column(data, validity, None, dt)
 
 
-def _string_word_compare(col: Column, value: str, op_name: str) -> jax.Array:
-    """Lexicographic compare of a string column against a scalar, on the
+#: a string scalar's words come in fours (32 bytes), so that scalars of
+#: most lengths have one shape: a stage program that takes the words as an
+#: operand (plan/expr.py) is then the same program for all of them
+_SCALAR_WORDS_STEP = 4
+
+
+def string_scalar_words(value: str) -> np.ndarray:
+    """``value`` in the packed big-endian ``uint64`` word encoding of
+    ``keys.pack_string_words``, zero-padded to a multiple of
+    ``_SCALAR_WORDS_STEP`` words."""
+    enc = value.encode("utf-8")
+    step = 8 * _SCALAR_WORDS_STEP
+    padded = np.zeros((max(1, -(-len(enc) // step)) * step,), np.uint8)
+    padded[:len(enc)] = np.frombuffer(enc, np.uint8)
+    svals = padded.reshape(-1, 8).astype(np.uint64)
+    shifts = np.array([56, 48, 40, 32, 24, 16, 8, 0], np.uint64)
+    return (svals << shifts).sum(axis=1, dtype=np.uint64)
+
+
+def _string_word_compare(col: Column, swords, op_name: str) -> jax.Array:
+    """Lexicographic compare of a string column against a scalar's words
+    (``string_scalar_words``; host values or a traced operand), on the
     packed big-endian word encoding (reference compares through arrow
-    compute / object loops, compute.pyx:92-153; here it is vectorized)."""
+    compute / object loops, compute.pyx:92-153; here it is vectorized).
+    Where the scalar is longer than the column's padded width, equal-prefix
+    rows compare less-than: the column's missing words are zeros."""
     from .ops import keys as keys_mod
 
     words = keys_mod.pack_string_words(col.data)
-    enc = value.encode("utf-8")
-    width = col.data.shape[1]
-    buf = np.zeros((max(width, len(enc)),), np.uint8)
-    buf[:len(enc)] = np.frombuffer(enc, np.uint8)
-    if len(enc) > width:
-        # scalar longer than the column's padded width: equal-prefix rows
-        # compare less-than
-        pass
-    padded = np.zeros(((len(buf) + 7) // 8 * 8,), np.uint8)
-    padded[:len(buf)] = buf
-    svals = padded.reshape(-1, 8).astype(np.uint64)
-    shifts = np.array([56, 48, 40, 32, 24, 16, 8, 0], np.uint64)
-    swords = (svals << shifts).sum(axis=1, dtype=np.uint64)
-
     lt = jnp.zeros(col.data.shape[:1], bool)
     gt = jnp.zeros(col.data.shape[:1], bool)
-    nw = max(len(words), len(swords))
-    for i in range(nw):
+    for i in range(max(len(words), len(swords))):
         w = words[i] if i < len(words) else jnp.zeros_like(words[0])
         s = jnp.uint64(swords[i]) if i < len(swords) else jnp.uint64(0)
         undecided = ~(lt | gt)
@@ -100,6 +107,8 @@ def _col_compare(col: Column, other, op_name: str, other_col: Optional[Column]) 
         validity = col.validity & other_col.validity
         return _result_col(data, validity, dtypes.bool_)
     if isinstance(other, str):
+        other = string_scalar_words(other)
+    if getattr(other, "ndim", 0) == 1:  # a string scalar's words
         if not col.is_string:
             raise CylonError(Code.Invalid, f"cannot compare {col.dtype} to str")
         data = _string_word_compare(col, other, op_name)
@@ -316,7 +325,8 @@ def is_in(table, values: Sequence, skip_null: bool = True):
             svals = [v for v in vals if isinstance(v, str)]
             hit = jnp.zeros(c.data.shape[:1], bool)
             for s in svals:
-                hit = hit | _string_word_compare(c, s, "eq")
+                hit = hit | _string_word_compare(
+                    c, string_scalar_words(s), "eq")
         else:
             nums = [v for v in vals if not isinstance(v, str) and v is not None]
             if nums:

@@ -316,11 +316,50 @@ def lexsort_indices(operands: Sequence[jax.Array], capacity: int,
         s_lo = s_lo >> jnp.uint32(idx_bits)
         return perm, ([s_lo] if len(words) == 1 else [s_hi, s_lo]), moved
     packed = _pack_encoded(enc)
+    if len(packed) > _MAX_KEY_WORDS:
+        perm = _word_by_word_argsort(packed, capacity)
+        return (perm, [jnp.take(word, perm) for word in packed],
+                _ride_ranks(perm, payload))
     iota = jnp.arange(capacity, dtype=jnp.int32)
     sorted_all = jax.lax.sort(tuple(packed) + (iota,),
                               num_keys=len(packed), is_stable=True)
     perm = sorted_all[-1]
     return perm, list(sorted_all[:-1]), _ride_ranks(perm, payload)
+
+
+#: Most packed key words one stable sort compares (two 64-bit keys and
+#: their flags are four).  Past it (string keys: a 32-byte string is four
+#: 64-bit words) the sort goes a word at a time.  The
+#: chip's compiler takes 4.8 / 19 / 108 s for a stable sort of 1 / 3 / 9
+#: ``u32`` keys at 2^14 rows and twelve times that at 393,216 (described
+#: v5e, PERF.md Findings PR 31: Q5's group-by on ``n_name`` compiled in
+#: 578 s); the loop below compiles one one-key sort whatever the words.
+_MAX_KEY_WORDS = 4
+
+
+def _word_by_word_argsort(packed: Sequence[jax.Array],
+                          capacity: int) -> jax.Array:
+    """The stable lexicographic argsort of ``packed`` as one stable one-key
+    sort a 32-bit word, least significant first, each word taken through
+    the order so far: a loop whose body holds one sort.  It pays a gather a
+    word, so it is for keys too long to compare in one sort."""
+    words = []
+    for word in packed:
+        if word.dtype.itemsize == 8:
+            words += [(word >> jnp.asarray(32, word.dtype)).astype(jnp.uint32),
+                      word.astype(jnp.uint32)]
+        else:
+            words.append(word.astype(jnp.uint32))
+    stacked = jnp.stack(words)
+
+    def one_word(i, perm):
+        word = jax.lax.dynamic_index_in_dim(stacked, len(words) - 1 - i,
+                                            keepdims=False)
+        return jax.lax.sort((jnp.take(word, perm), perm), num_keys=1,
+                            is_stable=True)[1]
+
+    return jax.lax.fori_loop(0, len(words), one_word,
+                             jnp.arange(capacity, dtype=jnp.int32))
 
 
 def _ride_ranks(perm: jax.Array, payload: Tuple[jax.Array, ...]):

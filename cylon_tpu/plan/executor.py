@@ -17,6 +17,14 @@ the same values; and an elided group-by's final combine folds exactly
 one partial per group (co-location guarantees it), which is the
 identity for every combine op.
 
+A stage the executor builds itself -- a filter with its compaction, a
+derive, the fused join's count pass, the fused join -> chain ->
+aggregate -- is ONE cached program (``plan_filter``, ``plan_derive``,
+``plan_join_count``, ``plan_fused``) on one shard as on many
+(``_Executor._stage``), keyed by the shape of its expressions and taking
+their literals as operands: a predicate with a date nobody sent before
+builds no program.
+
 Durable/serve integration is at PLAN granularity: one fingerprint for
 the whole op chain (``LogicalPlan.fingerprint``), one journaled result
 frame — a repeated plan replays from spill with zero compiles and zero
@@ -35,6 +43,7 @@ from ..obs import metrics as obs_metrics
 from ..obs import spans as obs_spans
 from ..obs import stats_catalog
 from ..status import Code, CylonError, Status
+from . import expr as expr_mod
 from . import ir, optimizer
 from . import profile as profile_mod
 
@@ -234,8 +243,8 @@ class _Executor:
         if isinstance(n, ir.Project):
             return self._project_to(self._exec(p.children[0]), p.keep)
         if isinstance(n, ir.Filter):
-            t = self._filter_table(self._exec(p.children[0]), n.pred)
-            return self._project_to(t, p.keep)
+            return self._filter_table(self._exec(p.children[0]), n.pred,
+                                      p.keep)
         if isinstance(n, ir.Derive):
             t = self._exec(p.children[0])
             if not p.ann.get("dead"):
@@ -260,42 +269,63 @@ class _Executor:
         return t.project(list(keep))
 
     # -- scans / local row ops -------------------------------------------
-    def _filter_table(self, t, pred):
+    def _stage(self, name: str, kind: str, fn, tables, key: tuple,
+               exprs=(), **attrs):
+        """Launch one stage program: ``fn(*tables, *operands)`` as the
+        cached program ``name`` (``table._shard_wise``), keyed by ``key``
+        and the shapes of ``exprs``, whose literals it takes as operands.
+        Span ``plan.stage`` of ``kind`` carries the program's name;
+        counters ``plan.stage_programs`` and ``plan.literal_operands``."""
+        from ..table import _shard_wise
+
+        operands = expr_mod.host_operands(exprs)
+        obs_metrics.counter_add("plan.stage_programs")
+        obs_metrics.counter_add("plan.literal_operands", len(operands))
+        with obs_spans.span("plan.stage", kind=kind, program=name, **attrs):
+            return _shard_wise(tables[0].ctx, fn, *tables,
+                               key=(name, key,
+                                    tuple(e.shape() for e in exprs)),
+                               name=name, operands=operands)
+
+    def _filter_table(self, t, pred, keep: Optional[Tuple[str, ...]] = None):
+        """The rows of ``t`` that ``pred`` keeps, compacted, as columns
+        ``keep`` (all of them where it is None): one program, so a column
+        the predicate alone reads never goes through the compaction."""
         import jax.numpy as jnp
 
         from ..ops import compact as compact_mod
-        from ..table import Table, _shard_wise
+        from ..table import Table
 
         names, ctx = t.names, t.ctx
+        keep = names if keep is None else tuple(keep)
 
-        def fn(tt):
+        def fn(tt, *lits):
             cap = tt.columns[0].data.shape[0]
             env = dict(zip(names, tt.columns))
-            c = pred.evaluate(env)
-            keep = c.data & c.validity & compact_mod.live_mask(
+            c = pred.evaluate(env, lits)
+            mask = c.data & c.validity & compact_mod.live_mask(
                 cap, tt.row_counts[0])
-            perm, m = compact_mod.compact_indices(keep)
+            perm, m = compact_mod.compact_indices(mask)
             live = compact_mod.live_mask(cap, m)
-            cols = tuple(col.take(perm, valid_mask=live)
-                         for col in tt.columns)
-            return Table(cols, jnp.reshape(m, (1,)), names, ctx)
+            cols = tuple(env[n].take(perm, valid_mask=live) for n in keep)
+            return Table(cols, jnp.reshape(m, (1,)), keep, ctx)
 
-        return _shard_wise(ctx, fn, t, key=("plan_filter", names,
-                                            pred.spec()))
+        return self._stage("plan_filter", "filter", fn, (t,),
+                           (names, keep), (pred,))
 
     def _derive_table(self, t, name: str, value):
-        from ..table import Table, _shard_wise
+        from ..table import Table
 
         names, ctx = t.names, t.ctx
         out_names = names + (name,)
 
-        def fn(tt):
+        def fn(tt, *lits):
             env = dict(zip(names, tt.columns))
-            c = value.evaluate(env)
+            c = value.evaluate(env, lits)
             return Table(tt.columns + (c,), tt.row_counts, out_names, ctx)
 
-        return _shard_wise(ctx, fn, t, key=("plan_derive", names, name,
-                                            value.spec()))
+        return self._stage("plan_derive", "derive", fn, (t,),
+                           (names, name), (value,))
 
     def _exec_chain(self, p: optimizer.Phys, keep: Tuple[str, ...]):
         """Execute a pure scan chain with an overridden column set (the
@@ -327,9 +357,8 @@ class _Executor:
             below = tuple(dict.fromkeys(tuple(keep)
                                         + tuple(sorted(n.pred.columns()))))
             t = self._exec_chain(child, below)
-            t = self._filter_table(t, n.pred)
-            return self._project_to(t, tuple(c for c in t.names
-                                             if c in set(keep)))
+            return self._filter_table(t, n.pred, tuple(
+                c for c in t.names if c in set(keep)))
         if isinstance(n, ir.Derive):
             below = tuple(dict.fromkeys(
                 tuple(c for c in keep if c != n.name)
@@ -468,7 +497,7 @@ class _Executor:
         from ..ops import groupby as groupby_mod
         from ..ops import join as join_mod
         from ..parallel import ops as par_ops
-        from ..table import Table, _cap_round, _shard_wise
+        from ..table import Table, _cap_round, _get_everywhere, host_sync
 
         node: ir.Aggregate = p.node  # type: ignore[assignment]
         jphys: optimizer.Phys = p.ann["fuse_join"]  # type: ignore
@@ -488,8 +517,15 @@ class _Executor:
             self._note_elided("aggregate", node.by)
 
         self._guard()
-        stage_spec = ("plan_fused", jnode.spec()[:7], node.spec()[:4],
-                      tuple(ph.node.spec()[:3] for ph in chain))
+        # the chain's literals are the fused program's operands: its key
+        # holds what each node does, not the values it does it with
+        steps = [ph for ph in chain if isinstance(ph.node, ir.Filter) or (
+            isinstance(ph.node, ir.Derive) and not ph.ann.get("dead"))]
+        exprs = tuple(ph.node.pred if isinstance(ph.node, ir.Filter)
+                      else ph.node.value for ph in steps)
+        stage_spec = (jnode.spec()[:7], node.spec()[:4], tuple(
+            (ph.node.kind, getattr(ph.node, "name", None),
+             bool(ph.ann.get("dead"))) for ph in chain))
 
         def count_fn(a, b):
             c = join_mod.join_row_count(
@@ -497,10 +533,10 @@ class _Executor:
                 cfg.left_on, cfg.right_on, jt, algo)
             return jnp.reshape(c, (1,))
 
-        with obs_spans.span("plan.stage", kind="join_count"):
-            counts = _shard_wise(ctx, count_fn, lt, rt,
-                                 key=("plan_join_count", stage_spec))
-            out_cap = _cap_round(max(1, int(jnp.max(counts))))
+        counts = self._stage("plan_join_count", "join_count", count_fn,
+                             (lt, rt), stage_spec[:1])
+        out_cap = _cap_round(max(1, int(np.max(host_sync(
+            counts, "plan.join_count", _get_everywhere)))))
         if self.profile is not None:
             # the fused join never materializes, but the exact count
             # pass that sizes it IS its observed cardinality — record
@@ -518,27 +554,27 @@ class _Executor:
             partial_list, partial_index = par_ops.groupby_partial_plan(
                 aggs_by_name)
 
-        def fused_fn(a: Table, b: Table) -> Table:
+        def fused_fn(a: Table, b: Table, *lits) -> Table:
+            operands = expr_mod.split_operands(exprs, lits)
             cols, m = join_mod.join_gather(
                 a.columns, a.row_counts[0], b.columns, b.row_counts[0],
                 cfg.left_on, cfg.right_on, jt, out_cap, algo)
             env = dict(zip(join_names, cols))
             count = m
-            for ph in reversed(chain):
+            for ph, ops in reversed(list(zip(steps, operands))):
                 cn = ph.node
                 if isinstance(cn, ir.Derive):
-                    if not ph.ann.get("dead"):
-                        env[cn.name] = cn.value.evaluate(env)
-                elif isinstance(cn, ir.Filter):
+                    env[cn.name] = cn.value.evaluate(env, ops)
+                else:
                     cap = next(iter(env.values())).data.shape[0]
-                    c = cn.pred.evaluate(env)
+                    c = cn.pred.evaluate(env, ops)
                     keepm = c.data & c.validity & compact_mod.live_mask(
                         cap, count)
                     perm, count = compact_mod.compact_indices(keepm)
                     live = compact_mod.live_mask(cap, count)
                     env = {k: col.take(perm, valid_mask=live)
                            for k, col in env.items()}
-                # Project: column selection is implicit in env-by-name
+                # Project, dead Derive: nothing to run, env is by name
             in_names = tuple(by_names) + tuple(n for n, _ in aggs_by_name)
             in_names = tuple(dict.fromkeys(in_names))
             in_cols = tuple(env[n] for n in in_names)
@@ -569,11 +605,9 @@ class _Executor:
             return Table(tuple(out_cols), jnp.reshape(fm, (1,)),
                          agg_names, ctx)
 
-        with obs_spans.span("plan.stage", kind="fused_join_agg",
-                            mode=mode, out_cap=out_cap):
-            out = _shard_wise(ctx, fused_fn, lt, rt,
-                              key=("plan_fused_exec", stage_spec, out_cap))
-        return out
+        return self._stage("plan_fused", "fused_join_agg", fused_fn,
+                           (lt, rt), (stage_spec, out_cap), exprs,
+                           mode=mode, out_cap=out_cap)
 
     # -- sort / limit -----------------------------------------------------
     def _exec_sort(self, p: optimizer.Phys):

@@ -8,7 +8,16 @@ read set).  This module is the lazy twin: a tiny expression tree
 (``col``/``lit`` + arithmetic/comparison/logical operators) whose
 
 - ``spec()`` is a canonical primitive tuple (feeds
-  :func:`cylon_tpu.durable.run_fingerprint` unchanged),
+  :func:`cylon_tpu.durable.run_fingerprint` unchanged) and names a
+  *result*: it holds every literal's value,
+- ``shape()`` names a *program*: operators, columns and literal types.
+  The executor keys a stage program by it and hands the program the
+  values of ``literals()`` as operands, by position, so a predicate with
+  a date or a name nobody sent before compiles nothing.  A compared
+  string goes as its packed words (``compute.string_scalar_words``; the
+  shape holds how many).  A literal divisor stays in the shape with its
+  value (zero is refused at trace time), and so does a string that is
+  not compared,
 - ``columns()`` is the exact read set (drives the optimizer's pruning),
 - ``evaluate(env)`` lowers onto the SAME kernels the eager compute layer
   uses (``cylon_tpu.compute._col_math`` / ``_col_compare``), so a
@@ -21,7 +30,7 @@ pandas behavior (NaN comparisons are False).
 """
 from __future__ import annotations
 
-from typing import Dict, Set, Tuple, Union
+from typing import Dict, Optional, Sequence, Set, Tuple, Union
 
 import numpy as np
 
@@ -44,10 +53,24 @@ class Expr:
     def spec(self) -> tuple:
         raise NotImplementedError
 
+    def shape(self) -> tuple:
+        raise NotImplementedError
+
+    def literals(self) -> Tuple["Lit", ...]:
+        """The literals whose values ``shape()`` leaves out, in its
+        order: a stage program's scalar operands."""
+        raise NotImplementedError
+
     def columns(self) -> Set[str]:
         raise NotImplementedError
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
+    def evaluate(self, env: Dict[str, Column],
+                 operands: Optional[Sequence] = None) -> Column:
+        """``operands``, where given, stand in for the values of
+        ``literals()``, one for one in its order: traced scalars inside a
+        stage program.  By position, never by the ``Lit`` object: one
+        object may stand in two places of a tree, and the next tree of
+        the same shape has two values there."""
         raise NotImplementedError
 
     # -- operator surface ----------------------------------------------
@@ -132,10 +155,15 @@ class Col(Expr):
     def spec(self) -> tuple:
         return ("col", self.name)
 
+    shape = spec
+
+    def literals(self) -> Tuple["Lit", ...]:
+        return ()
+
     def columns(self) -> Set[str]:
         return {self.name}
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
+    def evaluate(self, env: Dict[str, Column], operands=None) -> Column:
         if self.name not in env:
             raise CylonError(Code.KeyError,
                              f"expression references unknown column "
@@ -153,10 +181,32 @@ class Lit(Expr):
     def spec(self) -> tuple:
         return ("lit", type(self.value).__name__, self.value)
 
+    def shape(self) -> tuple:
+        if isinstance(self.value, str):
+            return ("lit", "str", len(self.host_operand()))
+        return ("lit", type(self.value).__name__)
+
+    def literals(self) -> Tuple["Lit", ...]:
+        return (self,)
+
+    def host_operand(self):
+        """What a stage hands its program for this literal: the value, or
+        a string's packed words."""
+        if isinstance(self.value, str):
+            from ..compute import string_scalar_words
+
+            return string_scalar_words(self.value)
+        return self.value
+
+    def operand(self, operands: Optional[Sequence]):
+        """The value to compute with: the stage program's operand where
+        it was given one, else the host value."""
+        return operands[0] if operands else self.value
+
     def columns(self) -> Set[str]:
         return set()
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
+    def evaluate(self, env: Dict[str, Column], operands=None) -> Column:
         # a bare literal never evaluates standalone: Bin special-cases
         # literal operands into the compute layer's scalar paths
         raise CylonError(Code.Invalid,
@@ -173,48 +223,74 @@ class Bin(Expr):
     def spec(self) -> tuple:
         return ("bin", self.op, self.left.spec(), self.right.spec())
 
+    def _in_key(self, side: Expr) -> bool:
+        """A literal side whose value stays in the program's key and is
+        no operand: a divisor, and a string that is not compared."""
+        if not isinstance(side, Lit):
+            return False
+        if side is self.right and self.op == "truediv":
+            return True
+        return isinstance(side.value, str) and self.op not in _CMP
+
+    def _side_literals(self, side: Expr) -> Tuple["Lit", ...]:
+        return () if self._in_key(side) else side.literals()
+
+    def shape(self) -> tuple:
+        return ("bin", self.op) + tuple(
+            side.spec() if self._in_key(side) else side.shape()
+            for side in (self.left, self.right))
+
+    def literals(self) -> Tuple["Lit", ...]:
+        return (self._side_literals(self.left)
+                + self._side_literals(self.right))
+
     def columns(self) -> Set[str]:
         return self.left.columns() | self.right.columns()
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
+    def evaluate(self, env: Dict[str, Column], operands=None) -> Column:
         from .. import compute as compute_mod
 
         op = self.op
         lv, rv = self.left, self.right
+        lops = rops = None  # each side's own operands, by position
+        if operands is not None:
+            n = len(self._side_literals(lv))
+            lops, rops = operands[:n], operands[n:]
         if isinstance(lv, Lit) and isinstance(rv, Lit):
             raise CylonError(Code.Invalid,
                              "literal-only expression; fold it on the host")
         # scalar fast paths mirror the eager compute layer exactly
         if isinstance(rv, Lit):
-            lc = lv.evaluate(env)
+            lc = lv.evaluate(env, lops)
             if op in _CMP:
-                return compute_mod._col_compare(lc, rv.value, op, None)
+                return compute_mod._col_compare(lc, rv.operand(rops), op,
+                                                None)
             if op in _MATH:
-                return compute_mod._col_math(lc, rv.value, op, None)
+                return compute_mod._col_math(lc, rv.operand(rops), op, None)
         if isinstance(lv, Lit):
-            rc = rv.evaluate(env)
+            rc = rv.evaluate(env, rops)
+            lo = lv.operand(lops)
             if op in _CMP:  # flip: lit < col  ==  col > lit
-                return compute_mod._col_compare(rc, lv.value, _FLIP[op], None)
+                return compute_mod._col_compare(rc, lo, _FLIP[op], None)
             if op in ("add", "mul"):
-                return compute_mod._col_math(rc, lv.value, op, None)
+                return compute_mod._col_math(rc, lo, op, None)
             if op == "sub":  # lit - col == (-col) + lit
-                return compute_mod._col_math(_neg_col(rc), lv.value, "add",
-                                             None)
+                return compute_mod._col_math(_neg_col(rc), lo, "add", None)
             if op == "truediv":  # lit / col: materialize the literal
-                lc = _lit_column(lv.value, rc)
+                lc = _lit_column(lo, type(lv.value), rc)
                 return compute_mod._col_math(lc, None, op, rc)
         if op in _LOGICAL and isinstance(rv, Lit):
             # a literal bool operand (often the residue of constant
             # folding, e.g. `pred & (lit(1) < lit(2))`): materialize it
             # against the evaluated side instead of crashing
-            lc = lv.evaluate(env)
-            rc = _lit_column(bool(rv.value), lc)
+            lc = lv.evaluate(env, lops)
+            rc = _lit_column(_truth(rv.operand(rops)), bool, lc)
         elif op in _LOGICAL and isinstance(lv, Lit):
-            rc = rv.evaluate(env)
-            lc = _lit_column(bool(lv.value), rc)
+            rc = rv.evaluate(env, rops)
+            lc = _lit_column(_truth(lv.operand(lops)), bool, rc)
         else:
-            lc = lv.evaluate(env)
-            rc = rv.evaluate(env)
+            lc = lv.evaluate(env, lops)
+            rc = rv.evaluate(env, rops)
         if op in _CMP:
             return compute_mod._col_compare(lc, None, op, rc)
         if op in _MATH:
@@ -238,16 +314,22 @@ class Not(Expr):
     def spec(self) -> tuple:
         return ("not", self.e.spec())
 
+    def shape(self) -> tuple:
+        return ("not", self.e.shape())
+
+    def literals(self) -> Tuple["Lit", ...]:
+        return self.e.literals()
+
     def columns(self) -> Set[str]:
         return self.e.columns()
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
+    def evaluate(self, env: Dict[str, Column], operands=None) -> Column:
         import jax.numpy as jnp
 
         from .. import compute as compute_mod
         from .. import dtypes
 
-        c = self.e.evaluate(env)
+        c = self.e.evaluate(env, operands)
         if c.data.dtype != jnp.bool_:
             raise CylonError(Code.Invalid, "~ needs a boolean operand")
         return compute_mod._result_col(~c.data, c.validity, dtypes.bool_)
@@ -260,11 +342,17 @@ class Neg(Expr):
     def spec(self) -> tuple:
         return ("neg", self.e.spec())
 
+    def shape(self) -> tuple:
+        return ("neg", self.e.shape())
+
+    def literals(self) -> Tuple["Lit", ...]:
+        return self.e.literals()
+
     def columns(self) -> Set[str]:
         return self.e.columns()
 
-    def evaluate(self, env: Dict[str, Column]) -> Column:
-        return _neg_col(self.e.evaluate(env))
+    def evaluate(self, env: Dict[str, Column], operands=None) -> Column:
+        return _neg_col(self.e.evaluate(env, operands))
 
 
 def _neg_col(c: Column) -> Column:
@@ -278,17 +366,23 @@ def _neg_col(c: Column) -> Column:
     return Column(data, c.validity, None, c.dtype)
 
 
-def _lit_column(value: Scalar, like: Column) -> Column:
-    """Materialize a scalar as a full column with ``like``'s capacity —
+def _truth(value):
+    """A host literal's truth value; a traced operand casts on the device."""
+    return bool(value) if isinstance(value, (bool, int, float, str)) else value
+
+
+def _lit_column(value, kind: type, like: Column) -> Column:
+    """Materialize a scalar (the host value, or a stage program's operand)
+    of Python type ``kind`` as a full column with ``like``'s capacity —
     only for the rare non-flippable literal-first forms."""
     import jax.numpy as jnp
 
     from .. import dtypes
 
-    if isinstance(value, str):
+    if kind is str:
         raise CylonError(Code.Invalid, "string literals only compare")
-    dt = (jnp.bool_ if isinstance(value, bool)
-          else jnp.int32 if isinstance(value, int) else jnp.float32)
+    dt = (jnp.bool_ if kind is bool
+          else jnp.int32 if kind is int else jnp.float32)
     cap = like.data.shape[0]
     data = jnp.full((cap,), value, dt)
     return Column(data, jnp.ones((cap,), bool), None,
@@ -312,6 +406,23 @@ def _fold(op: str, left: "Lit", right: "Lit") -> "Lit":
         raise CylonError(Code.Invalid,
                          f"cannot fold literal expression "
                          f"({left.value!r} {op} {right.value!r}): {e}")
+
+
+def host_operands(exprs: Sequence[Expr]) -> tuple:
+    """What a stage hands its program: the literals of ``exprs``,
+    expression after expression."""
+    return tuple(node.host_operand() for e in exprs for node in e.literals())
+
+
+def split_operands(exprs: Sequence[Expr], values: Sequence) -> list:
+    """``values`` (the program's side of ``host_operands(exprs)``) as each
+    expression's own ``evaluate`` operands."""
+    out, at = [], 0
+    for e in exprs:
+        n = len(e.literals())
+        out.append(tuple(values[at:at + n]))
+        at += n
+    return out
 
 
 def _as_expr(v) -> Expr:

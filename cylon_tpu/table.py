@@ -1042,16 +1042,24 @@ class _RowEnv:
 
 
 
-def _shard_wise(ctx: CylonContext, fn, *tables: Table, key: tuple):
+def _shard_wise(ctx: CylonContext, fn, *tables: Table, key: tuple,
+                name: Optional[str] = None, operands: tuple = ()):
     """Run a per-shard table function: directly for 1-shard tables, under a
     cached jitted shard_map over the mesh otherwise.  This is how every
     'local' op of the reference (executed independently per MPI rank) maps
-    onto the mesh."""
-    t0 = tables[0]
-    if t0.num_shards == 1:
-        return fn(*tables)
-    from jax.sharding import PartitionSpec as P
+    onto the mesh.
 
+    ``name`` makes the function a *stage program* (the planner's filter,
+    derive, join-count and fused stages): one cached program of that name
+    on one shard as on many, so a stage of eager primitives is compiled
+    once and passes over its columns once.  An eager op's kernels are
+    jitted programs already and run directly on one shard.  ``operands``
+    are scalars the program takes after the tables (a predicate's
+    literals), the same on every shard: their values are not in ``key``."""
+    t0 = tables[0]
+    world = t0.num_shards
+    if world == 1 and name is None:
+        return fn(*tables)
     from . import config
 
     # LRU-bounded: select predicates key entries by object identity, so an
@@ -1060,7 +1068,7 @@ def _shard_wise(ctx: CylonContext, fn, *tables: Table, key: tuple):
     # bodies trace accum/segsum/permute modes, and flipping one mid-process
     # must retrace, never serve the other realization (cylint CY103)
     cache = ctx_cache(ctx, "_shard_fn_cache", maxsize=256)
-    cache_key = (key, t0.num_shards,
+    cache_key = (key, world,
                  tuple(t.capacity for t in tables),
                  tuple(t.names for t in tables),
                  tuple(tuple((c.dtype, c.data.shape[1:]) for c in t.columns)
@@ -1069,15 +1077,22 @@ def _shard_wise(ctx: CylonContext, fn, *tables: Table, key: tuple):
     entry = cache.get(cache_key)
     if entry is None:
         obs_metrics.counter_add("plan_cache.miss")
-        from .utils import shard_map
+        if name is not None:
+            fn.__name__ = fn.__qualname__ = name
+        if world > 1:
+            from jax.sharding import PartitionSpec as P
 
-        spec = P(PARTITION_AXIS)
-        entry = jax.jit(shard_map(fn, mesh=ctx.mesh, in_specs=spec,
-                                  out_specs=spec, check_vma=False))
-        cache[cache_key] = entry
+            from .utils import shard_map
+
+            spec = P(PARTITION_AXIS)
+            fn = shard_map(fn, mesh=ctx.mesh,
+                           in_specs=(spec,) * len(tables)
+                           + (P(),) * len(operands),
+                           out_specs=spec, check_vma=False)
+        entry = cache[cache_key] = jax.jit(fn)
     else:
         obs_metrics.counter_add("plan_cache.hit")
-    return entry(*tables)
+    return entry(*tables, *operands)
 
 
 # a shard's live rows leave the device in slices of a multiple of

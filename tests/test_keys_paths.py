@@ -281,3 +281,37 @@ def test_pack_payload_leaves_to_the_take_what_cannot_ride():
     assert rode == 1 + budget // 2 * 2 and rode + took == 1 + 2 * len(wide)
     assert [w is None for w in layout[1:]] == [
         i >= budget // 2 for i in range(len(wide))]
+
+
+@pytest.mark.parametrize("dtypes_", [("uint32",) * 5,
+                                     ("uint32", "uint64", "uint64", "uint64",
+                                      "uint64")],
+                         ids=["five_u32", "flags_and_a_32_byte_string"])
+def test_word_by_word_argsort_is_the_stable_lexicographic_order(dtypes_):
+    """Keys of more than ``_MAX_KEY_WORDS`` packed words (string keys) are
+    sorted a 32-bit word at a time; the order is the one the many-key
+    stable sort gives, ties in row order."""
+    import jax.numpy as jnp
+
+    from cylon_tpu.ops import keys
+
+    rng = np.random.default_rng(3)
+    n = 4096
+    # few distinct values a word: ties on every prefix
+    packed = [rng.integers(0, 3, n).astype(dt) << (40 if dt == "uint64"
+                                                   else 0)
+              | rng.integers(0, 2, n).astype(dt) for dt in dtypes_]
+    assert len(packed) > keys._MAX_KEY_WORDS
+    perm = np.asarray(keys._word_by_word_argsort(
+        [jnp.asarray(p) for p in packed], n))
+    want = np.lexsort([p for p in reversed(packed)])   # stable
+    assert np.array_equal(perm, want)
+    # and through lexsort_indices: the sorted words come back too
+    got_perm, sorted_words, _ = keys.lexsort_indices(
+        [jnp.asarray(p) for p in packed], n)
+    assert np.array_equal(np.asarray(got_perm), want)
+    as_packed = keys._pack_encoded(
+        [keys._ordered_unsigned(jnp.asarray(p)) for p in packed])
+    assert len(sorted_words) == len(as_packed)
+    for word, p in zip(sorted_words, as_packed):
+        assert np.array_equal(np.asarray(word), np.asarray(p)[want])

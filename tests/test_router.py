@@ -247,7 +247,11 @@ def test_tenant_affinity_sticks_under_load(fleet):
                 timeout_s=WAIT_S)[1]
         pt = threading.Thread(target=pinned, daemon=True)
         pt.start()
-        _, s3 = fleet.client.route("t2", "join", l2, r2, on="k",
+        # inputs of t2's own: the request key is content-only, so with
+        # t1's it would follow t1's fingerprint (cache affinity) to the
+        # gated replica whenever t1's accept came first
+        l3, r3 = _inputs(14)
+        _, s3 = fleet.client.route("t2", "join", l3, r3, on="k",
                                    passes=1, mode="hash",
                                    timeout_s=WAIT_S)
         assert s3["router"]["replica"] == 1

@@ -215,6 +215,52 @@ def test_local_kernels_compile(one_chip, as_on_chip, program):
         assert set(_gather_stages(compiled)) <= gathers
 
 
+def test_plan_filter_stage_compiles_with_literal_operands(
+        one_chip, as_on_chip, monkeypatch):
+    """TPC-H Q5's date range over the orders at SF 10 (2^24-row buffers,
+    bench/configs/tpch_q5.json) as the planner launches it: one program
+    named ``plan_filter`` whose two literals are scalar operands, so no
+    date compiles it again; the date column the predicate alone reads goes
+    through no compaction."""
+    from types import SimpleNamespace
+
+    from cylon_tpu import CylonContext, Table
+    from cylon_tpu import table as table_mod
+    from cylon_tpu.plan import col, executor
+
+    ctx = CylonContext.Init()
+    names = ("o_orderkey", "o_custkey", "o_orderdate")
+    orders = Table(tuple(_col(ROWS, jnp.int32, dtypes.int32, one_chip)
+                         for _ in names),
+                   jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip),
+                   names, ctx)
+    launched = {}
+
+    def launch(ctx, fn, *tables, key, name, operands):
+        launched.update(fn=fn, tables=tables, key=key, name=name,
+                        operands=operands)
+        return tables[0]
+
+    monkeypatch.setattr(table_mod, "_shard_wise", launch)
+    pred = (col("o_orderdate") >= 731) & (col("o_orderdate") < 1096)
+    ex = executor._Executor(None, SimpleNamespace(root=None, world=1), ctx,
+                            None)
+    ex._filter_table(orders, pred, names[:2])
+    assert launched["name"] == "plan_filter"
+    assert launched["operands"] == (731, 1096)
+    assert "731" not in repr(launched["key"])
+    lowered = jax.jit(launched["fn"]).lower(orders, *launched["operands"])
+    scalars = [a for a in jax.tree_util.tree_leaves(lowered.args_info)
+               if a.shape == ()]
+    assert len(scalars) == 2
+    compiled = lowered.compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+    # two columns and one validity word each through the compaction: the
+    # date is compared and dropped
+    out = compiled.output_shardings
+    assert len(jax.tree_util.tree_leaves(out)) == 2 * 2 + 1
+
+
 @pytest.mark.parametrize("with_string", [False, True],
                          ids=["i32_f32", "i32_f32_str"])
 def test_ragged_shuffle_compiles_on_four_chips(topo, as_on_chip, with_string):
