@@ -126,6 +126,18 @@ class Column:
                 lengths = jnp.where(validity, lengths, 0)
         return Column(data, validity, lengths, self.dtype)
 
+    def masked(self, valid_mask: jax.Array) -> "Column":
+        """The rows under ``valid_mask`` and null elsewhere, as
+        ``take(indices, valid_mask)`` leaves them: a null reads zero in
+        data and lengths."""
+        validity = self.validity & valid_mask
+        data = jnp.where(validity if self.data.ndim == 1 else
+                         validity[:, None], self.data,
+                         jnp.zeros((), self.data.dtype))
+        lengths = None if self.lengths is None else jnp.where(
+            validity, self.lengths, 0)
+        return Column(data, validity, lengths, self.dtype)
+
 
 # ---------------------------------------------------------------------------
 # Host-boundary constructors / exporters

@@ -14,6 +14,13 @@ platform gets (``realization.py`` has the table and the measurements):
   pass, where a scatter is cheap: XLA:CPU).
 - ``sort``: pack (mask bit above row index) into ONE u32 word and
   ``lax.sort`` it (XLA:TPU serializes scatters).
+
+A compaction whose index exists only to move rows moves them itself:
+``compact_indices(mask, *payload)`` and ``partition_indices`` return each
+1-D payload array as ``jnp.take(x, idx)`` would, bit for bit.  Under
+``sort`` the arrays are operands 2... of the packed word's own sort (a
+32-bit lane costs 15-18 ms there at 2^24 rows, 0.144-0.45 s through an
+index: PERF.md §6); under ``scatter`` they go through ``jnp.take``.
 """
 from __future__ import annotations
 
@@ -22,7 +29,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-from ..obs import stage
+from ..obs import metrics as obs_metrics, stage
 from . import realization
 
 
@@ -38,29 +45,32 @@ def index_bits(cap: int) -> int:
     return max(1, (cap - 1).bit_length()) if cap > 1 else 1
 
 
-def _mask_sort_perm(mask: jax.Array) -> jax.Array:
+def _mask_sort_perm(mask: jax.Array, payload: Tuple[jax.Array, ...] = ()):
     """Stable partition permutation via ONE single-word unstable sort:
     ``(~mask) << idx_bits | row`` — all words unique, ascending row bits
     make the unstable sort stable per mask value.  Arrays longer than
     2^31 rows can arise internally (e.g. the join expansion's merge of
     csum + out_capacity slots), where flag+index no longer fit u32; those
-    fall back to a two-operand stable sort."""
+    fall back to a two-operand stable sort.  Returns ``(perm, carried)``:
+    ``payload`` rides either sort as further operands, never compared."""
     cap = mask.shape[0]
     bits = index_bits(cap)
+    obs_metrics.counter_add("compact.payload_lanes", sum(
+        -(-x.dtype.itemsize // 4) for x in payload))
     if bits + 1 > 32:
         # >=2^31 rows: int32 positions would wrap negative — exactly the
         # case this branch exists for — so carry the permutation in int64
         # (x64 is enabled package-wide; round-4 advice finding 1)
         iota = jnp.arange(cap, dtype=jnp.int64)
-        _, perm = jax.lax.sort(
-            (jnp.where(mask, jnp.uint32(0), jnp.uint32(1)), iota),
+        _, perm, *carried = jax.lax.sort(
+            (jnp.where(mask, jnp.uint32(0), jnp.uint32(1)), iota) + payload,
             num_keys=1, is_stable=True)
-        return perm
+        return perm, carried
     iota = jnp.arange(cap, dtype=jnp.uint32)
     word = (jnp.where(mask, jnp.uint32(0), jnp.uint32(1))
             << jnp.uint32(bits)) | iota
-    s = jax.lax.sort(word, is_stable=False)
-    return (s & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+    s, *carried = jax.lax.sort((word,) + payload, num_keys=1, is_stable=False)
+    return (s & jnp.uint32((1 << bits) - 1)).astype(jnp.int32), carried
 
 
 def _idx_dtype(cap: int):
@@ -72,41 +82,46 @@ def _idx_dtype(cap: int):
 
 
 @stage("compact.partition")
-def compact_indices(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """(idx, new_count): the first ``new_count`` entries of ``idx`` are the
-    row indices where ``mask`` is True, in order; entries past new_count
-    are in-bounds filler that callers must mask.  new_count is a scalar
-    (int32 below 2^31 rows, int64 past it)."""
+def compact_indices(mask: jax.Array, *payload: jax.Array):
+    """(idx, new_count, *carried): the first ``new_count`` entries of
+    ``idx`` are the row indices where ``mask`` is True, in order; entries
+    past new_count are in-bounds filler that callers must mask.  new_count
+    is a scalar (int32 below 2^31 rows, int64 past it).  ``carried`` is
+    each 1-D ``payload`` array as ``jnp.take(x, idx)`` would return it,
+    filler and all (the module docstring says how)."""
     cap = mask.shape[0]
     it = _idx_dtype(cap)
     new_count = jnp.sum(mask, dtype=it)
     if permute_mode() == "sort":
-        return _mask_sort_perm(mask), new_count
+        idx, carried = _mask_sort_perm(mask, payload)
+        return (idx, new_count, *carried)
     iota = jnp.arange(cap, dtype=it)
     pos = jnp.cumsum(mask, dtype=it) - 1
     idx = jnp.zeros((cap,), it).at[
         jnp.where(mask, pos, cap)].set(iota, mode="drop")
-    return idx, new_count
+    return (idx, new_count, *(jnp.take(x, idx) for x in payload))
 
 
 @stage("compact.partition")
-def partition_indices(mask: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """(perm, true_count): a full stable partition permutation — mask-True
-    row indices first (in order), then every mask-False index (in order).
-    Unlike ``compact_indices`` the tail is the real False rows, so ``perm``
-    is a permutation of [0, n) usable wherever each row must appear exactly
-    once (e.g. reordering a table without dropping rows)."""
+def partition_indices(mask: jax.Array, *payload: jax.Array):
+    """(perm, true_count, *carried): a full stable partition permutation —
+    mask-True row indices first (in order), then every mask-False index (in
+    order).  Unlike ``compact_indices`` the tail is the real False rows, so
+    ``perm`` is a permutation of [0, n) usable wherever each row must appear
+    exactly once (e.g. reordering a table without dropping rows).
+    ``carried`` is each ``payload`` array as ``jnp.take(x, perm)``."""
     cap = mask.shape[0]
     it = _idx_dtype(cap)
     nt = jnp.sum(mask, dtype=it)
     if permute_mode() == "sort":
-        return _mask_sort_perm(mask), nt
+        perm, carried = _mask_sort_perm(mask, payload)
+        return (perm, nt, *carried)
     iota = jnp.arange(cap, dtype=it)
     ct = jnp.cumsum(mask, dtype=it)
     cf = iota + 1 - ct  # cumsum of ~mask without a second scan
     dest = jnp.where(mask, ct - 1, nt + cf - 1)
     perm = jnp.zeros((cap,), it).at[dest].set(iota)
-    return perm, nt
+    return (perm, nt, *(jnp.take(x, perm) for x in payload))
 
 
 def count_leq_dense(sorted_vals: jax.Array, num_queries: int) -> jax.Array:

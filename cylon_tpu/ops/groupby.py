@@ -221,11 +221,12 @@ def hash_groupby(cols: Tuple[Column, ...], count,
     """
     cap = cols[0].data.shape[0]
     key_cols = [cols[i] for i in key_idx]
-    # the value columns ride the sort into group order (keys.pack_payload)
+    # the value and the key columns ride the sort into group order
+    # (keys.pack_payload)
     values = {i: cols[i] for i, _ in aggs}
     with stage("groupby.gather"):
         buffers, columns = jax.tree.flatten(values)
-        lanes, layout = keys.pack_payload(buffers)
+        lanes, layout = keys.pack_payload(buffers + jax.tree.leaves(key_cols))
     with stage("groupby.sort"):
         operands = keys.build_operands(key_cols, count, cap)
         perm, sorted_ops, lanes = keys.lexsort_indices(operands, cap, lanes)
@@ -233,33 +234,55 @@ def hash_groupby(cols: Tuple[Column, ...], count,
         values = jax.tree.unflatten(columns, [
             jnp.take(buffer, perm, axis=0, mode="clip") if rode is None
             else rode
-            for buffer, rode in zip(buffers,
-                                    keys.unpack_payload(lanes, layout))])
+            for buffer, rode in zip(buffers, keys.unpack_payload(
+                lanes, layout[:len(buffers)]))])
     with stage("groupby.boundaries"):
         new_group = ~keys.rows_equal_adjacent(sorted_ops)
-        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-        start, end = segments.segment_spans(new_group)
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        # padding sorted last -> first `count` sorted rows live
-        live = iota < count
-        num_groups = jnp.where(
-            count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
-
-        # group leader positions (first sorted row of each group)
-        leader = jnp.clip(start, 0, cap - 1)
-        group_live = iota[:cap] < num_groups
-
-    out_cols = []
-    with stage("groupby.keys"):
-        leader_src = jnp.take(perm, leader)  # compose index gathers: one
-        for kc in key_cols:                  # column gather instead of two
-            out_cols.append(kc.take(leader_src, valid_mask=group_live))
+    out_cols, gid, start, end, live, num_groups, group_live = _groups(
+        new_group, count, key_cols, lanes, layout[len(buffers):], perm)
 
     for col_idx, op in aggs:
         out_cols.append(_aggregate(op, values[col_idx], live, gid, cap, ddof,
                                    start, end, new_group, group_live,
                                    cols[col_idx].dtype))
     return tuple(out_cols), num_groups
+
+
+def _groups(new_group, count, key_cols, lanes, layout, perm=None):
+    """The groups of rows that lie in group order, from their run-start
+    mask: ``(key columns, gid, start, end, live, num_groups, group_live)``.
+
+    The key columns' buffers ride the compaction that finds the group
+    starts (``segments.segment_spans``) to each group's first row:
+    ``layout`` is ``keys.pack_payload``'s of ``jax.tree.leaves(key_cols)``
+    and ``lanes`` its lanes in group order.  A buffer the layout marks
+    ``None`` (a string's byte matrix, lanes past the budget) is taken
+    through the source rows of the group starts: ``perm``, the rows' order,
+    carried by the same compaction, or the starts themselves where the
+    input lies in group order.  Each column reads as
+    ``Column.take(..., valid_mask=group_live)`` does, bit for bit."""
+    cap = new_group.shape[0]
+    buffers, columns = jax.tree.flatten(key_cols)
+    used = sorted({where[0] for where in layout if where is not None})
+    src = [perm] if perm is not None and None in layout else []
+    with stage("groupby.boundaries"):
+        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+        start, end, *carried = segments.segment_spans(
+            new_group, *(lanes[lane] for lane in used), *src)
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        # padding sorted last -> first `count` sorted rows live
+        live = iota < count
+        num_groups = jnp.where(
+            count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
+        group_live = iota < num_groups
+    with stage("groupby.keys"):
+        leader_src = carried.pop() if src else jnp.clip(start, 0, cap - 1)
+        rode = keys.unpack_payload(dict(zip(used, carried)), layout)
+        key_cols = jax.tree.unflatten(columns, [
+            jnp.take(buffer, leader_src, axis=0, mode="clip") if r is None
+            else r for buffer, r in zip(buffers, rode)])
+        return ([kc.masked(group_live) for kc in key_cols], gid, start, end,
+                live, num_groups, group_live)
 
 
 def _aggregate(op: AggOp, vcol: Column, live, gid, cap: int, ddof: int,
@@ -323,19 +346,10 @@ def pipeline_groupby(cols: Tuple[Column, ...], count,
         for kc in key_cols:
             operands.extend(keys.column_operands(kc))
         new_group = ~keys.rows_equal_adjacent(keys.pack_operands(operands))
-        gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-        start, end = segments.segment_spans(new_group)
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        live = iota < count
-        num_groups = jnp.where(
-            count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
-        leader = jnp.clip(start, 0, cap - 1)
-        group_live = iota < num_groups
+        lanes, layout = keys.pack_payload(jax.tree.leaves(key_cols))
+    out_cols, gid, start, end, live, num_groups, group_live = _groups(
+        new_group, count, key_cols, lanes, layout)
 
-    out_cols = []
-    with stage("groupby.keys"):
-        for kc in key_cols:
-            out_cols.append(kc.take(leader, valid_mask=group_live))
     for col_idx, op in aggs:
         out_cols.append(_aggregate(op, cols[col_idx], live, gid, cap, ddof,
                                    start, end, new_group, group_live,

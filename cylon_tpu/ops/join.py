@@ -11,8 +11,10 @@ build/probe; join_utils.cpp build_final_table).  Design:
    data-dependent control flow.
 2. Per-left-row match ranges [lo, lo+matches) into the key-ordered right
    side are prefix arithmetic over the sorted order (cumsum + segmented
-   broadcasts); the key-ordered right permutation is a compaction of the
-   combined sort's right entries — the merge step without a second sort.
+   broadcasts); the key-ordered right permutation is the combined sort's
+   permutation carried by a partition of its right entries (one one-word
+   sort it rides as payload on a TPU, ``compact.partition_indices``) —
+   the merge step without a second key sort and without an index.
 3. The variable-size expansion (a left row with k matches emits k rows;
    outer variants emit null-filled singletons, the reference's -1 fills,
    join.cpp:179-235) is realized as a static-capacity gather: each emitting
@@ -68,7 +70,8 @@ def _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
       broadcast (cummax of run-start values / suffix-cummin of run-end
       values), replacing per-gid histogram scatter-adds;
     - the gid-ordered right permutation falls out of the combined sort by
-      compacting its right-side entries (cumsum-scatter) — no second sort;
+      partitioning its right-side entries to the front, the permutation
+      riding along (``compact.partition_indices``) — no second key sort;
     - per-original-row results come back through one scatter along the sort
       permutation.
 
@@ -106,16 +109,15 @@ def _match_ranges(cols_l, count_l, cols_r, count_r, left_on, right_on,
         unmatched_r = jnp.zeros((cap_r,), bool)
 
     # gid-ordered right permutation AND left key order from ONE stable
-    # partition of the combined sort's entries: exactly cap_r of them are
-    # right-side (perm is a full permutation), so the front cap_r slots
-    # are the right rows in key order (the order ``lo`` indexes into) and
-    # the tail cap_l slots are the left rows in key order (key_grouped
-    # output) — half the compaction cost, which in sort mode is a full
-    # combined-length sort per call
-    part, _ = compact.partition_indices(is_right)
-    perm_r = jnp.take(perm, part[:cap_r]) - cap_l
-    left_key_order = jnp.take(perm, part[cap_r:])
-    return lo, matches, perm_r, live_l, unmatched_r, left_key_order
+    # partition of the combined sort's entries, which ``perm`` rides
+    # (compact.py: no index is built to move it): exactly cap_r of them
+    # are right-side (perm is a full permutation), so the front cap_r
+    # slots are the right rows in key order (the order ``lo`` indexes
+    # into) and the tail cap_l slots are the left rows in key order
+    # (key_grouped output)
+    _, _, by_side = compact.partition_indices(is_right, perm)
+    return (lo, matches, by_side[:cap_r] - cap_l, live_l, unmatched_r,
+            by_side[cap_r:])
 
 
 @stage("join.emit")

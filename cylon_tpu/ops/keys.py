@@ -247,7 +247,8 @@ def pack_payload(buffers: Sequence[jax.Array]):
 
 def unpack_payload(sorted_lanes: Sequence[jax.Array], layout) -> list:
     """The buffers ``pack_payload`` packed, in sorted order; ``None`` where
-    the layout holds ``None``."""
+    the layout holds ``None``.  ``sorted_lanes`` is indexed by lane: a
+    mapping will do where only some lanes were moved."""
     out = []
     for where in layout:
         if where is None:

@@ -33,20 +33,23 @@ from .. import precision
 from . import compact, realization
 
 
-def segment_spans(new_group: jax.Array) -> Tuple[jax.Array, jax.Array]:
+def segment_spans(new_group: jax.Array, *payload: jax.Array):
     """Per-segment [start, end) positions from a group-boundary mask.
 
     ``new_group[i]`` is True where sorted row i starts a new segment
     (position 0 must be True for any nonempty input).  Returns
-    (start[cap], end[cap]) where segment g spans rows [start[g], end[g]);
-    ids >= the number of segments get empty spans at cap.
+    (start[cap], end[cap], *carried) where segment g spans rows [start[g],
+    end[g]); ids >= the number of segments get empty spans at cap.
+    ``carried`` is each 1-D ``payload`` array's row at every segment's
+    start, moved by the compaction itself (``compact.compact_indices``);
+    past the number of segments it holds filler.
     """
     cap = new_group.shape[0]
-    starts_perm, num = compact.compact_indices(new_group)
+    starts_perm, num, *carried = compact.compact_indices(new_group, *payload)
     iota = jnp.arange(cap, dtype=jnp.int32)
     start = jnp.where(iota < num, starts_perm, cap)
     end = jnp.concatenate([start[1:], jnp.full((1,), cap, jnp.int32)])
-    return start, end
+    return (start, end, *carried)
 
 
 def run_extents(member: jax.Array, new_group: jax.Array,

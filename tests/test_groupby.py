@@ -192,3 +192,139 @@ def test_float_zero_and_nan_key_semantics(local_ctx):
 
     ucols, m = umod.unique((kcol,), jnp.asarray(6, jnp.int32), (0,), "first")
     assert int(m) == 3
+
+
+# -- the key columns ride the sort and the compaction of the group starts ----
+
+def _parent_keys(cols, count, key_idx, lexsort):
+    """PR 31's key columns, the plain reference: the group leaders'
+    positions out of the compaction's index, through the sort's
+    permutation, then ``Column.take`` a key column."""
+    import jax.numpy as jnp
+    from cylon_tpu.ops import keys, segments
+
+    cap = cols[0].data.shape[0]
+    key_cols = [cols[i] for i in key_idx]
+    if lexsort:
+        perm, sorted_ops, _ = keys.lexsort_indices(
+            keys.build_operands(key_cols, count, cap), cap)
+    else:
+        operands = [keys.padding_operand(cap, count)]
+        for kc in key_cols:
+            operands.extend(keys.column_operands(kc))
+        perm = jnp.arange(cap, dtype=jnp.int32)
+        sorted_ops = keys.pack_operands(operands)
+    new_group = ~keys.rows_equal_adjacent(sorted_ops)
+    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
+    start, _ = segments.segment_spans(new_group)
+    num_groups = jnp.where(
+        count > 0, jnp.take(gid, jnp.clip(count - 1, 0, cap - 1)) + 1, 0)
+    leader_src = jnp.take(perm, jnp.clip(start, 0, cap - 1))
+    group_live = jnp.arange(cap, dtype=jnp.int32) < num_groups
+    return [kc.take(leader_src, valid_mask=group_live)
+            for kc in key_cols], num_groups
+
+
+def _key_case(case, rng):
+    """(columns, count, key_idx, value_idx) of one case."""
+    from cylon_tpu import column as colmod
+
+    def ints(n, cap, hi, dtype=np.int64, nulls=0.0):
+        validity = rng.random(n) > nulls if nulls else None
+        return colmod.from_numpy(rng.integers(0, hi, n).astype(dtype),
+                                 validity=validity, capacity=cap)
+
+    def floats(n, cap, nulls=0.2):
+        return colmod.from_numpy(rng.random(n), validity=rng.random(n) > nulls,
+                                 capacity=cap)
+
+    if case == "nulls_in_keys":
+        n, cap = 230, 256
+        return (ints(n, cap, 40, nulls=0.15), floats(n, cap)), n, (0,), (1,)
+    if case == "string_beside_int":
+        n, cap = 200, 256
+        words = np.array([f"w{x}" * (1 + x % 3)
+                          for x in rng.integers(0, 6, n)], dtype=object)
+        words[rng.random(n) < 0.1] = None
+        return (ints(n, cap, 5, np.int32, nulls=0.1),
+                colmod.from_numpy(words, capacity=cap),
+                floats(n, cap)), n, (0, 1), (2,)
+    if case == "float_keys":    # -0.0 groups with 0.0: the leader's bits stay
+        n, cap = 100, 128
+        k = rng.choice(np.array([0.0, -0.0, 1.5, np.nan, -np.inf]), n)
+        return (colmod.from_numpy(k, validity=np.ones(n, bool), capacity=cap),
+                floats(n, cap)), n, (0,), (1,)
+    if case == "33_columns":    # two validity words, lanes past the budget
+        n, cap = 120, 128
+        cols = tuple(ints(n, cap, 2, np.int32, nulls=0.1 if i % 3 else 0.0)
+                     for i in range(16)) + (ints(n, cap, 3),) + tuple(
+                         ints(n, cap, 9, np.int32, nulls=0.2)
+                         for _ in range(16))
+        return cols, n, tuple(range(17)), tuple(range(17, 33))
+    n, cap = {"count_0": (0, 64), "count_is_capacity": (128, 128)}[case]
+    return (ints(n, cap, 30), floats(n, cap, 0.0)), n, (0,), (1,)
+
+
+KEY_CASES = ["nulls_in_keys", "string_beside_int", "float_keys", "33_columns",
+             "count_0", "count_is_capacity"]
+
+
+@pytest.mark.parametrize("mode", ["scatter", "sort"])
+@pytest.mark.parametrize("kernel", ["hash_groupby", "pipeline_groupby"])
+@pytest.mark.parametrize("case", KEY_CASES)
+def test_key_columns_equal_the_parents(realize, case, kernel, mode):
+    """Every buffer of the key columns, bit for bit, in both rows of
+    ``ops/realization.py``; the aggregates against the same kernel over
+    PR 31's compactions.  ``pipeline_groupby`` takes the rows as they lie:
+    a run of equal keys is a group, sorted input or not."""
+    import jax.numpy as jnp
+    from cylon_tpu.ops import groupby as gmod, realization
+    from tests.test_join_lanes import _assert_same_buffers
+    from tests.test_permute_modes import index_then_take
+
+    cols, n, key_idx, value_idx = _key_case(case, np.random.default_rng(31))
+    count = jnp.asarray(n, jnp.int32)
+    aggs = tuple((i, gmod.AggOp.SUM) for i in value_idx)
+    with realize(realization.current()._replace(permute=mode)):
+        got, groups = getattr(gmod, kernel)(cols, count, key_idx, aggs)
+        want, want_groups = _parent_keys(cols, count, key_idx,
+                                         kernel == "hash_groupby")
+        _assert_same_buffers(got[:len(key_idx)], groups, want, want_groups)
+        with index_then_take():
+            plain, plain_groups = getattr(gmod, kernel)(cols, count, key_idx,
+                                                        aggs)
+        _assert_same_buffers(got, groups, plain, plain_groups)
+    if case == "count_0":
+        assert int(groups) == 0
+    if case == "count_is_capacity" and kernel == "hash_groupby":
+        assert int(groups) == len(np.unique(np.asarray(cols[0].data)))
+
+
+@pytest.mark.parametrize("kernel", ["hash_groupby", "pipeline_groupby"])
+@pytest.mark.parametrize("case,gathers", [
+    ("nulls_in_keys", 0), ("float_keys", 0), ("string_beside_int", 1)])
+def test_a_key_that_can_ride_goes_through_no_index(realize, case, gathers,
+                                                   kernel):
+    """The TPU's row: no gather under ``groupby.keys`` but a string key's
+    byte matrix, and no ``pred`` vector through any index."""
+    import jax
+    import jax.numpy as jnp
+    from cylon_tpu.obs import metrics
+    from cylon_tpu.ops import groupby as gmod, realization
+    from tests.test_join_lanes import _gathers
+
+    cols, n, key_idx, value_idx = _key_case(case, np.random.default_rng(31))
+    aggs = tuple((i, gmod.AggOp.SUM) for i in value_idx)
+    with realize(realization.current()._replace(permute="sort")):
+        before = metrics.counter_value("compact.payload_lanes")
+        jaxpr = jax.make_jaxpr(lambda c: getattr(gmod, kernel)(
+            c, jnp.asarray(n, jnp.int32), key_idx, aggs))(cols)
+        rode = metrics.counter_value("compact.payload_lanes") - before
+    found = list(_gathers(jaxpr.jaxpr))
+    assert [stage for stage, _ in found].count("groupby.keys") == gathers
+    assert not any(dtype == jnp.bool_ for _, dtype in found)
+    # a validity word, the key's data; the string's lengths and the order
+    # its byte matrix is taken through (the group starts where the rows
+    # lie in group order already)
+    assert rode == {"nulls_in_keys": 3, "float_keys": 3,
+                    "string_beside_int": 3 + (kernel == "hash_groupby")}[case]

@@ -20,8 +20,10 @@ from cylon_tpu import Table
 from cylon_tpu import column as colmod
 from cylon_tpu.config import JoinType
 from cylon_tpu.obs import STAGES, metrics
+from cylon_tpu.ops import common, compact
 from cylon_tpu.ops import join as jmod
 from cylon_tpu.ops import realization
+from tests.test_permute_modes import index_then_take
 
 MODES = ("scatter", "sort")
 HOW = {JoinType.INNER: "inner", JoinType.LEFT: "left",
@@ -200,6 +202,63 @@ def test_join_on_the_mesh_equals_pandas(ctx4, how):
     assert (got["s"].fillna("") == want["s"].fillna("")).all()
 
 
+RANGES_CASES = {
+    # nl, nr, cap, keys
+    "nulls_in_keys": (230, 190, 256, 60),
+    "count_0": (0, 0, 64, 5),
+    "count_is_capacity": (128, 128, 128, 40),
+}
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("jt", list(HOW), ids=lambda jt: jt.name)
+@pytest.mark.parametrize("case", list(RANGES_CASES))
+def test_right_order_and_left_key_order_equal_the_parents(
+        realize, local_ctx, case, jt, mode):
+    """``perm_r`` and ``left_key_order`` ride the partition of the combined
+    sort's entries; PR 31 took them out of the sort's permutation through
+    the partition's index, and that stays here as the plain reference."""
+    nl, nr, cap, keys = RANGES_CASES[case]
+    with realize(realization.current()._replace(permute=mode)):
+        left, right = _tables(local_ctx, 13, nl, nr, cap, keys)
+        sides = (left.columns, left.row_counts[0], right.columns,
+                 right.row_counts[0], (0,), (0,))
+        _, _, perm_r, _, _, left_key_order = jmod._match_ranges(*sides, jt)
+        perm = common.combined_sorted_runs(*sides)[0]
+        part, _ = compact.partition_indices(perm >= cap)
+        want_r = jnp.take(perm, part[:cap]) - cap
+        want_l = jnp.take(perm, part[cap:])
+        for got, want in ((perm_r, want_r), (left_key_order, want_l)):
+            assert got.dtype == want.dtype == jnp.int32
+            np.testing.assert_array_equal(got, want)
+        assert sorted(np.asarray(perm_r)) == list(range(cap))
+        assert sorted(np.asarray(left_key_order)) == list(range(cap))
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("jt,key_grouped", [
+    (jt, False) for jt in HOW] + [(JoinType.INNER, True)],
+    ids=lambda v: getattr(v, "name", f"key_grouped_{v}"))
+def test_join_equals_the_join_with_index_then_take(realize, local_ctx, jt,
+                                                   key_grouped, mode):
+    """Every output buffer of ``join_gather`` and ``join_indices`` against
+    the same kernels over PR 31's compactions."""
+    with realize(realization.current()._replace(permute=mode)):
+        left, right = _tables(local_ctx, 29, 210, 240, 256, 50)
+        args = (left.columns, left.row_counts[0], right.columns,
+                right.row_counts[0], (0,), (0,), jt, 2048, "sort",
+                key_grouped)
+        got, got_count = jmod.join_gather(*args)
+        got_indices = jmod.join_indices(*args)
+        with index_then_take():
+            want, want_count = jmod.join_gather(*args)
+            want_indices = jmod.join_indices(*args)
+        _assert_same_buffers(got, got_count, want, want_count)
+        for a, b in zip(got_indices, want_indices):
+            assert a.dtype == b.dtype
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 def _gathers(jaxpr, scope=()):
     """(stage, operand dtype) of every gather in ``jaxpr`` and the jaxprs it
     calls; the stage is the innermost of ``obs.STAGES`` round it."""
@@ -232,6 +291,9 @@ def test_two_lanes_through_the_expansion(realize, jt):
         found = list(_gathers(jaxpr.jaxpr))
     assert [stage for stage, _ in found].count("join.expand") == 2
     assert not any(dtype == jnp.bool_ for _, dtype in found)
+    # the key-ordered right rows and the left key order ride the partition
+    # (the parent took both out of the permutation: two gathers there)
+    assert "join.ranges" not in [stage for stage, _ in found]
 
 
 @pytest.mark.parametrize("algo", ["sort", "hash"])

@@ -10,6 +10,8 @@ consumer (reference behavior being preserved: join.cpp:179-235 output
 building, table.cpp:966-1029 unique filter, arrow_kernels.hpp:60-96
 splitters).
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -326,3 +328,171 @@ def test_join_projection_pushdown(realize, jt):
 
     a, b = _per_mode(realize, run)
     assert a == b
+
+
+def _parent_mask_sort_perm(mask):
+    """PR 31's ``compact._mask_sort_perm`` below 2^31 rows."""
+    cap = mask.shape[0]
+    bits = compact.index_bits(cap)
+    iota = jnp.arange(cap, dtype=jnp.uint32)
+    word = (jnp.where(mask, jnp.uint32(0), jnp.uint32(1))
+            << jnp.uint32(bits)) | iota
+    s = jax.lax.sort(word, is_stable=False)
+    return (s & jnp.uint32((1 << bits) - 1)).astype(jnp.int32)
+
+
+def _parent_compact_indices(mask):
+    """PR 31's ``compact_indices``: the index alone, the plain reference of
+    the carrying form (``jnp.take(x, idx)`` is the rest of it)."""
+    cap = mask.shape[0]
+    new_count = jnp.sum(mask, dtype=jnp.int32)
+    if compact.permute_mode() == "sort":
+        return _parent_mask_sort_perm(mask), new_count
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.cumsum(mask, dtype=jnp.int32) - 1
+    idx = jnp.zeros((cap,), jnp.int32).at[
+        jnp.where(mask, pos, cap)].set(iota, mode="drop")
+    return idx, new_count
+
+
+def _parent_partition_indices(mask):
+    """PR 31's ``partition_indices``."""
+    cap = mask.shape[0]
+    nt = jnp.sum(mask, dtype=jnp.int32)
+    if compact.permute_mode() == "sort":
+        return _parent_mask_sort_perm(mask), nt
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    ct = jnp.cumsum(mask, dtype=jnp.int32)
+    cf = iota + 1 - ct
+    dest = jnp.where(mask, ct - 1, nt + cf - 1)
+    perm = jnp.zeros((cap,), jnp.int32).at[dest].set(iota)
+    return perm, nt
+
+
+PARENT = {"compact_indices": _parent_compact_indices,
+          "partition_indices": _parent_partition_indices}
+
+
+@contextlib.contextmanager
+def index_then_take():
+    """Run the body with the compactions as PR 31 had them: an index, and
+    ``jnp.take`` through it for whatever has to move.  The whole-kernel
+    reference of the join's and the group-by's carrying compactions."""
+    from unittest import mock
+
+    def plain(name):
+        def fn(mask, *payload):
+            idx, count = PARENT[name](mask)
+            return (idx, count, *(jnp.take(x, idx) for x in payload))
+        return fn
+
+    jax.clear_caches()
+    try:
+        with mock.patch.object(compact, "compact_indices",
+                               plain("compact_indices")), \
+                mock.patch.object(compact, "partition_indices",
+                                  plain("partition_indices")):
+            yield
+    finally:
+        jax.clear_caches()
+
+
+def _odd_floats(rng, cap):
+    """float32 rows whose bits a value comparison would lose: NaNs with
+    payload bits, -0.0 beside 0.0, infinities, denormals."""
+    bits = rng.integers(0, 1 << 32, cap, dtype=np.uint64).astype(np.uint32)
+    odd = np.array([0x7FC00001, 0xFFC12345, 0x7F800001, 0x80000000, 0,
+                    0x7F800000, 0xFF800000, 1], np.uint32)
+    return np.where(rng.random(cap) < 0.5, odd[rng.integers(0, 8, cap)],
+                    bits).view(np.float32)
+
+
+def _carried_arrays(rng, cap, kind):
+    def words():
+        return keys.pack_bits([jnp.asarray(rng.random(cap) < 0.5)
+                               for _ in range(7)])[0]
+
+    make = {
+        "bits": words,
+        "int32": lambda: rng.integers(-2 ** 31, 2 ** 31, cap).astype(np.int32),
+        "uint32": lambda: rng.integers(0, 2 ** 32, cap).astype(np.uint32),
+        "float32": lambda: _odd_floats(rng, cap),
+    }
+    kinds = [kind, kind] if kind in make else [
+        "bits", "int32", "uint32", "float32", "float32"]
+    return [jnp.asarray(make[k]()) for k in kinds]
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("fill", ["mixed", "all_true", "all_false"])
+@pytest.mark.parametrize("cap", [1, 8, 1000])
+@pytest.mark.parametrize("kind", ["bits", "int32", "uint32", "float32",
+                                  "five_arrays"])
+def test_carrying_form_equals_take(realize, kind, cap, fill, mode):
+    """``fn(mask, *payload)``: the index and the count of ``fn(mask)``, and
+    each array as ``jnp.take(x, idx)`` returns it, bit for bit, filler
+    and all, under both rows of ``ops/realization.py``."""
+    rng = np.random.default_rng(cap)
+    mask = jnp.asarray({"mixed": rng.random(cap) < 0.4,
+                        "all_true": np.ones(cap, bool),
+                        "all_false": np.zeros(cap, bool)}[fill])
+    payload = _carried_arrays(rng, cap, kind)
+    with realize(realization.current()._replace(permute=mode)):
+        for fn in (compact.compact_indices, compact.partition_indices):
+            want_idx, want_count = PARENT[fn.__name__](mask)
+            idx, count, *carried = fn(mask, *payload)
+            np.testing.assert_array_equal(idx, want_idx)
+            assert int(count) == int(want_count) == int(mask.sum())
+            assert len(carried) == len(payload)
+            for x, moved in zip(payload, carried):
+                want = np.asarray(jnp.take(x, want_idx))
+                assert moved.dtype == x.dtype and moved.shape == x.shape
+                assert np.asarray(moved).tobytes() == want.tobytes()
+        if mode == "sort":
+            jaxpr = str(jax.make_jaxpr(lambda m, *p: compact.compact_indices(
+                m, *p))(mask, *payload))
+            assert " gather[" not in jaxpr and jaxpr.count(" sort[") == 1
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("name", list(PARENT))
+def test_without_payload_the_jaxpr_is_the_parents(realize, name, mode):
+    """Every other caller (``unique``, the set operations, the filters, the
+    exchange's partitions) traces what it traced before."""
+    mask = jnp.arange(1000) % 3 == 0
+    with realize(realization.current()._replace(permute=mode)):
+        got = jax.make_jaxpr(lambda m: getattr(compact, name)(m))(mask)
+        want = jax.make_jaxpr(lambda m: PARENT[name](m))(mask)
+        assert str(got) == str(want)
+        assert len(got.out_avals) == 2
+
+
+def test_two_words_past_2_31_rows_carry_too():
+    """The stable two-operand sort of a mask of more than 2^31 rows carries
+    the payload beside the int64 index (traced, never run)."""
+    cap = (1 << 31) + 8
+    jaxpr = jax.make_jaxpr(lambda m, x: compact._mask_sort_perm(m, (x,)))(
+        jax.ShapeDtypeStruct((cap,), jnp.bool_),
+        jax.ShapeDtypeStruct((cap,), jnp.float32))
+    sort, = [e for e in jaxpr.eqns if e.primitive.name == "sort"]
+    assert sort.params["num_keys"] == 1 and sort.params["is_stable"]
+    assert [v.aval.dtype for v in sort.invars] == [
+        jnp.uint32, jnp.int64, jnp.float32]
+    assert [a.dtype for a in jaxpr.out_avals] == [jnp.int64, jnp.float32]
+
+
+def test_counter_says_how_many_lanes_rode_a_compaction(realize):
+    from cylon_tpu.obs import metrics
+
+    mask = jnp.arange(64) % 3 == 0
+    payload = (jnp.arange(64, dtype=jnp.int32),
+               jnp.arange(64, dtype=jnp.float64),
+               jnp.arange(64, dtype=jnp.uint32))
+    with realize(realization.current()._replace(permute="sort")):
+        before = metrics.counter_value("compact.payload_lanes")
+        jax.make_jaxpr(lambda m, *p: compact.compact_indices(m, *p))(
+            mask, *payload)
+        assert metrics.counter_value("compact.payload_lanes") - before == 4
+        jax.make_jaxpr(lambda m: compact.partition_indices(m))(mask)
+        assert metrics.counter_value("compact.payload_lanes") - before == 4
+    assert "compact.payload_lanes" in metrics.snapshot()["counters"]

@@ -144,8 +144,12 @@ def test_entry_step_compiles(one_chip, as_on_chip, log2_rows):
     stages = _gather_stages(compiled)
     assert 0 < stages["join.expand"] + stages["gather"] <= 2
     assert stages["join.gather_left"] == 3     # key, value, validity word
-    assert not [line for line in _validity_gathers(compiled)
-                if "jit(join_gather)" in line]
+    # the key-ordered right rows ride the partition's sort (PR 31 took them
+    # out of the combined permutation, from HBM), and the group-by's key
+    # with its validity the compaction of the group starts: no ``pred``
+    # vector goes through an index in the whole step
+    assert stages["join.ranges"] == stages["groupby.keys"] == 0
+    assert not _validity_gathers(compiled)
 
 
 def _gather_stages(compiled) -> collections.Counter:
@@ -172,8 +176,9 @@ def _validity_gathers(compiled) -> list:
     pytest.param("hash_groupby_int64", marks=pytest.mark.slow)])
 def test_local_kernels_compile(one_chip, as_on_chip, program):
     """The rows ride the sorts: ``sort_rows`` compiles with no gather at
-    all, ``hash_groupby`` with none but the compactions through the group
-    leaders and the segment ends.  The ``int64`` cases are the benchmark
+    all, ``hash_groupby`` with none but the reductions' through the
+    segment ends (its keys ride the sort and the compaction of the group
+    starts).  The ``int64`` cases are the benchmark
     cell's shapes (int64 key, float64 or float32 values): minutes each,
     for the table of forms in PERF.md, by hand."""
     from cylon_tpu.ops import groupby as groupby_mod
@@ -203,7 +208,7 @@ def test_local_kernels_compile(one_chip, as_on_chip, program):
                 (1, op) for op in (groupby_mod.AggOp.SUM,
                                    groupby_mod.AggOp.MEAN,
                                    groupby_mod.AggOp.COUNT)))
-        gathers = {"groupby.keys", "groupby.reduce"}
+        gathers = {"groupby.reduce"}
     elif program == "unique":
         lowered = unique_mod.unique.lower(kv, count, (0,), "first")
     else:
