@@ -1406,7 +1406,11 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
             tuple(c.dtype for c in left.columns),
             tuple(c.dtype for c in right.columns))
 
-    def gather_at(out_cap: int) -> Table:
+    def gather_at(out_cap: int, counts=None):
+        """(the gather at ``out_cap`` slots a shard, the fullest shard's
+        rows).  ``counts``: the join's rows a shard where the count pass
+        has brought them to the host, else read off the result, which is
+        the check of a remembered capacity."""
         def gather_fn(a: Table, b: Table) -> Table:
             cols, m = join_mod.join_gather(
                 a.columns, a.row_counts[0], b.columns, b.row_counts[0],
@@ -1416,15 +1420,26 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
         # 32-bit lanes through the join's slot -> row indices (take_side)
         obs_metrics.counter_add("join.take_lanes", sum(
             join_mod.take_lanes(t.columns) for t in (left, right)))
-        with obs_span("join.gather"):
-            return _shard_wise(ctx, gather_fn, left, right,
-                               key=("join", cfg.left_on, cfg.right_on, jt,
-                                    out_cap, algo))
+        slots = out_cap * left.num_shards
+        with obs_span("join.gather", slots=slots) as sp:
+            out = _shard_wise(ctx, gather_fn, left, right,
+                              key=("join", cfg.left_on, cfg.right_on, jt,
+                                   out_cap, algo))
+            if counts is None:
+                counts = _host_row_counts(out, "join.capacity")
+            rows = int(np.sum(counts))
+            sp.set(rows=rows)
+        hi = int(np.max(counts))
+        if hi <= out_cap:
+            # a join that ran, and how full its slots are: the fullest
+            # shard sizes them for all, and every later buffer with them
+            obs_metrics.counter_add("join.out_rows", rows)
+            obs_metrics.counter_add("join.out_slots", slots)
+        return out, hi
 
     cached = cap_cache.get(site)
     if cached is not None:
-        out = gather_at(cached)
-        hi = int(np.max(_host_row_counts(out, "join.capacity")))
+        out, hi = gather_at(cached)
         if hi <= cached:
             # shrink with hysteresis: one skewed join must not inflate
             # this site (and everything sized off its result) forever
@@ -1448,10 +1463,10 @@ def _local_join(left: Table, right: Table, cfg: JoinConfig) -> Table:
         counts = _shard_wise(ctx, count_fn, left, right,
                              key=("join_count", cfg.left_on, cfg.right_on, jt,
                                   algo))
-        out_cap = _cap_round(max(1, int(host_sync(jnp.max(counts),
-                                                  "join.count"))))
+        counts = np.asarray(host_sync(counts, "join.count", _get_everywhere))
+        out_cap = _cap_round(max(1, int(np.max(counts))))
     cap_cache[site] = out_cap
-    return gather_at(out_cap)
+    return gather_at(out_cap, counts)[0]
 
 
 def _local_set_op(a: Table, b: Table, op: str) -> Table:

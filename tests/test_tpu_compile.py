@@ -150,6 +150,12 @@ def test_entry_step_compiles(one_chip, as_on_chip, log2_rows):
     # vector goes through an index in the whole step
     assert stages["join.ranges"] == stages["groupby.keys"] == 0
     assert not _validity_gathers(compiled)
+    # what bench/metrics/kernels.gather_ms_per_query.py reads by name: a
+    # bare custom fusion is a gather, one each, and every gather is in one
+    held = _custom_fusion_gathers(compiled)
+    assert held and set(held.values()) == {1}
+    assert all(re.fullmatch(r"%fusion(\.\d+)?", name) for name in held)
+    assert sum(held.values()) == sum(stages.values())
 
 
 def _gather_stages(compiled) -> collections.Counter:
@@ -162,6 +168,27 @@ def _gather_stages(compiled) -> collections.Counter:
             named = [part for part in op_name.split("/") if part in STAGES]
             stages[named[-1] if named else op_name] += 1
     return stages
+
+
+def _custom_fusion_gathers(compiled) -> dict:
+    """{name of each ``kind=kCustom`` fusion: the ``gather`` instructions
+    of the computation it calls}."""
+    lines = compiled.as_text().splitlines()
+    bodies, body = {}, None
+    for line in lines:
+        head = re.match(r"(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        elif body is not None:
+            body.append(line)
+    held = {}
+    for line in lines:
+        if "kind=kCustom" in line:
+            called = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+            held[line.split(" = ")[0].split()[-1]] = sum(
+                1 for inner in bodies[called]
+                if re.search(r"= \S+ gather\(", inner))
+    return held
 
 
 def _validity_gathers(compiled) -> list:
