@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from ..column import Column
-from ..obs import metrics as obs_metrics
+from ..obs import metrics as obs_metrics, stage
 from . import compact
 
 
@@ -258,6 +258,27 @@ def unpack_payload(sorted_lanes: Sequence[jax.Array], layout) -> list:
         out.append(sorted_lanes[lane] if bit is None else
                    unpack_bit(sorted_lanes, 32 * lane + bit))
     return out
+
+
+def compact_columns(mask: jax.Array, cols: Sequence[Column]):
+    """``(columns, count)``: the rows of ``cols`` (each of ``mask``'s
+    capacity) where ``mask`` is True, packed to the front in order, null
+    past the new ``count``.  What ``compact.compact_indices(mask)`` and
+    ``Column.take(idx, valid_mask=live_mask(cap, count))`` of every column
+    give, bit for bit, with no index between them: the buffers ride the
+    compaction as its payload (``pack_payload``), and only a buffer that
+    cannot ride is taken through the index."""
+    buffers, columns = jax.tree.flatten(tuple(cols))
+    with stage("compact.permute"):
+        lanes, layout = pack_payload(buffers)
+    idx, count, *carried = compact.compact_indices(mask, *lanes)
+    with stage("compact.permute"):
+        moved = jax.tree.unflatten(columns, [
+            jnp.take(buffer, idx, axis=0, mode="clip") if rode is None
+            else rode
+            for buffer, rode in zip(buffers, unpack_payload(carried, layout))])
+        live = compact.live_mask(mask.shape[0], count)
+        return tuple(c.masked(live) for c in moved), count
 
 
 def lexsort_indices(operands: Sequence[jax.Array], capacity: int,

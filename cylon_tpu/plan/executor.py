@@ -294,6 +294,7 @@ class _Executor:
         import jax.numpy as jnp
 
         from ..ops import compact as compact_mod
+        from ..ops import keys as keys_mod
         from ..table import Table
 
         names, ctx = t.names, t.ctx
@@ -305,9 +306,7 @@ class _Executor:
             c = pred.evaluate(env, lits)
             mask = c.data & c.validity & compact_mod.live_mask(
                 cap, tt.row_counts[0])
-            perm, m = compact_mod.compact_indices(mask)
-            live = compact_mod.live_mask(cap, m)
-            cols = tuple(env[n].take(perm, valid_mask=live) for n in keep)
+            cols, m = keys_mod.compact_columns(mask, [env[n] for n in keep])
             return Table(cols, jnp.reshape(m, (1,)), keep, ctx)
 
         return self._stage("plan_filter", "filter", fn, (t,),
@@ -496,6 +495,7 @@ class _Executor:
         from ..ops import compact as compact_mod
         from ..ops import groupby as groupby_mod
         from ..ops import join as join_mod
+        from ..ops import keys as keys_mod
         from ..parallel import ops as par_ops
         from ..table import Table, _cap_round, _get_everywhere, host_sync
 
@@ -570,10 +570,9 @@ class _Executor:
                     c = cn.pred.evaluate(env, ops)
                     keepm = c.data & c.validity & compact_mod.live_mask(
                         cap, count)
-                    perm, count = compact_mod.compact_indices(keepm)
-                    live = compact_mod.live_mask(cap, count)
-                    env = {k: col.take(perm, valid_mask=live)
-                           for k, col in env.items()}
+                    kept, count = keys_mod.compact_columns(
+                        keepm, env.values())
+                    env = dict(zip(env, kept))
                 # Project, dead Derive: nothing to run, env is by name
             in_names = tuple(by_names) + tuple(n for n, _ in aggs_by_name)
             in_names = tuple(dict.fromkeys(in_names))

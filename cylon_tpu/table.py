@@ -39,6 +39,7 @@ from .ops import aggregates as agg_mod
 from .ops import compact as compact_mod
 from .ops import groupby as groupby_mod
 from .ops import join as join_mod
+from .ops import keys as keys_mod
 from .ops import setops as setops_mod
 from .ops import sort as sort_mod
 from .ops import unique as unique_mod
@@ -503,9 +504,7 @@ class Table:
             env = _RowEnv({n: c for n, c in zip(names, t.columns)})
             mask = predicate(env)
             mask = jnp.asarray(mask, bool) & compact_mod.live_mask(cap, count)
-            perm, m = compact_mod.compact_indices(mask)
-            cols = tuple(c.take(perm, valid_mask=compact_mod.live_mask(cap, m))
-                         for c in t.columns)
+            cols, m = keys_mod.compact_columns(mask, t.columns)
             return Table(cols, jnp.reshape(m, (1,)), names, ctx)
 
         # the predicate object itself keys the cache (kept alive by the cache
@@ -523,12 +522,10 @@ class Table:
             from .ops import common as common_mod
             mask = jnp.concatenate([compact_mod.live_mask(cap_a, a.row_counts[0]),
                                     compact_mod.live_mask(cap_b, b.row_counts[0])])
-            perm, m = compact_mod.compact_indices(mask)
-            cols = []
-            for ca, cb in zip(a.columns, b.columns):
-                cc = common_mod.concat_columns(ca, cb)
-                cols.append(cc.take(perm, valid_mask=compact_mod.live_mask(cap_a + cap_b, m)))
-            return Table(tuple(cols), jnp.reshape(m, (1,)), names, ctx)
+            cols, m = keys_mod.compact_columns(mask, [
+                common_mod.concat_columns(ca, cb)
+                for ca, cb in zip(a.columns, b.columns)])
+            return Table(cols, jnp.reshape(m, (1,)), names, ctx)
 
         return _shard_wise(self.ctx, fn, self, other, key=("merge",))
 
@@ -859,9 +856,7 @@ class Table:
             cap = t.columns[0].data.shape[0]
             mc = m.columns[0]
             keep = mc.data & mc.validity & compact_mod.live_mask(cap, t.row_counts[0])
-            perm, cnt = compact_mod.compact_indices(keep)
-            cols = tuple(c.take(perm, valid_mask=compact_mod.live_mask(cap, cnt))
-                         for c in t.columns)
+            cols, cnt = keys_mod.compact_columns(keep, t.columns)
             return Table(cols, jnp.reshape(cnt, (1,)), names, ctx)
 
         return _shard_wise(self.ctx, fn, self, mask, key=("filter",))

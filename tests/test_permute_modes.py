@@ -496,3 +496,158 @@ def test_counter_says_how_many_lanes_rode_a_compaction(realize):
         jax.make_jaxpr(lambda m: compact.partition_indices(m))(mask)
         assert metrics.counter_value("compact.payload_lanes") - before == 4
     assert "compact.payload_lanes" in metrics.snapshot()["counters"]
+
+
+def _compacted_columns(rng, case):
+    """(mask, columns) of one case of ``keys.compact_columns``; the
+    columns are built buffer by buffer, so that a null keeps whatever
+    bits lie under it."""
+    from cylon_tpu import dtypes
+
+    cap = 777 if case == "odd_capacity" else 1024
+
+    def column(data, logical, p_valid=0.8):
+        return colmod.Column(jnp.asarray(data),
+                             jnp.asarray(rng.random(cap) < p_valid), None,
+                             logical)
+
+    def int32():
+        return column(rng.integers(-2 ** 31, 2 ** 31, cap).astype(np.int32),
+                      dtypes.int32)
+
+    def float64():
+        bits = rng.integers(0, 1 << 63, cap, dtype=np.uint64)
+        odd = np.array([0x7FF8000000000001, 0xFFF8000000012345,
+                        0x8000000000000000, 0, 0x7FF0000000000000, 1],
+                       np.uint64)
+        return column(np.where(rng.random(cap) < 0.5,
+                               odd[rng.integers(0, 6, cap)],
+                               bits).view(np.float64), dtypes.double)
+
+    def int64():
+        return column(rng.integers(-2 ** 63, 2 ** 63, cap), dtypes.int64)
+
+    def string():
+        words = np.array(["", "a", "bravo", "charlie delta"], object)
+        return colmod.from_numpy(words[rng.integers(0, 4, cap)],
+                                 validity=rng.random(cap) < 0.8)
+
+    cols = {
+        "int32_nulls": lambda: [int32()],
+        "float64_odd_bits": lambda: [float64(), float64()],
+        "int64": lambda: [int64(), int32()],
+        "string_beside_numeric": lambda: [int32(), string(), float64()],
+        "past_the_lane_budget": lambda: [int32() for _ in range(9)]
+        + [float64(), int64(), int32()],
+    }.get(case, lambda: [int32(), float64()])()
+    mask = {"all_false": np.zeros(cap, bool),
+            "all_true": np.ones(cap, bool)}.get(case, rng.random(cap) < 0.4)
+    return jnp.asarray(mask), cols
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("case", [
+    "int32_nulls", "float64_odd_bits", "int64", "string_beside_numeric",
+    "past_the_lane_budget", "all_false", "all_true", "odd_capacity"])
+def test_compact_columns_equals_index_then_take(realize, case, mode):
+    """``keys.compact_columns``: what the index of ``compact_indices(mask)``
+    and ``Column.take(idx, valid_mask=live)`` of every column gave the
+    filters before, buffer by buffer and bit for bit, nulls and filler
+    included, under both rows of ``ops/realization.py``; under ``sort`` only
+    a buffer that cannot ride goes through an index."""
+    mask, cols = _compacted_columns(np.random.default_rng(len(case)), case)
+    cap = mask.shape[0]
+    with realize(realization.current()._replace(permute=mode)):
+        idx, want_count = _parent_compact_indices(mask)
+        live = compact.live_mask(cap, want_count)
+        want = [c.take(idx, valid_mask=live) for c in cols]
+        got, count = keys.compact_columns(mask, cols)
+        jaxpr = str(jax.make_jaxpr(
+            lambda m, c: keys.compact_columns(m, c))(mask, cols))
+    assert int(count) == int(want_count) == int(mask.sum())
+    assert isinstance(got, tuple) and len(got) == len(cols)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        for moved, taken in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            assert moved.dtype == taken.dtype and moved.shape == taken.shape
+            assert np.asarray(moved).tobytes() == np.asarray(taken).tobytes()
+    if mode == "sort":
+        # 32-bit lanes past the word of validity: 9 + 2 ride, the int64
+        # and the last int32 do not; a string's bytes never do
+        took = {"string_beside_numeric": 1, "past_the_lane_budget": 2}
+        assert jaxpr.count(" sort[") == 1
+        assert jaxpr.count(" gather[") == took.get(case, 0)
+
+
+def _frame(rng, n):
+    a = rng.integers(0, 50, n).astype(np.int32)
+    x = rng.random(n)
+    x[rng.random(n) < 0.2] = np.nan                 # nulls
+    s = np.array(["ash", "birch", None, "cedar"], object)[
+        rng.integers(0, 4, n)]
+    return pd.DataFrame({"a": a, "x": x, "s": s,
+                         "big": rng.integers(-2 ** 62, 2 ** 62, n)})
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("op", ["select", "filter", "merge", "planned"])
+def test_row_compactions_match_pandas(realize, op, mode):
+    """``Table.select``, ``Table.filter``, ``Table.merge`` and a planned
+    filter, whose compactions all go through ``keys.compact_columns``,
+    end to end against pandas under both rows."""
+    from cylon_tpu import CylonContext, Table
+    from cylon_tpu.plan import col
+
+    rng = np.random.default_rng(34)
+    df, other = _frame(rng, 300), _frame(rng, 41)
+    with realize(realization.current()._replace(permute=mode)):
+        ctx = CylonContext.Init()
+        t = Table.from_pandas(df, ctx=ctx)
+        if op == "select":
+            got, want = t.select(lambda r: r.a % 3 == 1), df[df.a % 3 == 1]
+        elif op == "filter":
+            keep = Table.from_pandas(pd.DataFrame({"m": df.a.values > 20}),
+                                     ctx=ctx)
+            got, want = t.filter(keep), df[df.a > 20]
+        elif op == "merge":
+            got = t.merge(Table.from_pandas(other, ctx=ctx))
+            want = pd.concat([df, other])
+        else:
+            got = t.plan().filter((col("a") >= 10) & (col("x") < 0.5)
+                                  ).execute()
+            want = df[(df.a >= 10) & (df.x < 0.5)]
+        got = got.to_pandas()
+    assert len(want) and list(got.columns) == list(want.columns)
+    pd.testing.assert_frame_equal(
+        got.reset_index(drop=True),
+        want.reset_index(drop=True).astype(got.dtypes.to_dict()),
+        check_exact=True)
+
+
+def test_a_filter_counts_the_lanes_that_ride_and_the_lanes_it_takes(realize):
+    """TPC-H Q5's filter of the orders keeps two int32 columns: two data
+    lanes and one word of validity ride the compaction's sort and nothing
+    is taken; a string column's byte matrix is (its lengths ride)."""
+    from cylon_tpu import CylonContext, Table
+    from cylon_tpu.obs import metrics
+    from cylon_tpu.plan import col
+
+    names = ("compact.payload_lanes", "sort.payload_lanes", "sort.take_lanes")
+
+    def counted(run):
+        before = [metrics.counter_value(n) for n in names]
+        run()
+        return [metrics.counter_value(n) - b for n, b in zip(names, before)]
+
+    with realize(realization.current()._replace(permute="sort")):
+        ctx = CylonContext.Init()
+        orders = Table.from_pydict({n: np.arange(64, dtype=np.int32) for n in (
+            "o_orderkey", "o_custkey", "o_orderdate")}, ctx=ctx)
+        dates = (col("o_orderdate") >= 7) & (col("o_orderdate") < 30)
+        assert counted(orders.plan().filter(dates).project(
+            ["o_orderkey", "o_custkey"]).execute) == [3, 3, 0]
+        tagged = Table.from_pydict({"k": np.arange(64, dtype=np.int32),
+                                    "tag": ["ab", "c"] * 32}, ctx=ctx)
+        width = tagged.columns[1].data.shape[1]
+        assert counted(lambda: tagged.select(lambda r: r.k % 2 == 0)) == [
+            3, 3, -(-width // 4)]

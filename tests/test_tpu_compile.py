@@ -253,7 +253,8 @@ def test_plan_filter_stage_compiles_with_literal_operands(
     bench/configs/tpch_q5.json) as the planner launches it: one program
     named ``plan_filter`` whose two literals are scalar operands, so no
     date compiles it again; the date column the predicate alone reads goes
-    through no compaction."""
+    through no compaction, and the kept columns ride the compaction's own
+    sort (``keys.compact_columns``): the program holds no gather."""
     from types import SimpleNamespace
 
     from cylon_tpu import CylonContext, Table
@@ -291,6 +292,13 @@ def test_plan_filter_stage_compiles_with_literal_operands(
     # date is compared and dropped
     out = compiled.output_shardings
     assert len(jax.tree_util.tree_leaves(out)) == 2 * 2 + 1
+    assert not _gather_stages(compiled)
+    assert not _validity_gathers(compiled)
+    assert not any(_custom_fusion_gathers(compiled).values())
+    # the packed word, the two kept columns' data, one word of validity
+    sort, = [line.split(" sort(")[0]
+             for line in compiled.as_text().splitlines() if " sort(" in line]
+    assert sort.count(f"[{ROWS}]") == 4
 
 
 @pytest.mark.parametrize("with_string", [False, True],
