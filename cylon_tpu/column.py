@@ -24,6 +24,7 @@ Representation choices (TPU-first):
 """
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -406,42 +407,65 @@ def _decode_rows(rows: np.ndarray, valid: np.ndarray,
     return out
 
 
+def wide_wait(buffers):
+    """Span ``table.fetch.d2h.wide`` round the host's wait for ``buffers``
+    where any of its leaves has 8-byte elements, else nothing: a 64-bit
+    buffer leaves a chip far more slowly than a 32-bit one.  Where every
+    copy is in flight together (``Table._live_shard_rows``) the buffers
+    are waited for in flatten order, so the span is the host's wait on a
+    64-bit buffer, not its transfer: what earlier buffers' waits already
+    covered is not in it."""
+    if any(np.dtype(b.dtype).itemsize == 8
+           for b in jax.tree_util.tree_leaves(buffers)):
+        return obs_span("table.fetch.d2h.wide")
+    return contextlib.nullcontext()
+
+
 def fetch_d2h(buffers, n: Optional[int] = None, get=jax.device_get):
     """One blocking device->host copy of a fetch, as NumPy: a pytree of
     buffers, or the first ``n`` rows of one buffer (the device slice is
-    part of the copy).  Span ``table.fetch.d2h``; the bytes that arrived
-    add to counter ``table.fetch.bytes``.  A buffer that is NumPy already
-    (a sharded table's fetched rows, ``Table._fetched_columns``) was
-    counted when it arrived: its rows come back as they are, with no span
-    and nothing counted."""
+    part of the copy).  Span ``table.fetch.d2h``, and inside it
+    ``table.fetch.d2h.wide`` (``wide_wait``) where a buffer is 64-bit; the
+    bytes that arrived add to counter ``table.fetch.bytes``.  A buffer
+    that is NumPy already (a sharded table's fetched rows,
+    ``Table._fetched_columns``) was counted when it arrived: its rows come
+    back as they are, with no span and nothing counted."""
     if isinstance(buffers, np.ndarray):
         return buffers if n is None else buffers[:n]
     with obs_span("table.fetch.d2h"):
-        out = get(buffers if n is None else buffers[:n])
+        with wide_wait(buffers):
+            out = get(buffers if n is None else buffers[:n])
         obs_metrics.counter_add(
             "table.fetch.bytes",
             sum(a.nbytes for a in jax.tree_util.tree_leaves(out)))
     return out
 
 
+def _fetch_buffers(col: Column, n: int):
+    """validity, data and lengths (None for a fixed-width column) of the
+    first ``n`` rows on the host, each copied by ``fetch_d2h``: what
+    ``to_numpy`` / ``to_arrow`` convert, in span ``table.fetch.convert``
+    after the copies, so that span holds no ``table.fetch.d2h``."""
+    return (fetch_d2h(col.validity, n), fetch_d2h(col.data, n),
+            None if col.lengths is None else fetch_d2h(col.lengths, n))
+
+
 def to_numpy(col: Column, row_count: int):
     """Export valid rows to host. Strings come back as an object array of
     ``bytes`` decoded to str when valid utf-8."""
-    n = int(row_count)
-    valid = fetch_d2h(col.validity, n)
-    if col.is_string:
-        mat = fetch_d2h(col.data, n)
-        lens = fetch_d2h(col.lengths, n)
-        return _decode_rows(_bytes_rows(mat, lens), valid)
-    vals = fetch_d2h(col.data, n)
-    ndt = col.dtype.numpy_dtype()
-    if vals.dtype != ndt and vals.dtype.kind in "iu" and np.dtype(ndt).kind in "iu":
-        vals = vals.astype(ndt)  # narrow-mode count buffers widen at export
-    if valid.all():
-        return vals
-    out = vals.astype(object)
-    out[~valid] = None
-    return out
+    valid, vals, lens = _fetch_buffers(col, int(row_count))
+    with obs_span("table.fetch.convert"):
+        if col.is_string:
+            return _decode_rows(_bytes_rows(vals, lens), valid)
+        ndt = col.dtype.numpy_dtype()
+        if (vals.dtype != ndt and vals.dtype.kind in "iu"
+                and np.dtype(ndt).kind in "iu"):
+            vals = vals.astype(ndt)  # narrow-mode count buffers widen at export
+        if valid.all():
+            return vals
+        out = vals.astype(object)
+        out[~valid] = None
+        return out
 
 
 def to_arrow(col: Column, row_count: int):
@@ -449,20 +473,16 @@ def to_arrow(col: Column, row_count: int):
     padded byte matrices back into offsets+bytes)."""
     import pyarrow as pa
 
-    n = int(row_count)
-    valid = fetch_d2h(col.validity, n)
-    mask = None if valid.all() else ~valid
-    at = dtypes.to_arrow_type(col.dtype)
-    if col.is_string:
-        mat = fetch_d2h(col.data, n)
-        lens = fetch_d2h(col.lengths, n)
-        rows = _bytes_rows(mat, lens)
-        if col.dtype.type == Type.STRING:
-            # errors='replace' never raises, so every valid row decodes
-            vals = _decode_rows(rows, valid, errors="replace")
-            vals[~valid] = ""  # placeholder under the null mask
-        else:
-            vals = rows
+    valid, vals, lens = _fetch_buffers(col, int(row_count))
+    with obs_span("table.fetch.convert"):
+        mask = None if valid.all() else ~valid
+        at = dtypes.to_arrow_type(col.dtype)
+        if col.is_string:
+            rows = _bytes_rows(vals, lens)
+            if col.dtype.type == Type.STRING:
+                # errors='replace' never raises, so every valid row decodes
+                vals = _decode_rows(rows, valid, errors="replace")
+                vals[~valid] = ""  # placeholder under the null mask
+            else:
+                vals = rows
         return pa.array(vals, type=at, mask=mask)
-    vals = fetch_d2h(col.data, n)
-    return pa.array(vals, type=at, mask=mask)

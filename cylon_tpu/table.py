@@ -246,23 +246,33 @@ class Table:
         whole buffers come through one cross-process all-gather (the
         reference's analog is a gather-to-rank pattern over MPI) and are
         cut to their counts here.  Either way the blocking copies are span
-        ``table.fetch.d2h`` and what arrived adds to ``table.fetch.bytes``;
-        nothing is uploaded."""
+        ``table.fetch.d2h`` (the waits for 64-bit buffers also
+        ``table.fetch.d2h.wide``) and what arrived adds to
+        ``table.fetch.bytes``; nothing is uploaded.  Putting a buffer's
+        shard pieces into one array (the cross-process path's cut to the
+        counts with it) is span ``table.fetch.assemble``, one a buffer,
+        opened after that buffer's wait has closed."""
         cap = self.shard_capacity
         buffers, treedef = jax.tree_util.tree_flatten(self.columns)
         if jax.process_count() > 1:
             counts, whole = column_mod.fetch_d2h(
                 (self.row_counts, buffers), get=_get_everywhere)
             counts = np.asarray(counts)
-            pieces = ([np.asarray(w)[s * cap: s * cap + int(n)]
-                       for s, n in enumerate(counts)] for w in whole)
+            # lazy, so the cut to the counts runs inside assemble's span
+            pieces = ((np.asarray(w)[s * cap: s * cap + int(n)]
+                       for s, n in enumerate(counts)) for w in whole)
         else:
             counts = np.asarray(column_mod.fetch_d2h(self.row_counts))
             pieces = ([rows[s] for s in range(self.num_shards)]
                       for rows in _live_shard_rows(buffers, counts, cap))
+
+        def assemble(parts):
+            with obs_span("table.fetch.assemble"):
+                return np.concatenate(list(parts))
+
         # a buffer is assembled while the later ones are still on their way
         cols = jax.tree_util.tree_unflatten(
-            treedef, [np.concatenate(parts) for parts in pieces])
+            treedef, [assemble(parts) for parts in pieces])
         return list(cols), int(counts.sum())
 
     def _export_columns(self) -> Tuple[List[Column], int]:
@@ -284,7 +294,8 @@ class Table:
         hold HOST (NumPy) buffers — a sharded table's cut to the live
         count (``_live_shard_rows``), a one-shard table's whole, and a
         writer cuts to the count either way; the copies are spans
-        ``table.fetch.d2h``, counted in ``table.fetch.bytes`` once, and
+        ``table.fetch.d2h`` (``table.fetch.d2h.wide`` inside them where a
+        buffer is 64-bit), counted in ``table.fetch.bytes`` once, and
         nothing is uploaded."""
         with obs_span("table.fetch", shards=self.num_shards):
             counts = np.asarray(column_mod.fetch_d2h(self.row_counts,
@@ -1109,8 +1120,11 @@ def _live_shard_rows(buffers: Sequence[jax.Array], counts: np.ndarray,
     before the first is waited for: each chip has its own link to the
     host.  An empty shard moves nothing; a replicated buffer spans every
     shard and is sliced accordingly.  The dispatch and each buffer's wait
-    are spans ``table.fetch.d2h``; the bytes that arrived add to counter
-    ``table.fetch.bytes``."""
+    are spans ``table.fetch.d2h``, and a 64-bit buffer's wait is also
+    ``table.fetch.d2h.wide`` inside it (``column.wide_wait``): the buffers
+    are waited for in flatten order, so that span is the host's wait on a
+    64-bit buffer once the earlier ones have landed, not its transfer
+    time.  The bytes that arrived add to counter ``table.fetch.bytes``."""
     step = max(1, cap // _FETCH_STEPS)
     in_flight = collections.deque()
     with obs_span("table.fetch.d2h"):
@@ -1135,7 +1149,7 @@ def _live_shard_rows(buffers: Sequence[jax.Array], counts: np.ndarray,
         # a buffer's device slices are let go as soon as it has landed
         while in_flight:
             arr, slices = in_flight.popleft()
-            with obs_span("table.fetch.d2h"):
+            with obs_span("table.fetch.d2h"), column_mod.wide_wait(arr):
                 rows = {sid: (np.empty((0,) + arr.shape[1:], arr.dtype)
                               if piece is None else np.asarray(piece))
                         for sid, piece in slices.items()}

@@ -8,6 +8,7 @@ the sum of the depth-0 spans; the HBM gauges read ``memory_stats()``; and
 ``tools/trace_report.py --device`` reduces a recorded chip trace to the
 stage table kept beside it.
 """
+import dataclasses
 import importlib.util
 import json
 import os
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cylon_tpu import Table, config, obs
+from cylon_tpu import Table, config, dtypes, obs
 from cylon_tpu.config import JoinType
 from cylon_tpu.obs import metrics as obs_metrics
 from cylon_tpu.obs import spans as obs_spans
@@ -199,6 +200,137 @@ def test_fetch_counts_the_bytes_it_moved(clean_obs, local_ctx, ctx4, shards):
     assert rep["table.fetch"][1] == 1
     assert rep["table.fetch.d2h"][0] <= rep["table.fetch"][0]
     assert "host.sync" not in rep  # the fetch's reads are its own
+
+
+FETCH_PARTS = ("table.fetch.d2h", "table.fetch.assemble",
+               "table.fetch.convert")
+
+
+def _result_table(ctx, rows=200, strings=False):
+    """A join cell's result: an int64 key, float32 values, a count kept
+    narrow (an int32 buffer under an int64 column, as narrow accumulation
+    leaves it); with ``strings`` a string column beside them."""
+    rng = np.random.default_rng(5)
+    names = ["l_k", "sum_a", "count_a"]
+    arrays = [rng.integers(0, rows, rows), rng.random(rows, np.float32),
+              rng.integers(1, 9, rows).astype(np.int32)]
+    if strings:
+        names.append("s")
+        arrays.append(np.array([f"row{i}" for i in range(rows)], object))
+    table = Table.from_numpy(names, arrays, ctx=ctx)
+    cols = list(table.columns)
+    cols[2] = dataclasses.replace(cols[2], dtype=dtypes.int64)
+    return Table(cols, table.row_counts, table.names, table.ctx)
+
+
+def _export(table, how):
+    out = getattr(table, how)()
+    assert (out.num_rows if how == "to_arrow" else len(out["l_k"])) == 200
+    return out
+
+
+@pytest.mark.parametrize("strings", [False, True])
+@pytest.mark.parametrize("how", ["to_numpy", "to_arrow"])
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_fetch_splits_into_copies_assembly_and_conversion(
+        clean_obs, local_ctx, ctx4, shards, how, strings):
+    table = _result_table(local_ctx if shards == 1 else ctx4,
+                          strings=strings)
+    out = _export(table, how)
+    if how == "to_numpy":
+        assert out["count_a"].dtype == np.int64  # the narrow count widened
+    rep = obs_spans.aggregate_report()
+    count = {n: c for n, (_, c) in rep.items()}
+    buffers = len(jax.tree_util.tree_leaves(table.columns))
+    wide = sum(np.dtype(b.dtype).itemsize == 8
+               for b in jax.tree_util.tree_leaves(table.columns))
+    assert wide == 1  # l_k's data; floats, the count, validity, strings: no
+    assert count.get("table.fetch.assemble", 0) == (buffers if shards == 4
+                                                    else 0)
+    assert count["table.fetch.convert"] == len(table.columns)
+    assert count["table.fetch.d2h.wide"] == wide
+    parts = sum(rep.get(n, (0.0, 0))[0] for n in FETCH_PARTS)
+    assert parts <= rep["table.fetch"][0]
+    assert rep["table.fetch.d2h.wide"][0] <= rep["table.fetch.d2h"][0]
+    assert "host.sync" not in rep  # the fetch's reads are its own
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_fetch_parts_are_disjoint_children_of_the_fetch(
+        clean_obs, local_ctx, ctx4, shards):
+    table = _result_table(local_ctx if shards == 1 else ctx4, strings=True)
+    with config.knob_env(CYLON_TPU_TRACE="1"):
+        _export(table, "to_numpy")
+        _export(table, "to_arrow")
+    events = obs_spans.events()
+    span = {n: [(e.ts, e.ts + e.dur) for e in events if e.name == n]
+            for n in ("table.fetch", "table.fetch.d2h.wide") + FETCH_PARTS}
+
+    def inside(iv, outers):
+        return any(a <= iv[0] and iv[1] <= b for a, b in outers)
+
+    assert len(span["table.fetch"]) == 2
+    parts = sorted(iv for n in FETCH_PARTS for iv in span[n])
+    assert parts and all(inside(iv, span["table.fetch"]) for iv in parts)
+    assert all(a[1] <= b[0] for a, b in zip(parts, parts[1:]))
+    assert span["table.fetch.d2h.wide"] and all(
+        inside(iv, span["table.fetch.d2h"])
+        for iv in span["table.fetch.d2h.wide"])
+
+
+def test_no_fetch_part_opens_with_tracing_off(clean_obs, ctx4):
+    table = _result_table(ctx4, strings=True)
+    with config.knob_env(CYLON_TPU_TRACE="0"):
+        _export(table, "to_numpy")
+        _export(table, "to_arrow")
+    assert obs_spans.aggregate_report() == {}
+
+
+FETCH_READERS = {"entry.fetch_assemble_ms_per_query": "table.fetch.assemble",
+                 "entry.fetch_convert_ms_per_query": "table.fetch.convert",
+                 "entry.fetch_d2h_wide_ms_per_query": "table.fetch.d2h.wide"}
+
+
+@pytest.mark.parametrize("name", sorted(FETCH_READERS))
+def test_the_fetch_part_readers(name):
+    """ms of the part per completed query; a part that never opened is a
+    measured 0; a program without ``obs.root`` (the parent of the PR that
+    added these spans has it, and reads 0) or a window without a completed
+    query gives nothing."""
+    from types import SimpleNamespace
+
+    from bench.run import load_reader
+
+    def read(spans, queries):
+        return load_reader(name)(SimpleNamespace(
+            spans=spans, counters={"queries": queries}))
+
+    spans = {"obs.root": (25.0, 20), "table.fetch": (0.9, 5),
+             FETCH_READERS[name]: (0.6, 40)}
+    assert read(spans, 5) == pytest.approx(120.0)
+    assert read({"obs.root": (25.0, 20), "table.fetch": (0.9, 5)}, 5) == 0
+    assert read({k: v for k, v in spans.items() if k != "obs.root"},
+                5) is None
+    assert read({}, 5) is None
+    assert read(spans, 0) is None
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_the_fetch_part_readers_read_a_real_fetch(clean_obs, local_ctx,
+                                                  ctx4, shards):
+    from types import SimpleNamespace
+
+    from bench.run import load_reader
+
+    table = _result_table(local_ctx if shards == 1 else ctx4)
+    with obs.span("bench.like.query"):  # a query: obs.root is its span
+        _export(table, "to_numpy")
+    run = SimpleNamespace(spans=obs_spans.aggregate_report(),
+                          counters={"queries": 1})
+    got = {n: load_reader(n)(run) for n in FETCH_READERS}
+    assert got["entry.fetch_convert_ms_per_query"] > 0
+    assert got["entry.fetch_d2h_wide_ms_per_query"] > 0
+    assert (got["entry.fetch_assemble_ms_per_query"] > 0) == (shards == 4)
 
 
 def test_the_limit_uploads_the_live_rows_it_gathered(clean_obs, ctx4):
