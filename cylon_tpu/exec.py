@@ -1694,7 +1694,7 @@ def chunked_repartition(data, keys, world: int, *, passes: int = 4,
     @jax.jit
     def prog(cols, cnt):
         t = partition_mod.hash_targets(cols, cnt, key_idx, world)
-        perm_t = shuffle_mod._perm_by_target(t, world)
+        perm_t, = shuffle_mod._perm_by_target(t, world)
         counts = shuffle_mod.target_counts(t, world)
         grouped = tuple(c.take(perm_t) for c in cols)
         return grouped, counts

@@ -16,8 +16,9 @@ platform gets (``realization.py`` has the table and the measurements):
   ``lax.sort`` it (XLA:TPU serializes scatters).
 
 A compaction whose index exists only to move rows moves them itself:
-``compact_indices(mask, *payload)`` and ``partition_indices`` return each
-1-D payload array as ``jnp.take(x, idx)`` would, bit for bit.  Under
+``compact_indices(mask, *payload)``, ``partition_indices`` and the
+exchange's grouping by target, ``sort_by_target``, return each 1-D payload
+array as ``jnp.take(x, idx)`` would, bit for bit.  Under
 ``sort`` the arrays are operands 2... of the packed word's own sort (a
 32-bit lane costs 15-18 ms there at 2^24 rows, 0.144-0.45 s through an
 index: PERF.md §6); under ``scatter`` they go through ``jnp.take``.
@@ -71,6 +72,35 @@ def _mask_sort_perm(mask: jax.Array, payload: Tuple[jax.Array, ...] = ()):
             << jnp.uint32(bits)) | iota
     s, *carried = jax.lax.sort((word,) + payload, num_keys=1, is_stable=False)
     return (s & jnp.uint32((1 << bits) - 1)).astype(jnp.int32), carried
+
+
+#: Most 32-bit payload lanes one sort carries beside its keys; what is past
+#: it moves through ``take(perm)``.  A lane more costs every such sort
+#: compile seconds as well as device time (PERF.md, Findings).
+MAX_PAYLOAD_LANES = 12
+
+
+@stage("compact.partition")
+def sort_by_target(targets: jax.Array, world: int, *payload: jax.Array):
+    """``(perm, *carried)``: the stable permutation that groups rows by
+    ``targets`` (values in [0, world], ``world`` the padding last), and
+    each 1-D ``payload`` array as ``jnp.take(x, perm)`` would return it.
+    The small-alphabet form of ``_mask_sort_perm``: where target and row
+    index fit one word, ``target << idx_bits | row`` is sorted unstably
+    (the words are unique, so rows keep their order inside a target);
+    past 32 bits, a two-operand stable sort.  The payload rides either
+    sort as further operands, never compared."""
+    cap = targets.shape[0]
+    bits = index_bits(cap)
+    if world.bit_length() + bits > 32:
+        iota = jnp.arange(cap, dtype=_idx_dtype(cap))
+        _, perm, *carried = jax.lax.sort((targets, iota) + payload,
+                                         num_keys=1, is_stable=True)
+        return (perm, *carried)
+    word = (targets.astype(jnp.uint32) << jnp.uint32(bits)) | jnp.arange(
+        cap, dtype=jnp.uint32)
+    s, *carried = jax.lax.sort((word,) + payload, num_keys=1, is_stable=False)
+    return ((s & jnp.uint32((1 << bits) - 1)).astype(jnp.int32), *carried)
 
 
 def _idx_dtype(cap: int):

@@ -181,12 +181,6 @@ def _pack_encoded(enc: Sequence[Tuple[jax.Array, int]]) -> List[jax.Array]:
     return out
 
 
-#: Most 32-bit payload lanes one sort carries beside its keys; what is past
-#: it moves through ``take(perm)``.  PERF.md Findings PR 26 has the compile
-#: and device seconds that chose it.
-_MAX_PAYLOAD_LANES = 12
-
-
 def row_lanes(buffer: jax.Array) -> int:
     """32-bit lanes one row of ``buffer`` fills."""
     row_bytes = buffer.dtype.itemsize * math.prod(buffer.shape[1:])
@@ -218,13 +212,13 @@ def pack_payload(buffers: Sequence[jax.Array]):
     vector is built for them.  Returns ``(lanes, layout)``; ``layout`` is
     for ``unpack_payload`` and holds ``None`` for a buffer that cannot ride
     and is left to ``take(perm)``: a 2-D byte matrix and whatever is past
-    ``_MAX_PAYLOAD_LANES``.
+    ``compact.MAX_PAYLOAD_LANES``.
 
     1-D ``bool`` buffers (validity) ride as one bit each, 32 to a ``uint32``
     word (``pack_bits``); every other 1-D buffer rides as it is: a sort
     moves a non-key operand as bits, so NaN payloads, -0.0 and float64
     (emulated on a TPU) come back exact."""
-    budget = _MAX_PAYLOAD_LANES
+    budget = compact.MAX_PAYLOAD_LANES
     flat = [i for i, b in enumerate(buffers) if b.ndim == 1]
     bits = [i for i in flat if buffers[i].dtype == jnp.bool_][:32 * budget]
     layout: list = [None] * len(buffers)

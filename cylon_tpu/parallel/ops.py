@@ -255,9 +255,17 @@ def _record_exchange(cols, packed: bool, family: str,
     rounds the exchange went in and ``shuffle.operand_bytes`` the bytes of
     its send operands in the layout the collective moves: the ragged
     family's ``operand_rows`` at one 128-lane vector a row, the bucketed
-    family's padded buckets, which are the bytes sent."""
+    family's padded buckets, which are the bytes sent.  Of a packed
+    plane's words, ``shuffle.payload_lanes`` counts those that rode the
+    ragged exchange's target sort (``shuffle.riding_words``) and
+    ``shuffle.take_lanes`` those taken through a permutation."""
     launches = 1 if packed else shuffle_mod.buffer_count(cols)
     bytes_sent = rows_exchanged * _row_bytes(cols, packed, spec)
+    if packed:
+        words = plane_mod.plane_words(cols, spec)
+        ride = shuffle_mod.riding_words(words) if family == "ragged" else 0
+        obs_metrics.counter_add("shuffle.payload_lanes", ride)
+        obs_metrics.counter_add("shuffle.take_lanes", words - ride)
     obs_metrics.counter_add("shuffle.exchanges")
     obs_metrics.counter_add("shuffle.collective_launches", launches)
     obs_metrics.counter_add("shuffle.counts_gathers")

@@ -317,15 +317,11 @@ def _field_values(cols: Sequence[Column], spec=None,
 
 
 @stage("plane.pack")
-def pack_plane(cols: Sequence[Column], spec=None,
-               codes: Optional[Dict[int, jax.Array]] = None) -> jax.Array:
-    """Bit-pack the columns' buffers into one uint32[rows, words] plane.
-    Bit-exact round trip with unpack_plane (floats travel as raw bits, so
-    NaN payloads and -0.0 survive).  With ``spec``, compressed fields are
-    laid out instead of raw ones (dict columns need ``codes``)."""
-    widths = _field_widths(cols, spec)
-    slots, nwords = _layout(widths)
-    n = cols[0].data.shape[0]
+def pack_plane_words(cols: Sequence[Column], spec=None,
+                     codes: Optional[Dict[int, jax.Array]] = None
+                     ) -> List[jax.Array]:
+    """The columns of ``pack_plane``'s plane, each a uint32[rows] word."""
+    slots, nwords = _layout(_field_widths(cols, spec))
     words: List[Optional[jax.Array]] = [None] * nwords
     for (word, shift, bits), v in zip(slots, _field_values(cols, spec,
                                                            codes)):
@@ -333,9 +329,20 @@ def pack_plane(cols: Sequence[Column], spec=None,
             continue
         sh = v if shift == 0 else (v << jnp.uint32(shift))
         words[word] = sh if words[word] is None else (words[word] | sh)
-    if nwords == 0:
-        return jnp.zeros((n, 0), jnp.uint32)
-    return jnp.stack([w for w in words], axis=1)
+    return words
+
+
+def pack_plane(cols: Sequence[Column], spec=None,
+               codes: Optional[Dict[int, jax.Array]] = None) -> jax.Array:
+    """Bit-pack the columns' buffers into one uint32[rows, words] plane.
+    Bit-exact round trip with unpack_plane (floats travel as raw bits, so
+    NaN payloads and -0.0 survive).  With ``spec``, compressed fields are
+    laid out instead of raw ones (dict columns need ``codes``)."""
+    words = pack_plane_words(cols, spec, codes)
+    with stage("plane.pack"):
+        if not words:
+            return jnp.zeros((cols[0].data.shape[0], 0), jnp.uint32)
+        return jnp.stack(words, axis=1)
 
 
 @stage("plane.unpack")
@@ -682,6 +689,9 @@ class PlaneCodec:
 
     def pack(self, cols: Sequence[Column]) -> jax.Array:
         return pack_plane(cols, self.spec, self.codes)
+
+    def pack_words(self, cols: Sequence[Column]) -> List[jax.Array]:
+        return pack_plane_words(cols, self.spec, self.codes)
 
     def unpack(self, plane: jax.Array, like: Sequence[Column],
                valid_mask: Optional[jax.Array] = None,

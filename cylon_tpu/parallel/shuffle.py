@@ -97,16 +97,19 @@ def _remap_oob_targets(targets: jax.Array, world: int) -> jax.Array:
     return jnp.where((targets < 0) | (targets > world), world, targets)
 
 
-def _perm_by_target(targets: jax.Array, world: int) -> jax.Array:
-    """Stable permutation grouping rows by target, padding (== world) last.
+def _perm_by_target(targets: jax.Array, world: int, *payload: jax.Array):
+    """``(perm, *carried)``: the stable permutation grouping rows by target,
+    padding (== world) last, and each 1-D ``payload`` array as
+    ``jnp.take(x, perm)`` would return it.
 
     The target alphabet is tiny (world + 1 values), so a counting scan —
     one cumsum per target value, unrolled at trace time — replaces the
     stable sort the Split kernel would otherwise pay
     (reference: arrow_kernels.hpp:60-96 appends per-target builders row by
     row; here each target's rows get destinations base_t + rank-in-target).
-    Falls back to ``lax.sort`` for wide meshes where the unroll would bloat
-    the program.
+    Where permutations sort, and for wide meshes where the unroll would
+    bloat the program, ``compact.sort_by_target`` sorts instead and the
+    payload rides that sort.
 
     Precondition: targets in [0, world] (world == padding).  Producers
     (hash_targets/range_targets) guarantee it; out-of-range values — negative
@@ -115,10 +118,8 @@ def _perm_by_target(targets: jax.Array, world: int) -> jax.Array:
     misrouting them to rank 0, a legitimate destination."""
     cap = targets.shape[0]
     targets = _remap_oob_targets(targets, world)
-    iota = jnp.arange(cap, dtype=jnp.int32)
     if world + 1 > _WIDE_MESH_CUTOFF or compact_mod.permute_mode() == "sort":
-        _, perm = jax.lax.sort((targets, iota), num_keys=1, is_stable=True)
-        return perm
+        return compact_mod.sort_by_target(targets, world, *payload)
     dest = jnp.zeros((cap,), jnp.int32)
     base = jnp.zeros((), jnp.int32)
     for t in range(world + 1):
@@ -126,7 +127,33 @@ def _perm_by_target(targets: jax.Array, world: int) -> jax.Array:
         c = jnp.cumsum(m.astype(jnp.int32))
         dest = jnp.where(m, base + c - 1, dest)
         base = base + c[-1]
-    return jnp.zeros((cap,), jnp.int32).at[dest].set(iota)
+    perm = jnp.zeros((cap,), jnp.int32).at[dest].set(
+        jnp.arange(cap, dtype=jnp.int32))
+    return (perm, *(jnp.take(x, perm) for x in payload))
+
+
+def riding_words(words: int) -> int:
+    """How many of a packed plane's ``words`` the ragged exchange carries
+    through its own target sort: none where permutations scatter, else up
+    to the payload a sort carries (``compact.MAX_PAYLOAD_LANES``); the rest
+    go through the permutation.  Shared with the exchange's counters
+    (``parallel/ops.py::_record_exchange``)."""
+    if compact_mod.permute_mode() != "sort":
+        return 0
+    return min(words, compact_mod.MAX_PAYLOAD_LANES)
+
+
+def _plane_by_target(targets: jax.Array, world: int, words) -> jax.Array:
+    """``uint32[cap, len(words)]``: the plane whose columns are ``words``,
+    its rows grouped by target.  The words that ride (``riding_words``)
+    are payload of ``_perm_by_target``; the rest are taken through its
+    permutation."""
+    ride = riding_words(len(words))
+    perm, *carried = _perm_by_target(targets, world, *words[:ride])
+    parts = [jnp.stack(carried, axis=1)] if carried else []
+    if ride < len(words):
+        parts.append(jnp.take(jnp.stack(words[ride:], axis=1), perm, axis=0))
+    return jnp.concatenate(parts, axis=1)
 
 
 def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
@@ -153,7 +180,7 @@ def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
 
     counts = target_counts(targets, world)
     # group rows by target: rows for shard t become contiguous, padding last
-    perm_t = _perm_by_target(targets, world)
+    perm_t, = _perm_by_target(targets, world)
     start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                              jnp.cumsum(counts, dtype=jnp.int32)[:-1]])
 
@@ -395,12 +422,12 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
 
     Exchange realization (``plane.pack_enabled()``, read at trace time):
     packed — the whole table travels as one bit-packed u32 plane through
-    ONE ``ragged_all_to_all`` (the target-sort gather also runs once, on
-    the plane); per-buffer — one collective and one sort-gather per
-    buffer.  Bit-identical outputs either way.
+    ONE ``ragged_all_to_all``, its words grouped by target inside the
+    target sort where permutations sort (``_plane_by_target``);
+    per-buffer — one collective and one sort-gather per buffer.
+    Bit-identical outputs either way.
     """
     counts = target_counts(targets, world)
-    perm_t = _perm_by_target(targets, world)
 
     # on-device count-matrix exchange (the 6-int header protocol's job);
     # trace-time child spans, like shuffle_shard's (cylint CY101-clean)
@@ -412,9 +439,9 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
     if plane_mod.pack_enabled():
         codec = plane_mod.PlaneCodec(cols, spec)
         with obs_spans.span("shuffle.pack", columns=len(cols)) as sp:
-            packed = codec.pack(cols)
-            sp.set(words=int(packed.shape[1]), compressed=spec is not None)
-            sorted_plane = jnp.take(packed, perm_t, axis=0)
+            words = codec.pack_words(cols)
+            sp.set(words=len(words), compressed=spec is not None)
+            sorted_plane = _plane_by_target(targets, world, words)
         with obs_spans.span("shuffle.collective",
                             family="ragged_all_to_all", packed=True,
                             launches=1, rounds=rounds or 1):
@@ -434,6 +461,8 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
                 tail = jnp.arange(out_capacity, dtype=jnp.int32) < total
             out_cols = codec.unpack(got, cols, tail_mask=tail)
         return out_cols, total
+
+    perm_t, = _perm_by_target(targets, world)
 
     def exchange(buf):
         squeeze = buf.ndim == 1

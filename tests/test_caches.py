@@ -83,6 +83,6 @@ def test_perm_by_target_clips_out_of_range(ctx2):
     from cylon_tpu.parallel import shuffle as shuffle_mod
 
     targets = jnp.asarray([0, 1, 99, -3, 1, 0], jnp.int32)
-    perm = shuffle_mod._perm_by_target(targets, world=2)
+    perm, = shuffle_mod._perm_by_target(targets, world=2)
     # a valid permutation: every source row appears exactly once
     assert sorted(np.asarray(perm).tolist()) == list(range(6))

@@ -111,7 +111,7 @@ def test_perm_by_target_wide_mesh_fallback(rng):
         targets = jnp.asarray(
             np.append(rng.integers(0, world, n - 5), [world] * 5)  # 5 padding
             .astype(np.int32))
-        perm = np.asarray(shuffle._perm_by_target(targets, world))
+        perm = np.asarray(shuffle._perm_by_target(targets, world)[0])
         t = np.asarray(targets)
         # stable grouping: targets nondecreasing, ties in original order
         g = t[perm]
@@ -254,12 +254,12 @@ def test_pack_payload_leaves_to_the_take_what_cannot_ride():
     import jax.numpy as jnp
 
     from cylon_tpu.obs import metrics
-    from cylon_tpu.ops import keys
+    from cylon_tpu.ops import compact, keys
 
     cap = 16
     flags = [jnp.arange(cap) % (i + 2) == 0 for i in range(33)]
     matrix = jnp.zeros((cap, 12), jnp.uint8)
-    wide = [jnp.arange(cap, dtype=jnp.int64)] * keys._MAX_PAYLOAD_LANES
+    wide = [jnp.arange(cap, dtype=jnp.int64)] * compact.MAX_PAYLOAD_LANES
 
     def counted(buffers):
         before = [metrics.counter_value(f"sort.{k}_lanes")
@@ -277,7 +277,7 @@ def test_pack_payload_leaves_to_the_take_what_cannot_ride():
         np.testing.assert_array_equal(np.asarray(back), np.asarray(flag))
 
     lanes, layout, (rode, took) = counted(flags[:1] + wide)
-    budget = keys._MAX_PAYLOAD_LANES - 1           # one word of validity
+    budget = compact.MAX_PAYLOAD_LANES - 1           # one word of validity
     assert rode == 1 + budget // 2 * 2 and rode + took == 1 + 2 * len(wide)
     assert [w is None for w in layout[1:]] == [
         i >= budget // 2 for i in range(len(wide))]

@@ -651,3 +651,192 @@ def test_a_filter_counts_the_lanes_that_ride_and_the_lanes_it_takes(realize):
         width = tagged.columns[1].data.shape[1]
         assert counted(lambda: tagged.select(lambda r: r.k % 2 == 0)) == [
             3, 3, -(-width // 4)]
+
+
+def _targets_with_strays(rng, cap, world):
+    """int32[cap] targets in [0, world], padding (== world) included, with
+    negative and past-``world`` values that ``_remap_oob_targets`` sends to
+    padding."""
+    t = rng.integers(0, world + 1, cap)
+    stray = rng.random(cap) < 0.1
+    t = np.where(stray, rng.choice([-1, -7, world + 1, world + 40], cap), t)
+    return jnp.asarray(t.astype(np.int32))
+
+
+def _plane_words(rng, cap, n):
+    """``n`` uint32 words a plane could hold: float32 bit patterns a value
+    comparison would lose (NaN payloads, -0.0), all-ones, and random bits."""
+    words = [jax.lax.bitcast_convert_type(jnp.asarray(_odd_floats(rng, cap)),
+                                          jnp.uint32),
+             jnp.full((cap,), 0xFFFFFFFF, jnp.uint32)]
+    while len(words) < n:
+        words.append(jnp.asarray(
+            rng.integers(0, 1 << 32, cap, dtype=np.uint64).astype(np.uint32)))
+    return words[:n]
+
+
+def _sorts(jaxpr):
+    return [e for e in jaxpr.eqns if e.primitive.name == "sort"]
+
+
+def _stable_by_target(targets, world):
+    """numpy's stable grouping of ``targets`` by value, strays (negative,
+    past ``world``) with the padding: the permutation to agree with."""
+    t = np.asarray(targets)
+    t = np.where((t < 0) | (t > world), world, t)
+    return np.argsort(t, kind="stable"), t
+
+
+@pytest.mark.parametrize("cap", [1, 2, 1023, 1025])
+@pytest.mark.parametrize("world", [1, 2, 4, 8, 40])
+def test_perm_by_target_carries_its_payload(realize, world, cap):
+    """``_perm_by_target(targets, world, *payload)`` on either row: the
+    stable grouping by target, padding and strays last, and each payload
+    word as ``jnp.take`` through it returns it, bit for bit.  On the TPU's
+    row it is ``compact.sort_by_target``, one single-key sort of the packed
+    word with the payload behind it; on the ``scatter`` row the counting
+    scan and a take (world 40 is past the scan's wide-mesh cutoff and sorts
+    on both rows; its target still fits the word)."""
+    from cylon_tpu.parallel import shuffle
+
+    rng = np.random.default_rng(world * 7919 + cap)
+    targets = _targets_with_strays(rng, cap, world)
+    payload = _plane_words(rng, cap, 3)
+    want, remapped = _stable_by_target(targets, world)
+    for mode in MODES:
+        with realize(realization.current()._replace(permute=mode)):
+            perm, *carried = shuffle._perm_by_target(targets, world,
+                                                     *payload)
+            jaxpr = jax.make_jaxpr(lambda t, *p: shuffle._perm_by_target(
+                t, world, *p))(targets, *payload)
+        np.testing.assert_array_equal(perm, want)
+        assert perm.dtype == jnp.int32
+        assert (np.diff(remapped[np.asarray(perm)]) >= 0).all()
+        for word, moved in zip(payload, carried):
+            assert moved.dtype == word.dtype and moved.shape == word.shape
+            assert np.asarray(moved).tobytes() == np.asarray(word)[
+                want].tobytes()
+        sorts = _sorts(jaxpr)
+        if mode == "scatter" and world < 40:
+            assert not sorts
+            continue
+        sort, = sorts
+        assert sort.params["num_keys"] == 1 and not sort.params["is_stable"]
+        assert [v.aval.dtype for v in sort.invars] == [jnp.uint32] * 4
+        assert " gather[" not in str(jaxpr)
+
+
+@pytest.mark.parametrize("case", ["wide_alphabet", "past_2_31_rows"])
+def test_sort_by_target_falls_back_past_32_bits(realize, case):
+    """Where target and row index do not fit one word, the two-operand
+    stable sort carries the payload: run at 2^13 rows to 2^20 targets (21 +
+    13 bits), traced at more than 2^31 rows (int64 index)."""
+    from cylon_tpu.parallel import shuffle
+
+    if case == "past_2_31_rows":
+        cap, world = (1 << 31) + 8, 4
+        jaxpr = jax.make_jaxpr(lambda t, x: compact.sort_by_target(t, world, x))(
+            jax.ShapeDtypeStruct((cap,), jnp.int32),
+            jax.ShapeDtypeStruct((cap,), jnp.uint32))
+        index = jnp.int64
+    else:
+        cap, world = 1 << 13, 1 << 20
+        rng = np.random.default_rng(13)
+        targets = _targets_with_strays(rng, cap, world)
+        payload = _plane_words(rng, cap, 2)
+        want, _ = _stable_by_target(targets, world)
+        with realize(realization.ON_TPU):
+            perm, *carried = shuffle._perm_by_target(targets, world, *payload)
+            jaxpr = jax.make_jaxpr(lambda t, *p: shuffle._perm_by_target(
+                t, world, *p))(targets, *payload)
+        np.testing.assert_array_equal(perm, want)
+        for word, moved in zip(payload, carried):
+            assert np.asarray(moved).tobytes() == np.asarray(word)[
+                want].tobytes()
+        index = jnp.int32
+    sort, = _sorts(jaxpr)
+    assert sort.params["num_keys"] == 1 and sort.params["is_stable"]
+    assert [v.aval.dtype for v in sort.invars][:2] == [jnp.int32, index]
+    assert jaxpr.out_avals[0].dtype == index
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("nwords", [1, 3, compact.MAX_PAYLOAD_LANES, 15])
+def test_plane_by_target_equals_the_gathered_plane(realize, nwords, mode):
+    """The exchange's plane grouped by target: the stacked words in the
+    stable order by target, bit for bit.  Under
+    ``sort`` the words ride the target sort up to the payload a sort
+    carries and only the rest is gathered; under ``scatter`` the plane is
+    gathered as before."""
+    from cylon_tpu.parallel import shuffle
+
+    world, cap = 4, 1000
+    rng = np.random.default_rng(nwords)
+    targets = _targets_with_strays(rng, cap, world)
+    words = _plane_words(rng, cap, nwords)
+    want = np.stack(words, axis=1)[_stable_by_target(targets, world)[0]]
+    with realize(realization.current()._replace(permute=mode)):
+        got = shuffle._plane_by_target(targets, world, words)
+        jaxpr = jax.make_jaxpr(lambda t, *w: shuffle._plane_by_target(
+            t, world, list(w)))(targets, *words)
+        ride = shuffle.riding_words(nwords)
+    assert got.shape == (cap, nwords) and got.dtype == jnp.uint32
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert ride == (min(nwords, compact.MAX_PAYLOAD_LANES) if mode == "sort"
+                    else 0)
+    gathers = str(jaxpr).count(" gather[")
+    assert gathers == (1 if ride < nwords else 0)
+    if mode == "sort":
+        sort, = _sorts(jaxpr)
+        assert len(sort.invars) == 1 + ride
+
+
+@pytest.mark.parametrize("cap", [1, 1000, 1 << 20])
+def test_mask_sort_perm_traces_the_parents_program(cap):
+    """The new small-alphabet form leaves the mask's sort as it was."""
+    mask = jax.ShapeDtypeStruct((cap,), jnp.bool_)
+    got = jax.make_jaxpr(lambda m: compact._mask_sort_perm(m))(mask)
+    want = jax.make_jaxpr(lambda m: _parent_mask_sort_perm(m))(mask)
+    assert str(got) == str(want)
+
+
+@pytest.mark.parametrize("case", [
+    ("sort", "ragged", True, 12, 3), ("scatter", "ragged", True, 0, 15),
+    ("sort", "bucketed", True, 0, 15), ("sort", "ragged", False, 0, 0),
+    ("sort", "cell_join", True, 3, 0)],
+    ids=["sort_ragged", "scatter_ragged", "sort_bucketed", "perbuf",
+         "cell_join"])
+def test_an_exchange_counts_its_riding_and_taken_words(realize, case):
+    """``_record_exchange`` adds, once an exchange that ran, the packed
+    plane's words that rode the ragged exchange's target sort to
+    ``shuffle.payload_lanes`` and the rest to ``shuffle.take_lanes``: 14
+    int32 columns are 15 words (the validity bits share one), the
+    benchmark's join (a narrowed int64 key, a float64) 3."""
+    from cylon_tpu import dtypes
+    from cylon_tpu.obs import metrics
+    from cylon_tpu.parallel import ops as par_ops, plane
+
+    mode, family, packed, rode, taken = case
+    n = 64
+
+    def column(dt, logical):
+        return colmod.Column(jnp.zeros((n,), dt), jnp.ones((n,), bool), None,
+                             logical)
+
+    spec = None
+    if family == "cell_join":
+        family = "ragged"
+        cols = (column(jnp.int64, dtypes.int64),
+                column(jnp.float64, dtypes.double))
+        spec = (("narrow", 0, 26), plane.RAW)
+    else:
+        cols = tuple(column(jnp.int32, dtypes.int32) for _ in range(14))
+    names = ("shuffle.payload_lanes", "shuffle.take_lanes")
+    with realize(realization.current()._replace(permute=mode)):
+        before = [metrics.counter_value(name) for name in names]
+        par_ops._record_exchange(cols, packed, family, 10, spec=spec)
+        grew = [metrics.counter_value(name) - b
+                for name, b in zip(names, before)]
+    assert grew == [rode, taken]
+    if packed:
+        assert rode + taken == plane.plane_words(cols, spec)

@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 from cylon_tpu import Table, column as colmod
 from cylon_tpu.context import PARTITION_AXIS, ctx_cache
 from cylon_tpu.obs import metrics as obs_metrics
+from cylon_tpu.ops import realization
 from cylon_tpu.parallel import collectives, ops as par_ops, plane
 from cylon_tpu.parallel import shuffle as shuffle_mod
 from cylon_tpu.utils import shard_map
@@ -163,6 +164,45 @@ def test_rounded_exchange_equals_whole_and_numpy(
         assert not np.asarray(rounded[0].validity)[hi:(t + 1) * out_cap].any()
 
 
+@pytest.mark.parametrize("rounded", [False, True], ids=["whole", "rounds"])
+@pytest.mark.parametrize("realization_", ["packed", "compressed"])
+@pytest.mark.parametrize("kind", ["uniform", "hot", "empty"])
+def test_the_plane_rides_the_target_sort_to_the_same_shards(
+        ragged_on_cpu, monkeypatch, realize, rng, kind, realization_,
+        rounded):
+    """Where permutations sort, the packed exchange carries its plane's
+    words through the sort that groups them by target instead of taking
+    the plane through that sort's permutation: every shard it returns
+    holds the rows, in the order and to the bit, of the exchange that
+    gathers the plane (the ``scatter`` row's)."""
+    monkeypatch.setenv("CYLON_TPU_SHUFFLE_PACK", "1")
+    cols = _columns(rng)
+    targets, _ = _targets(kind, rng)
+    spec = None
+    if realization_ == "compressed":
+        spec = plane.estimate_spec(cols, WORLD, SHARD)
+    cm = np.stack([np.bincount(targets[s * SHARD:(s + 1) * SHARD],
+                               minlength=WORLD + 1)[:WORLD]
+                   for s in range(WORLD)])
+    _, out_cap = shuffle_mod.plan_shuffle(cm)
+    rounds = None
+    if rounded:
+        monkeypatch.setattr(shuffle_mod, "_RAGGED_OPERAND_LIMIT", SMALL_LIMIT)
+        rounds, _ = shuffle_mod.plan_rounds(cm, SHARD)
+        assert rounds > 1
+    got = {}
+    for permute in ("scatter", "sort"):
+        with realize(realization.current()._replace(permute=permute)):
+            got[permute] = _exchange(ragged_on_cpu, cols, targets, out_cap,
+                                     spec, rounds)
+    (gathered, totals), (carried, totals_sorted) = got["scatter"], got["sort"]
+    np.testing.assert_array_equal(totals, cm.sum(axis=0))
+    np.testing.assert_array_equal(totals_sorted, totals)
+    for a, b in zip(jax.tree.leaves(gathered), jax.tree.leaves(carried)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
 def test_a_large_shard_without_a_round_count_is_refused(
         ragged_on_cpu, small_limit, rng):
     from cylon_tpu.status import Code, CylonError
@@ -182,11 +222,15 @@ def _frames(rng, n: int):
     return left, right
 
 
+@pytest.mark.parametrize("permute", ["scatter", "sort"])
 @pytest.mark.parametrize("pack", ["0", "1"], ids=["perbuf", "compressed"])
 def test_join_groupby_sort_through_rounds_equals_pandas(
-        ragged_on_cpu, small_limit, monkeypatch, rng, pack):
+        ragged_on_cpu, small_limit, monkeypatch, realize, rng, pack,
+        permute):
     """The benchmark's query on the 4-device mesh: every exchange of it
-    goes in rounds, each planned from one host sync."""
+    goes in rounds, each planned from one host sync.  Where permutations
+    sort, as on a TPU, every word of each packed plane rides its
+    exchange's target sort and none is taken."""
     ctx = ragged_on_cpu
     monkeypatch.setenv("CYLON_TPU_SHUFFLE_PACK", pack)
     monkeypatch.setenv("CYLON_TPU_SHUFFLE_COMPRESS", pack)
@@ -197,10 +241,12 @@ def test_join_groupby_sort_through_rounds_equals_pandas(
     assert lt.shard_capacity * shuffle_mod.RAGGED_ROW_BYTES >= SMALL_LIMIT
 
     before = dict(obs_metrics.snapshot()["counters"])
-    out = (lt.distributed_join(rt, on="k", how="inner")
-           .groupby("l_k", {"a": ["sum", "mean", "count"]})
-           .distributed_sort(["count_a", "l_k"], ascending=[False, True]))
-    got = out.to_pandas()
+    with realize(realization.current()._replace(permute=permute)):
+        out = (lt.distributed_join(rt, on="k", how="inner")
+               .groupby("l_k", {"a": ["sum", "mean", "count"]})
+               .distributed_sort(["count_a", "l_k"],
+                                 ascending=[False, True]))
+        got = out.to_pandas()
     after = obs_metrics.snapshot()["counters"]
 
     def grew(name):
@@ -210,6 +256,13 @@ def test_join_groupby_sort_through_rounds_equals_pandas(
     assert grew("shuffle.rounds") > 4 * 3
     assert grew("shuffle.operand_bytes") >= (
         grew("shuffle.rounds") * WORLD * 32 * shuffle_mod.RAGGED_ROW_BYTES)
+    rode, taken = grew("shuffle.payload_lanes"), grew("shuffle.take_lanes")
+    if pack == "0":
+        assert rode == taken == 0
+    elif permute == "sort":
+        assert rode >= 4 * 2 and taken == 0
+    else:
+        assert rode == 0 and taken >= 4 * 2
 
     merged = left.merge(right[["k"]], on="k")
     exp = merged.groupby("k")["a"].agg(["sum", "mean", "count"]).reset_index()

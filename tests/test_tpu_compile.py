@@ -340,13 +340,31 @@ def test_ragged_shuffle_compiles_on_four_chips(topo, as_on_chip, with_string):
             cols, targets).compile()
     assert "ragged-all-to-all" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+    _assert_the_plane_rides_the_target_sort(
+        compiled, shard, plane.plane_words(cols, spec))
+
+
+def _assert_the_plane_rides_the_target_sort(compiled, shard: int,
+                                            words: int) -> None:
+    """No bare custom fusion gathers the ``u32[shard, words]`` plane, and
+    the exchange's target sort (stage ``compact.partition``) carries the
+    plane's words beside its packed key: ``1 + words`` arrays of a shard."""
+    held = _custom_fusion_gathers(compiled)
+    lines = compiled.as_text().splitlines()
+    assert not [line for line in lines if "kind=kCustom" in line
+                and held[line.split(" = ")[0].split()[-1]]
+                and re.search(rf"= u32\[{shard},\d+\]", line)]
+    sorts = [line.split(" sort(")[0].count(f"u32[{shard}]") for line in lines
+             if " sort(" in line and "compact.partition" in line]
+    assert 1 + words in sorts, sorts
 
 
 def test_rounded_shuffle_compiles_at_the_weak_scaling_shard(topo, as_on_chip):
     """The benchmark's four-chip cell: (k int64, a float64) at 2^24 rows a
     shard, over the collective's operand limit, so the exchange goes in
     rounds of 2^21 rows and none of its buffers is a shard in the
-    collective's 512 B rows (8 GB to send and 8 GB to receive)."""
+    collective's 512 B rows (8 GB to send and 8 GB to receive).  The
+    plane's three words ride the target sort: nothing gathers the plane."""
     from cylon_tpu.parallel import plane, shuffle
     from cylon_tpu.utils import shard_map
 
@@ -374,3 +392,5 @@ def test_rounded_shuffle_compiles_at_the_weak_scaling_shard(topo, as_on_chip):
             cols, targets).compile()
     assert "ragged-all-to-all" in compiled.as_text()
     assert _device_bytes(compiled) < HBM_BYTES
+    assert plane.plane_words(cols, spec) == 3
+    _assert_the_plane_rides_the_target_sort(compiled, shard, 3)
