@@ -75,25 +75,28 @@ def _shapes_key(t) -> tuple:
 # shuffle (reference: Shuffle, table.cpp:951-964)
 # ---------------------------------------------------------------------------
 
-def _counts_for(t, key_idx: Tuple[int, ...], mode: str, opts: SortOptions | None):
+def _counts_for(t, key_idx: Tuple[int, ...], mode: str,
+                opts: SortOptions | None, hot: tuple = ()):
     """[world, world] count matrix for a prospective shuffle, replicated on
-    every process (multi-host planners need it host-side everywhere)."""
+    every process (multi-host planners need it host-side everywhere);
+    under a skew mode (``hot`` given) also ``_split_targets``' numbers."""
     from jax.sharding import PartitionSpec as P
 
     world = t.num_shards
     ctx = t.ctx
 
-    def fn(tt):
-        tgt = _targets(tt, key_idx, world, mode, opts)
+    def fn(tt, *hot):
+        tgt, skew = _split_targets(tt, key_idx, world, mode, opts, hot)
         counts = shuffle_mod.target_counts(tgt, world)  # [world] per shard
-        return collectives.allgather(counts, axis=0).reshape(world, world)
+        return (collectives.allgather(counts, axis=0).reshape(world, world),
+                ) + skew
 
     return _shard_map(ctx, fn, ("counts", key_idx, mode, opts), _shapes_key(t),
-                      out_specs=P())(t)
+                      out_specs=(P(),) + _skew_specs(hot))(t, *hot)
 
 
 def _targets_and_counts(t, key_idx: Tuple[int, ...], mode: str,
-                        opts: SortOptions | None):
+                        opts: SortOptions | None, hot: tuple = ()):
     """One targets pass returning (sharded targets array, replicated
     [world, world] count matrix) — the exchange program reuses the targets
     instead of re-hashing, and every process can size the plan."""
@@ -102,18 +105,20 @@ def _targets_and_counts(t, key_idx: Tuple[int, ...], mode: str,
     world = t.num_shards
     ctx = t.ctx
 
-    def fn(tt):
-        tgt = _targets(tt, key_idx, world, mode, opts)
+    def fn(tt, *hot):
+        tgt, skew = _split_targets(tt, key_idx, world, mode, opts, hot)
         counts = shuffle_mod.target_counts(tgt, world)
-        return tgt, collectives.allgather(counts, axis=0).reshape(world, world)
+        return (tgt, collectives.allgather(counts, axis=0).reshape(
+            world, world)) + skew
 
     return _shard_map(ctx, fn, ("targets+counts", key_idx, mode, opts),
                       _shapes_key(t),
-                      out_specs=(P(PARTITION_AXIS), P()))(t)
+                      out_specs=(P(PARTITION_AXIS), P()) + _skew_specs(hot))(
+                          t, *hot)
 
 
 def _targets_counts_stats(t, key_idx: Tuple[int, ...], mode: str,
-                          opts: SortOptions | None):
+                          opts: SortOptions | None, hot: tuple = ()):
     """The compression pre-pass: ONE program returning (sharded targets,
     replicated count matrix, replicated per-column value stats).  The
     stats ride the pass that already touches every key (the count-matrix
@@ -125,21 +130,22 @@ def _targets_counts_stats(t, key_idx: Tuple[int, ...], mode: str,
     ctx = t.ctx
     n_stats = partition_mod.stats_arity(t.columns)
 
-    def fn(tt):
-        tgt = _targets(tt, key_idx, world, mode, opts)
+    def fn(tt, *hot):
+        tgt, skew = _split_targets(tt, key_idx, world, mode, opts, hot)
         counts = shuffle_mod.target_counts(tgt, world)
         cm = collectives.allgather(counts, axis=0).reshape(world, world)
         stats = partition_mod.column_stats(tt.columns, tt.row_counts[0])
-        return tgt, cm, stats
+        return (tgt, cm, stats) + skew
 
     return _shard_map(ctx, fn, ("targets+counts+stats", key_idx, mode, opts),
                       _shapes_key(t),
                       out_specs=(P(PARTITION_AXIS), P(),
-                                 tuple(P() for _ in range(n_stats))))(t)
+                                 tuple(P() for _ in range(n_stats)))
+                      + _skew_specs(hot))(t, *hot)
 
 
 def _counts_stats_for(t, key_idx: Tuple[int, ...], mode: str,
-                      opts: SortOptions | None):
+                      opts: SortOptions | None, hot: tuple = ()):
     """Bucketed-path compression pre-pass: replicated (count matrix,
     stats) — _counts_for plus the observation, with NO sharded targets
     output (the bucketed exchange recomputes targets inside its own
@@ -150,15 +156,38 @@ def _counts_stats_for(t, key_idx: Tuple[int, ...], mode: str,
     ctx = t.ctx
     n_stats = partition_mod.stats_arity(t.columns)
 
-    def fn(tt):
-        tgt = _targets(tt, key_idx, world, mode, opts)
+    def fn(tt, *hot):
+        tgt, skew = _split_targets(tt, key_idx, world, mode, opts, hot)
         counts = shuffle_mod.target_counts(tgt, world)
         cm = collectives.allgather(counts, axis=0).reshape(world, world)
-        return cm, partition_mod.column_stats(tt.columns, tt.row_counts[0])
+        return (cm, partition_mod.column_stats(tt.columns, tt.row_counts[0])
+                ) + skew
 
     return _shard_map(ctx, fn, ("counts+stats", key_idx, mode, opts),
                       _shapes_key(t),
-                      out_specs=(P(), tuple(P() for _ in range(n_stats))))(t)
+                      out_specs=(P(), tuple(P() for _ in range(n_stats)))
+                      + _skew_specs(hot))(t, *hot)
+
+
+def _skew_specs(hot: tuple) -> tuple:
+    """The out_specs of ``_split_targets``' replicated numbers: one where
+    the program runs a skew mode (``hot`` given), none otherwise."""
+    from jax.sharding import PartitionSpec as P
+
+    return (P(),) if hot else ()
+
+
+def _split_targets(tt, key_idx, world, mode, opts, hot: tuple):
+    """``(targets, numbers)``: ``_targets``, and under a skew mode the
+    replicated ``int32[2]`` (hot keys, the rows of every shard whose key
+    is hot) that ride to the host with the count matrix; ``()`` in a plain
+    mode."""
+    if mode not in SKEW_MODES:
+        return _targets(tt, key_idx, world, mode, opts), ()
+    with obs_spans.span("shuffle.partition", mode=mode, world=world):
+        tgt, hot_rows = _skew_targets(tt, key_idx, world, mode, *hot)
+    n = hot[1][0]
+    return tgt, (jnp.stack([n, collectives.allreduce_sum(hot_rows)]),)
 
 
 def _targets(tt, key_idx, world, mode, opts: SortOptions | None):
@@ -176,6 +205,157 @@ def _targets(tt, key_idx, world, mode, opts: SortOptions | None):
             num_bins=opts.num_bins or 16 * world,
             num_samples=opts.num_samples or 4096,
             ascending=opts.ascending, nulls_first=opts.nulls_first)
+
+
+# ---------------------------------------------------------------------------
+# the skew split of a join's exchange: partial redistribution, partial
+# duplication (Xu et al., "Handling data skew in parallel joins in
+# shared-nothing systems", SIGMOD 2008).  Probe (left) rows whose key is
+# hot stay on their shard; build (right) rows whose key is hot go to every
+# shard; every other row is hash-exchanged.  Every shape below is set in
+# code, none by the data: the hot set is an operand of the programs.
+# ---------------------------------------------------------------------------
+
+#: probe rows sampled a shard, by a stride over its live rows (a shard
+#: with fewer live rows than the fullest samples proportionally fewer)
+SKEW_SAMPLE = 4096
+#: capacity of the hot set (K)
+SKEW_HOT_KEYS = 64
+#: a key is hot when more than this share of the samples holds it
+#: (``> samples / SKEW_SHARE_INV``: 16 of 16,384 on four shards)
+SKEW_SHARE_INV = 1024
+#: rows a shard may hold back for every shard (R): a hot key stays hot
+#: only while the build rows of the hot keys, over all shards, fit it
+SKEW_BLOCK_ROWS = 1024
+#: the probe side's and the build side's targeting modes
+SKEW_MODES = ("hash.keep", "hash.spread")
+
+
+def _member(h: jax.Array, hot: jax.Array, n) -> jax.Array:
+    """bool: ``h`` is in the hot set.  ``hot`` holds its ``n`` hashes
+    sorted, the slots past them a copy of the first: every slot is
+    compared, which XLA:TPU fuses into one reduction over ``h`` with no
+    gather and no ``[rows, K]`` buffer."""
+    return jnp.any(h[:, None] == hot[None, :], axis=1) & (n > 0)
+
+
+def _hot_keys(lcols, lcount, lkeys, rcols, rcount, rkeys, world: int):
+    """``(hot, n)``, the same on every shard: up to ``SKEW_HOT_KEYS``
+    hashes of probe keys that more than 1/``SKEW_SHARE_INV`` of a stride
+    sample of the probe rows holds, most sampled first, and their count.
+    A key is dropped again, fewest build rows kept first, while the build
+    rows of the hot keys over all shards would pass ``SKEW_BLOCK_ROWS``:
+    such a key takes the plain hash exchange on both sides.  Runs under
+    shard_map; the samples and the build counts are gathered and summed
+    over the shards, so every shard derives the same set."""
+    S, K, R = SKEW_SAMPLE, SKEW_HOT_KEYS, SKEW_BLOCK_ROWS
+    fullest = collectives.allreduce_max(lcount.astype(jnp.int32))
+    # this shard's samples, a stride over its live rows
+    n_s = (S * lcount.astype(jnp.float32)
+           / jnp.maximum(fullest, 1).astype(jnp.float32))
+    n_s = jnp.ceil(n_s).astype(jnp.int32)
+    j = jnp.arange(S, dtype=jnp.int32)
+    pos = ((j.astype(jnp.float32) + 0.5) * lcount.astype(jnp.float32)
+           / jnp.maximum(n_s, 1).astype(jnp.float32)).astype(jnp.int32)
+    pos = jnp.clip(pos, 0, jnp.maximum(lcount - 1, 0))
+    sample = [lcols[i].take(pos) for i in lkeys]
+    h, _ = partition_mod.key_hashes(sample, range(len(sample)), world)
+    late = collectives.allgather((j >= n_s).astype(jnp.int32)).reshape(-1)
+    h = collectives.allgather(h).reshape(-1)
+    # runs of equal hashes, the samples past a shard's count last
+    late, h = jax.lax.sort((late, h), num_keys=2)
+    i = jnp.arange(h.shape[0], dtype=jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool),
+                             (h[1:] != h[:-1]) | (late[1:] != late[:-1])])
+    last = jnp.concatenate([first[1:], jnp.ones((1,), bool)])
+    start = jax.lax.cummax(jnp.where(first, i, 0))
+    run = jnp.where(last & (late == 0), i - start + 1, 0)
+    samples = jnp.sum(1 - late)
+    run = jnp.where(run * SKEW_SHARE_INV > samples, run, 0)
+    top, at = jax.lax.top_k(run, K)
+    cand = jnp.take(h, at)
+    valid = top > 0
+    # build rows of each candidate, over all shards
+    rh, _ = partition_mod.key_hashes(rcols, rkeys, world)
+    rlive = jnp.arange(rh.shape[0], dtype=jnp.int32) < rcount
+    built = jnp.sum((rh[:, None] == cand[None, :]) & rlive[:, None], axis=0,
+                    dtype=jnp.int32)
+    built = collectives.allreduce_sum(jnp.where(valid, built, 0))
+    # fewest build rows first (the most sampled first among equals)
+    key = jnp.where(valid, built, jnp.iinfo(jnp.int32).max)
+    _, order = jax.lax.sort((key, jnp.arange(K, dtype=jnp.int32)),
+                            num_keys=2)
+    fits = jnp.take(valid, order) & (
+        jnp.cumsum(jnp.take(built, order)) <= R)
+    keep = jnp.zeros((K,), bool).at[order].set(fits)
+    n = jnp.sum(keep, dtype=jnp.int32)
+    hot = jnp.sort(jnp.where(keep, cand, jnp.uint32(0xFFFFFFFF)))
+    hot = jnp.where(jnp.arange(K) < n, hot, hot[0])
+    return hot, n
+
+
+def _skew_targets(tt, key_idx, world: int, mode: str, hot, n):
+    """(targets, this shard's live rows whose key is hot) of one side of
+    the skew split: a hot probe row targets its own shard
+    (``hash.keep``); a hot build row targets padding (``hash.spread``),
+    which the exchange holds back and sends to every shard
+    (``shuffle._append_held``); every other live row its hash target."""
+    from ..ops import compact as compact_mod
+
+    cap = tt.columns[0].data.shape[0]
+    h, t = partition_mod.key_hashes(tt.columns, key_idx, world)
+    live = compact_mod.live_mask(cap, tt.row_counts[0])
+    is_hot = _member(h, hot, n[0]) & live
+    keep = collectives.my_rank() if mode == "hash.keep" else world
+    t = jnp.where(is_hot, jnp.asarray(keep, jnp.int32), t)
+    return (jnp.where(live, t, jnp.int32(world)),
+            jnp.sum(is_hot, dtype=jnp.int32))
+
+
+def _hot_set(left, right, lkeys: Tuple[int, ...], rkeys: Tuple[int, ...]):
+    """The program ``skew_fn``: every shard's copy of the hot set, as
+    ``(uint32[world * K], int32[world])`` sharded one copy a shard, the
+    operand of both sides' targets passes.  No host sync."""
+    world = left.num_shards
+
+    def skew_fn(lt, rt):
+        hot, n = _hot_keys(lt.columns, lt.row_counts[0], lkeys, rt.columns,
+                           rt.row_counts[0], rkeys, world)
+        return hot, jnp.reshape(n, (1,))
+
+    return _shard_map(left.ctx, skew_fn, ("skew", lkeys, rkeys),
+                      (_shapes_key(left), _shapes_key(right)))(left, right)
+
+
+def join_exchange(left, right, left_on: Sequence[int],
+                  right_on: Sequence[int], split: bool):
+    """Both sides of a distributed join, exchanged so that every pair of
+    rows with equal keys meets on one shard exactly once: ``(left, right,
+    hot keys)``.
+
+    ``split`` (an INNER join, or a LEFT join that keeps the left side):
+    the skew split.  ``skew_fn`` finds the hot set on the device; the
+    left rows whose key is hot stay on their shard, the right rows whose
+    key is hot are copied to every shard, and every other row is
+    hash-exchanged.  The hot count reaches the host with the count
+    matrices the exchanges fetch anyway.  Unsplit, or with no key hot,
+    both sides end hash-partitioned on their keys."""
+    left_on, right_on = tuple(left_on), tuple(right_on)
+    if not split:
+        return shuffle(left, left_on), shuffle(right, right_on), 0
+    with obs_spans.span("shuffle.skew", world=left.num_shards) as sp:
+        hot = _hot_set(left, right, left_on, right_on)
+        kept, held = {}, {}
+        left_sh = _shuffled(left, left_on, "hash.keep", hot=hot, note=kept)
+        right_sh = _shuffled(right, right_on, "hash.spread", hot=hot,
+                             note=held)
+        n = kept.get("hot_keys", 0)
+        sp.set(hot_keys=n)
+    obs_metrics.counter_add("join.skew.hot_keys", n)
+    obs_metrics.counter_add("join.skew.kept_rows", kept.get("hot_rows", 0))
+    obs_metrics.counter_add("join.skew.replicated_rows",
+                            held.get("hot_rows", 0) * left.num_shards)
+    return left_sh, right_sh, n
 
 
 def _probe_ragged(ctx) -> bool:
@@ -390,12 +570,19 @@ def broadcast_gather(t):
 
 
 def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
-              opts: SortOptions | None = None):
+              opts: SortOptions | None = None, hot: tuple = (),
+              note: dict | None = None):
     """partition -> all-to-all -> compact; returns a new distributed Table.
 
     The exchange prefers the skew-proof RaggedAllToAll path (exact traffic,
     no bucket padding, targets computed once); if the active backend lacks
     the ragged collective the bucketed path is used and remembered.
+
+    A skew mode (``SKEW_MODES``) takes the hot set ``hot`` of ``_hot_set``
+    and writes into ``note`` the host's copy of its numbers, fetched with
+    the count matrix: ``hot_keys`` and ``hot_rows`` (kept or held back,
+    over all shards).  Under ``hash.spread`` every shard receives
+    ``SKEW_BLOCK_ROWS`` rows a shard besides the matrix's.
     """
     from .. import resilience
     from ..table import Table, host_sync
@@ -422,6 +609,22 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
         # key below (cylint CY109) — a data change retraces, never
         # decodes under a stale layout.
         compress = pack and plane_mod.compress_enabled()
+        held = SKEW_BLOCK_ROWS if mode == "hash.spread" else 0
+
+        def planned(fetched):
+            """The count matrix on the host, and the skew numbers noted."""
+            cm, *skew = fetched
+            if skew:
+                hot_keys, hot_rows = (int(v) for v in np.asarray(skew[0]))
+                if held and hot_rows > held:
+                    raise CylonError(
+                        Code.CapacityError,
+                        f"{hot_rows} build rows of hot keys held back, "
+                        f"more than the {held} a block holds")
+                if note is not None:
+                    note.update(hot_keys=hot_keys, hot_rows=hot_rows)
+            return np.asarray(cm).reshape(world, world)
+
         if _ragged_enabled(ctx):
             with obs_spans.span("shuffle.plan", mode=mode, world=world,
                       family="ragged"):
@@ -431,18 +634,17 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
                 resilience.fault_point("shuffle_plan")
                 spec = None
                 if compress:
-                    targets, counts, stats = _targets_counts_stats(
-                        t, key_idx, mode, opts)
+                    targets, counts, stats, *skew = _targets_counts_stats(
+                        t, key_idx, mode, opts, hot)
                     spec = plane_mod.build_spec(
                         t.columns, [np.asarray(s) for s in
                                     host_sync(stats, "shuffle.stats")], world,
                         t.shard_capacity)
                 else:
-                    targets, counts = _targets_and_counts(t, key_idx, mode,
-                                                          opts)
-                cm = np.asarray(host_sync(counts, "shuffle.plan")).reshape(
-                    world, world)
-                _, out_cap = shuffle_mod.plan_shuffle(cm)
+                    targets, counts, *skew = _targets_and_counts(
+                        t, key_idx, mode, opts, hot)
+                cm = planned(host_sync((counts, *skew), "shuffle.plan"))
+                _, out_cap = shuffle_mod.plan_shuffle(cm, held * world)
                 # the rounds of a shard over the collective's operand
                 # limit, sized from the count matrix already here
                 rounds, operand_rows = shuffle_mod.plan_rounds(
@@ -451,7 +653,7 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
             def rfn(tt, tgt):
                 cols, total = shuffle_mod.shuffle_shard_ragged(
                     tt.columns, tgt, world, out_cap, spec=spec,
-                    rounds=rounds)
+                    rounds=rounds, held_rows=held, count=tt.row_counts[0])
                 return Table(cols, jnp.reshape(total, (1,)), names, ctx)
 
             with obs_spans.span("shuffle.exchange", packed=pack, family="ragged",
@@ -459,7 +661,7 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
                       rounds=rounds):
                 out = _shard_map(ctx, rfn,
                                  ("shuffle-ragged", key_idx, out_cap, pack,
-                                  spec, rounds),
+                                  spec, rounds, held),
                                  _shapes_key(t))(t, targets)
             # ragged moves exactly the rows that exist
             _record_exchange(t.columns, pack, "ragged", int(cm.sum()),
@@ -471,25 +673,26 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
             resilience.fault_point("shuffle_plan")
             spec = None
             if compress:
-                counts, stats = _counts_stats_for(t, key_idx, mode, opts)
+                counts, stats, *skew = _counts_stats_for(t, key_idx, mode,
+                                                         opts, hot)
                 spec = plane_mod.build_spec(
                     t.columns, [np.asarray(s) for s in
                                 host_sync(stats, "shuffle.stats")], world,
                     t.shard_capacity)
             else:
-                counts = _counts_for(t, key_idx, mode, opts)
+                counts, *skew = _counts_for(t, key_idx, mode, opts, hot)
             bucket, out_cap = shuffle_mod.plan_shuffle(
-                np.asarray(host_sync(counts, "shuffle.plan")).reshape(
-                    world, world))
+                planned(host_sync((counts, *skew), "shuffle.plan")),
+                held * world)
 
         # unique closure name: cylint resolves closures module-wide by
         # bare name, and CY109 must see THIS body's spec use, not some
         # other `fn`'s
-        def bfn(tt):
-            tgt = _targets(tt, key_idx, world, mode, opts)
+        def bfn(tt, *hot):
+            tgt, _ = _split_targets(tt, key_idx, world, mode, opts, hot)
             cols, total = shuffle_mod.shuffle_shard(
                 tt.columns, tt.row_counts[0], tgt, world, bucket, out_cap,
-                spec=spec)
+                spec=spec, held_rows=held)
             return Table(cols, jnp.reshape(total, (1,)), names, ctx)
 
         with obs_spans.span("shuffle.exchange", packed=pack, family="bucketed",
@@ -497,7 +700,7 @@ def _shuffled(t, key_idx: Tuple[int, ...], mode: str = "hash",
             out = _shard_map(ctx, bfn,
                              ("shuffle", key_idx, mode, opts, bucket,
                               out_cap, pack, spec),
-                             _shapes_key(t))(t)
+                             _shapes_key(t))(t, *hot)
         # every (src, dst) pair pads to the static bucket
         _record_exchange(t.columns, pack, "bucketed",
                          world * world * bucket, spec=spec)

@@ -46,17 +46,25 @@ def hash_targets(cols: Sequence[Column], count, key_idx: Sequence[int],
     hasher, so host- and device-partitioned rows agree); string keys and
     CPU execution use the vectorized jnp hash."""
     cap = cols[0].data.shape[0]
-    key_cols = [cols[i] for i in key_idx]
-    if precision.on_tpu() and pallas_kernels.supported(key_cols):
-        _, t = pallas_kernels.hash_partition(key_cols, world)
-    else:
-        h = hashing.hash_columns(key_cols)
-        if world & (world - 1) == 0:
-            t = (h & jnp.uint32(world - 1)).astype(jnp.int32)
-        else:
-            t = (h % jnp.uint32(world)).astype(jnp.int32)
+    _, t = key_hashes(cols, key_idx, world)
     live = compact_mod.live_mask(cap, count)
     return jnp.where(live, t, jnp.int32(world))
+
+
+def key_hashes(cols: Sequence[Column], key_idx: Sequence[int],
+               world: int) -> Tuple[jax.Array, jax.Array]:
+    """(uint32 hash, int32 hash target) of every row's key, padding rows
+    included: the hash ``hash_targets`` places rows by, so that a row's
+    key hash found here names the shard that row is sent to."""
+    key_cols = [cols[i] for i in key_idx]
+    if precision.on_tpu() and pallas_kernels.supported(key_cols):
+        return pallas_kernels.hash_partition(key_cols, world)
+    h = hashing.hash_columns(key_cols)
+    if world & (world - 1) == 0:
+        t = (h & jnp.uint32(world - 1)).astype(jnp.int32)
+    else:
+        t = (h % jnp.uint32(world)).astype(jnp.int32)
+    return h, t
 
 
 def range_targets(col: Column, count, world: int, *, num_bins: int,

@@ -143,21 +143,67 @@ def riding_words(words: int) -> int:
     return min(words, compact_mod.MAX_PAYLOAD_LANES)
 
 
-def _plane_by_target(targets: jax.Array, world: int, words) -> jax.Array:
-    """``uint32[cap, len(words)]``: the plane whose columns are ``words``,
-    its rows grouped by target.  The words that ride (``riding_words``)
-    are payload of ``_perm_by_target``; the rest are taken through its
+def _plane_by_target(targets: jax.Array, world: int, words):
+    """``(perm, plane)``: ``_perm_by_target``'s permutation, and the
+    ``uint32[cap, len(words)]`` plane whose columns are ``words``, its
+    rows grouped by target.  The words that ride (``riding_words``) are
+    payload of ``_perm_by_target``; the rest are taken through its
     permutation."""
     ride = riding_words(len(words))
     perm, *carried = _perm_by_target(targets, world, *words[:ride])
     parts = [jnp.stack(carried, axis=1)] if carried else []
     if ride < len(words):
         parts.append(jnp.take(jnp.stack(words[ride:], axis=1), perm, axis=0))
-    return jnp.concatenate(parts, axis=1)
+    return perm, jnp.concatenate(parts, axis=1)
+
+
+def _append_held(out_cols: Tuple[Column, ...], total, cols, perm, counts,
+                 count, block_rows: int, world: int):
+    """``(columns, total)``: the exchange's result with every shard's
+    held-back rows after its received ones, on every shard.
+
+    A live row whose target is padding (``world``) is held back: it is
+    not sent.  ``perm`` groups rows by target and keeps their order inside
+    a target, and padding rows lie past every live row, so the rows held
+    back are ``perm[sent:count]``.  They go into a block of ``block_rows``
+    rows (the caller holds back no more), whose plane is gathered from
+    every shard in ONE collective, compacted and written at ``total``:
+    the receive capacity has room for ``world * block_rows`` rows past
+    the fullest shard's received ones (``plan_shuffle``'s ``extra``)."""
+    from ..ops import compact as compact_mod
+
+    cap = perm.shape[0]
+    sent = jnp.sum(counts, dtype=jnp.int32)
+    held = count.astype(jnp.int32) - sent
+    j = jnp.arange(block_rows, dtype=jnp.int32)
+    idx = jnp.take(perm, jnp.clip(sent + j, 0, cap - 1))
+    block = tuple(c.take(idx, valid_mask=j < held) for c in cols)
+    plane = plane_mod.pack_plane(block)
+    meta = jnp.zeros((1, plane.shape[1]), plane.dtype).at[0, 0].set(
+        held.astype(plane.dtype))
+    got = collectives.allgather(jnp.concatenate([plane, meta]), axis=0)
+    n = got[:, block_rows, 0].astype(jnp.int32)
+    rows = got[:, :block_rows, :].reshape(world * block_rows, -1)
+    live = (j[None, :] < n[:, None]).reshape(world * block_rows)
+    order, m = compact_mod.compact_indices(live)
+    gathered = plane_mod.unpack_plane(
+        jnp.take(rows, order, axis=0),
+        cols, valid_mask=jnp.arange(world * block_rows, dtype=jnp.int32) < m)
+
+    def put(buf, part):
+        return jax.lax.dynamic_update_slice_in_dim(buf, part, total, 0)
+
+    out = tuple(
+        Column(put(o.data, g.data), put(o.validity, g.validity),
+               None if o.lengths is None else put(o.lengths, g.lengths),
+               o.dtype)
+        for o, g in zip(out_cols, gathered))
+    return out, total + m.astype(total.dtype)
 
 
 def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
-                  world: int, bucket: int, out_capacity: int, spec=None):
+                  world: int, bucket: int, out_capacity: int, spec=None,
+                  held_rows: int = 0):
     """Shard-local body of the shuffle (run under shard_map).
 
     bucket: static per-(src,dst) bucket row capacity; rows beyond it would be
@@ -175,7 +221,11 @@ def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
     caller derived from the pre-pass stats — narrow/dictionary/truncated
     plane fields, bit-exact round trip, at most one extra dictionary
     all_gather (plane.PlaneCodec).  Data-dependent static layout: callers
-    key their jit-plan caches on it (cylint CY109)."""
+    key their jit-plan caches on it (cylint CY109).
+
+    ``held_rows`` > 0: the live rows whose target is padding go to every
+    shard instead, after its received rows (``_append_held``, at most
+    ``held_rows`` of them a shard)."""
     cap = cols[0].data.shape[0]
 
     counts = target_counts(targets, world)
@@ -230,6 +280,9 @@ def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
         with obs_spans.span("shuffle.unpack", columns=len(cols)):
             out_plane = jnp.take(recv_plane, src2, axis=0)
             out = codec.unpack(out_plane, cols, valid_mask=valid2)
+        if held_rows:
+            return _append_held(out, total, cols, perm_t, counts, count,
+                                held_rows, world)
         return out, total
 
     # per-buffer exchange: one tiled all_to_all per buffer
@@ -248,12 +301,18 @@ def shuffle_shard(cols: Tuple[Column, ...], count, targets: jax.Array,
             for c in send_cols)
     with obs_spans.span("shuffle.unpack", columns=len(cols)):
         out_cols = tuple(c.take(src2, valid_mask=valid2) for c in recv_cols)
+    if held_rows:
+        return _append_held(out_cols, total, cols, perm_t, counts, count,
+                            held_rows, world)
     return out_cols, total
 
 
-def plan_shuffle(counts: jax.Array) -> Tuple[int, int]:
+def plan_shuffle(counts: jax.Array, extra: int = 0) -> Tuple[int, int]:
     """Host-side sizing from the [world, world] count matrix: (bucket,
-    out_capacity), both rounded to powers of two to bound recompilation."""
+    out_capacity), both rounded to powers of two to bound recompilation.
+    ``extra``: rows every shard receives besides the matrix's (the held
+    rows of ``_append_held``), a constant, so the capacity depends on the
+    data through the matrix alone."""
     import numpy as np
 
     from ..utils import pow2ceil
@@ -261,7 +320,7 @@ def plan_shuffle(counts: jax.Array) -> Tuple[int, int]:
     cm = np.asarray(counts)
     bucket = int(cm.max()) if cm.size else 0
     incoming = cm.sum(axis=0).max() if cm.size else 0
-    return pow2ceil(bucket), pow2ceil(incoming)
+    return pow2ceil(bucket), pow2ceil(int(incoming) + extra)
 
 
 def ragged_plan(cm, me):
@@ -397,7 +456,7 @@ def _ragged_exchange(sorted_buf: jax.Array, cm: jax.Array, me,
 
 def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
                          world: int, out_capacity: int, spec=None,
-                         rounds=None):
+                         rounds=None, held_rows: int = 0, count=None):
     """Skew-proof shard-local shuffle body over ``lax.ragged_all_to_all``.
 
     Where ``shuffle_shard`` pads every (src,dst) pair to one static bucket
@@ -426,6 +485,10 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
     target sort where permutations sort (``_plane_by_target``);
     per-buffer — one collective and one sort-gather per buffer.
     Bit-identical outputs either way.
+
+    ``held_rows`` > 0: the live rows of the shard's ``count`` whose target
+    is padding go to every shard instead, after its received rows
+    (``_append_held``, at most ``held_rows`` of them a shard).
     """
     counts = target_counts(targets, world)
 
@@ -441,7 +504,7 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
         with obs_spans.span("shuffle.pack", columns=len(cols)) as sp:
             words = codec.pack_words(cols)
             sp.set(words=len(words), compressed=spec is not None)
-            sorted_plane = _plane_by_target(targets, world, words)
+            perm_t, sorted_plane = _plane_by_target(targets, world, words)
         with obs_spans.span("shuffle.collective",
                             family="ragged_all_to_all", packed=True,
                             launches=1, rounds=rounds or 1):
@@ -460,6 +523,9 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
             if spec is not None:
                 tail = jnp.arange(out_capacity, dtype=jnp.int32) < total
             out_cols = codec.unpack(got, cols, tail_mask=tail)
+        if held_rows:
+            return _append_held(out_cols, total, cols, perm_t, counts, count,
+                                held_rows, world)
         return out_cols, total
 
     perm_t, = _perm_by_target(targets, world)
@@ -485,4 +551,7 @@ def shuffle_shard_ragged(cols: Tuple[Column, ...], targets: jax.Array,
                    None if c.lengths is None else exchange(c.lengths),
                    c.dtype)
             for c in cols)
+    if held_rows:
+        return _append_held(out_cols, total, cols, perm_t, counts, count,
+                            held_rows, world)
     return out_cols, total

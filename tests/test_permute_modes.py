@@ -774,12 +774,14 @@ def test_plane_by_target_equals_the_gathered_plane(realize, nwords, mode):
     rng = np.random.default_rng(nwords)
     targets = _targets_with_strays(rng, cap, world)
     words = _plane_words(rng, cap, nwords)
-    want = np.stack(words, axis=1)[_stable_by_target(targets, world)[0]]
+    order = _stable_by_target(targets, world)[0]
+    want = np.stack(words, axis=1)[order]
     with realize(realization.current()._replace(permute=mode)):
-        got = shuffle._plane_by_target(targets, world, words)
+        perm, got = shuffle._plane_by_target(targets, world, words)
         jaxpr = jax.make_jaxpr(lambda t, *w: shuffle._plane_by_target(
-            t, world, list(w)))(targets, *words)
+            t, world, list(w))[1])(targets, *words)
         ride = shuffle.riding_words(nwords)
+    np.testing.assert_array_equal(np.asarray(perm), order)
     assert got.shape == (cap, nwords) and got.dtype == jnp.uint32
     assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
     assert ride == (min(nwords, compact.MAX_PAYLOAD_LANES) if mode == "sort"

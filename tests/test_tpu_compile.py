@@ -394,3 +394,48 @@ def test_rounded_shuffle_compiles_at_the_weak_scaling_shard(topo, as_on_chip):
     assert _device_bytes(compiled) < HBM_BYTES
     assert plane.plane_words(cols, spec) == 3
     _assert_the_plane_rides_the_target_sort(compiled, shard, 3)
+
+
+def test_skew_split_compiles_at_the_weak_scaling_shard(topo, as_on_chip):
+    """The skew split of the four-chip join at 2^24 rows a shard: the hot
+    set's program and the probe side's targets pass with its membership
+    test.  Neither holds a ``[rows, K]`` buffer: the test of every row
+    against the hot set is one reduction, its temporaries those of the
+    plain targets pass (the hash, the padded key words)."""
+    from cylon_tpu.parallel import collectives
+    from cylon_tpu.parallel import ops as par_ops
+    from cylon_tpu.parallel import shuffle
+    from cylon_tpu.utils import shard_map
+
+    world, shard, K = 4, ROWS, par_ops.SKEW_HOT_KEYS
+    mesh = Mesh(np.array(topo.devices), (PARTITION_AXIS,))
+    sharded = NamedSharding(mesh, P(PARTITION_AXIS))
+    side = (_col(world * shard, jnp.int64, dtypes.int64, sharded),
+            _col(world * shard, jnp.float64, dtypes.double, sharded))
+    counts = jax.ShapeDtypeStruct((world,), jnp.int32, sharding=sharded)
+
+    def skew_fn(lc, lk, rc, rk):
+        hot, n = par_ops._hot_keys(lc, lk[0], (0,), rc, rk[0], (0,), world)
+        return hot, jnp.reshape(n, (1,))
+
+    def fn(lc, lk, hot, n):
+        tt = collections.namedtuple("T", "columns row_counts")(lc, lk)
+        tgt, (skew,) = par_ops._split_targets(tt, (0,), world, "hash.keep",
+                                              None, (hot, n))
+        cm = collectives.allgather(shuffle.target_counts(tgt, world))
+        return tgt, cm.reshape(world, world), skew
+
+    def compiled(body, *args, out_specs):
+        return jax.jit(shard_map(body, mesh=mesh, in_specs=P(PARTITION_AXIS),
+                                 out_specs=out_specs, check_vma=False)
+                       ).lower(*args).compile()
+
+    hot = compiled(skew_fn, side, counts, side, counts,
+                   out_specs=P(PARTITION_AXIS))
+    split = compiled(
+        fn, side, counts,
+        jax.ShapeDtypeStruct((world * K,), jnp.uint32, sharding=sharded),
+        counts, out_specs=(P(PARTITION_AXIS), P(), P()))
+    for program in (hot, split):
+        assert program.memory_analysis().temp_size_in_bytes < shard * 16
+        assert "tpu_custom_call" in program.as_text()   # the Pallas hash
